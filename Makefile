@@ -35,9 +35,9 @@ bench:
 bench-solver:
 	$(PY) -m benchmarks.bench_solver --json BENCH_solver.json
 
-## decision-plane backend benchmark (PR 1 path vs batched numpy / per-
-## dispatch jax / fused device-resident engines; compile vs steady-state
-## split + catalog-size scaling column); refreshes BENCH_backend.json
+## decision-plane backend benchmark (PR 1 path vs batched numpy / fused
+## device-resident engine; compile vs steady-state split + catalog-size
+## scaling column); refreshes BENCH_backend.json
 bench-backend:
 	$(PY) -m benchmarks.bench_backend --json BENCH_backend.json
 

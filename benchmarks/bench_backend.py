@@ -13,8 +13,6 @@ the low-memo-hit regime where PR 4's DecisionMemo cannot collapse them):
     improvement-bit DP), still one cycle per decision, numpy backend;
   * ``batched_numpy``   — one :func:`bracketed_gss_many` over all
     decisions (cross-decision stacked prescan + lockstep golden rounds);
-  * ``batched_jax``     — the same batched cycle with every DP dispatched
-    through the PR 5 per-probe JAX-jitted scan backend;
   * ``fused_jax``       — the PR 6 device-resident plane
     (``make_backend("jax:fused")``): prescan + the whole golden-section
     search as jitted programs, counts read back once and replayed on host
@@ -50,10 +48,10 @@ from typing import List, Optional
 import numpy as np
 
 from repro.core import (NumpyBackend, Request, compile_market, e_total,
-                        generate_catalog, jax_available, make_backend,
+                        exact, generate_catalog, jax_available, make_backend,
                         preprocess)
 from repro.core.efficiency import NodePool, score_counts_batch
-from repro.core.gss import PHI, GssTrace, bracketed_gss_many
+from repro.core.gss import bracketed_gss_many
 
 #: ISSUE 5 acceptance bar: ≥5× end-to-end provisioning-cycle speedup over
 #: the PR 1 NumPy path at 250 offerings × 5k pods, n_decisions ≥ 32
@@ -182,8 +180,10 @@ def _pr1_solve(market, req_pods, alpha):
 
 def pr1_bracketed_gss(items, req_pods, market):
     """The PR 1 guarded cycle: 9-α prescan + golden refinement, every
-    solve through the vendored PR 1 solver (one decision at a time)."""
-    grid = [i / (PRESCAN - 1) for i in range(PRESCAN)]
+    solve through the vendored PR 1 solver (one decision at a time).  The
+    probes follow today's exact α grid and integer golden update
+    (:mod:`repro.core.exact`), so only the solver differs."""
+    grid = [exact.k_alpha(k) for k in exact.alpha_grid(PRESCAN)]
     counts_list = [_pr1_solve(market, req_pods, a) for a in grid]
     scores = score_counts_batch(items, counts_list, req_pods,
                                 none_score=float("-inf"),
@@ -196,41 +196,43 @@ def pr1_bracketed_gss(items, req_pods, market):
             pool.alpha = alpha
         if score > best_f:
             best_pool, best_f, best_idx = pool, score, gi
-    a = grid[max(0, best_idx - 1)]
-    b = grid[min(len(grid) - 1, best_idx + 1)]
+    kgrid = exact.alpha_grid(PRESCAN)
+    a = kgrid[max(0, best_idx - 1)]
+    b = kgrid[min(len(kgrid) - 1, best_idx + 1)]
 
     cache = {}
 
-    def evaluate(alpha):
-        key = round(alpha, 12)
-        if key in cache:
-            return cache[key]
+    def evaluate(k):
+        if k in cache:
+            return cache[k]
+        alpha = exact.k_alpha(k)
         counts = _pr1_solve(market, req_pods, alpha)
         if counts is None:
             out = (None, float("-inf"))
         else:
             pool = NodePool(items=list(items), counts=counts, alpha=alpha)
             out = (pool, e_total(pool, req_pods))
-        cache[key] = out
+        cache[k] = out
         return out
 
-    x1 = b - PHI * (b - a)
-    x2 = a + PHI * (b - a)
+    tol = exact.tolerance_k(TOLERANCE)
+    w = exact.golden_width(b - a)
+    x1, x2 = b - w, a + w
     pool1, f1 = evaluate(x1)
     pool2, f2 = evaluate(x2)
     g_pool, g_f = (pool1, f1) if f1 >= f2 else (pool2, f2)
-    while (b - a) > TOLERANCE:
+    while (b - a) > tol:
         if f1 >= f2:
             b = x2
             x2, f2, pool2 = x1, f1, pool1
-            x1 = b - PHI * (b - a)
+            x1 = b - exact.golden_width(b - a)
             pool1, f1 = evaluate(x1)
             if f1 > g_f:
                 g_pool, g_f = pool1, f1
         else:
             a = x1
             x1, f1, pool1 = x2, f2, pool2
-            x2 = a + PHI * (b - a)
+            x2 = a + exact.golden_width(b - a)
             pool2, f2 = evaluate(x2)
             if f2 > g_f:
                 g_pool, g_f = pool2, f2
@@ -331,17 +333,14 @@ def bench_tick(n_items: int, base_pods: int, n_dec: int, *,
     first_calls: dict = {}
     fused_be = None
     if jax_available():
-        jax_be = make_backend("jax")
         fused_be = make_backend("jax:fused")
         # first call = XLA trace + compile + one steady run; steady state
         # is measured interleaved below, compile ≈ first − steady
-        for name, be in (("batched_jax", jax_be), ("fused_jax", fused_be)):
-            t0 = time.perf_counter()
-            pools = batched_pools_of(be)
-            first_calls[name] = time.perf_counter() - t0
-            rec[f"{name}_selections_equal_numpy"] = _pools_equal(
-                batched_pools, pools)
-        fns["batched_jax"] = lambda: batched_cycle(jax_be)
+        t0 = time.perf_counter()
+        pools = batched_pools_of(fused_be)
+        first_calls["fused_jax"] = time.perf_counter() - t0
+        rec["fused_jax_selections_equal_numpy"] = _pools_equal(
+            batched_pools, pools)
         fns["fused_jax"] = lambda: batched_cycle(fused_be)
 
     best = _interleaved(fns, repeat)
@@ -443,9 +442,6 @@ def run(smoke: bool = False, n_decisions: Optional[int] = None,
             tick.get("fused_vs_batched_numpy"),
         "fused_steady_faster_than_numpy":
             (tick.get("fused_vs_batched_numpy") or 0.0) > 1.0,
-        "fused_vs_per_dispatch_jax": (
-            round(tick["batched_jax_wall_s"] / tick["fused_jax_wall_s"], 2)
-            if "fused_jax_wall_s" in tick else None),
         "pr1_meets_target": any(
             isinstance(v, float) and v >= TARGET_SPEEDUP
             for cfg in configs.values()
@@ -480,8 +476,7 @@ def main(argv: Optional[List[str]] = None):
     detail = (f"numpy:{tick['batched_numpy_wall_s']}s"
               f";fused:{tick.get('fused_jax_wall_s', 'n/a')}s"
               f"(compile:{tick.get('fused_jax_compile_s', 'n/a')}s)"
-              f";fused_vs_numpy:{h['fused_vs_batched_numpy_fleet_tick']}x"
-              f";fused_vs_jax:{h['fused_vs_per_dispatch_jax']}x")
+              f";fused_vs_numpy:{h['fused_vs_batched_numpy_fleet_tick']}x")
     us = round(tick["batched_numpy_wall_s"] / tick["n_decisions"] * 1e6)
     print(f"bench_backend,{us},{detail}")
     return out

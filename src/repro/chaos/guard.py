@@ -24,7 +24,7 @@ out:
    snapshot at all and falls through to the memo rung.
 3. **Solver rungs** — one bounded-retry loop per ladder backend spec
    (default ``("default", "numpy")``; a jax deployment would run
-   ``("jax:fused", "jax", "numpy")`` — all rungs produce bit-identical
+   ``("jax:fused", "numpy")`` — all rungs produce bit-identical
    selections per the DESIGN §12 backend contract, which is what makes
    descending *safe*).  Retries wait out a deterministic decorrelated-
    jitter backoff schedule (:func:`backoff_schedule`) whose delays are

@@ -1,6 +1,6 @@
 """KubePACS control plane: the paper's contribution as a composable library."""
 
-from . import events_log
+from . import events_log, exact
 from .market import (Offering, InterruptEvent, SpotMarketSimulator,
                      generate_catalog, restrict, snapshot_with,
                      pressure_interrupt_probability,
@@ -11,7 +11,7 @@ from .efficiency import (Request, CandidateItem, NodePool, pods_per_instance,
                          reweight_items, score_counts_batch,
                          score_counts_many)
 from .scaling import scaled_benchmark_score, build_base_price_index, matches_intent
-from .backend import (DEFAULT_COARSENING, CoarseningConfig, JaxBackend,
+from .backend import (DEFAULT_COARSENING, CoarseningConfig, FusedJaxBackend,
                       NumpyBackend, SolverBackend, get_backend,
                       jax_available, make_backend, set_backend)
 from .ilp import (solve_ilp, solve_ilp_batch, solve_ilp_many, solve_ilp_pulp,
@@ -41,7 +41,7 @@ __all__ = [
     "reweight_items", "reweight_market", "DecisionMemo",
     "solve_ilp_many", "bracketed_gss_many", "score_counts_many",
     "SolveBatch", "PendingDecision",
-    "SolverBackend", "NumpyBackend", "JaxBackend", "get_backend",
+    "SolverBackend", "NumpyBackend", "FusedJaxBackend", "get_backend",
     "set_backend", "make_backend", "jax_available",
-    "CoarseningConfig", "DEFAULT_COARSENING", "events_log",
+    "CoarseningConfig", "DEFAULT_COARSENING", "events_log", "exact",
 ]

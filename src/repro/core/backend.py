@@ -1,54 +1,38 @@
-"""Pluggable solver backends for the min-plus cover DP (DESIGN.md §12).
+"""Solver backends for the min-plus cover DP (DESIGN.md §12–13).
 
 The ILP engine reduces every solve — single-α, a GSS prescan grid, or the
 cross-decision batches of ``solve_ilp_many`` — to one primitive: a forward
 min-plus value pass over a bundle sequence that also emits *improvement
 bits*, the per-(bundle, coverage) booleans the exact backtracker consumes.
-This module defines that primitive once, with two interchangeable
-implementations:
+Two backends implement it:
 
-* :class:`NumpyBackend` — the host path: a Python loop over bundles with
-  in-place vectorized row updates.  Always available; the reference for
-  the bit-identical-selection contract.
-* :class:`JaxBackend` — the accelerator path: the same recurrence as a
-  ``jax.lax.scan`` under ``jit``, batched over stacked solve groups with
-  bucketed padding so recompilation is bounded.  Optionally (``pallas``
-  flag) the inner relaxation step runs as a Pallas kernel — interpreted
-  on CPU, lowerable on TPU/GPU — for the jax_pallas north star.
-* :class:`FusedJaxBackend` (``jax:fused`` / ``jax:fused:pallas``) — the
-  device-resident decision plane (DESIGN.md §13): whole GSS batches run
-  as two jitted programs (prescan grid + golden ``lax.while_loop``) with
-  the cover DP, backtrack, and pool scoring fused on device, market
-  arrays uploaded once per content digest, and a host replay that keeps
-  selections bit-identical to NumPy by construction.
+* :class:`NumpyBackend` — the host path and the reference: a Python loop
+  over bundles with in-place vectorized row updates.
+* :class:`FusedJaxBackend` (``jax:fused``) — the device-resident decision
+  plane (DESIGN.md §13): whole GSS batches run as two jitted programs
+  (prescan grid + golden ``lax.while_loop``) with the cover DP, backtrack,
+  and pool scoring fused on device, market arrays uploaded once per
+  content digest, and a host replay that consumes the recorded counts.
 
-Canonical kernel semantics (both backends, float64):
+Canonical kernel semantics (int64 costs, :mod:`repro.core.exact`):
 
-    dp[0] = 0, dp[j>0] = +inf
+    dp[0] = 0, dp[j>0] = INF
     for b in 0..B-1:                       # bundle order is significant
         cand[j] = dp[max(j - pods[b], 0)] + cost[b]      (j >= 1)
         bits[b, j] = cand[j] < dp[j]                     (bits[b, 0] = False)
         dp[j]    = min(dp[j], cand[j])                   (dp[0] pinned at 0)
 
-(The strict ``<`` needs no epsilon: dp values are exact subset-cost sums,
-so a strict improvement at (b, j) means every optimal solution of the
-bundle prefix uses b — the backtracker's take-rule — and equality means
-skipping b is optimal.  The seed solver's 1e-12 guard band protected a
-history matrix recomputed along a different float path; here bits and dp
-come from the same pass.)
-
-Every arithmetic step is an elementwise float64 op executed in the same
-order by both implementations, so the resulting ``dp``/``bits`` are
-bit-identical — which is what makes backend choice invisible to selections
-(the backtracker's tie-breaking reads only ``bits``).  The ``j``-prefix of
+dp values are exact subset-cost sums, so a strict improvement at (b, j)
+means every optimal solution of the bundle prefix uses b — the
+backtracker's take-rule — and equality means skipping b is optimal.
+Integer arithmetic is exact on every platform, so ``dp``/``bits`` — and
+with them selections — cannot depend on the backend.  The ``j``-prefix of
 ``dp``/``bits`` does not depend on the padded target length, so solve
 groups that share (costs, kept bundles) can share one padded row.
 
-JAX is an *optional* dependency of this path: importing this module never
-imports ``jax``.  Requesting the jax backend without jax installed warns
-once and falls back to :class:`NumpyBackend`
-(``KUBEPACS_SOLVER_BACKEND=numpy|jax|jax:pallas|jax:fused|jax:fused:pallas``
-overrides the default).
+Importing this module never imports ``jax``; ``make_backend("jax:fused")``
+does, and fails loudly where jax is missing
+(``KUBEPACS_SOLVER_BACKEND=numpy|jax:fused`` overrides the default).
 """
 
 from __future__ import annotations
@@ -57,15 +41,20 @@ import collections
 import dataclasses
 import math
 import os
+import pathlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import events_log
+from . import events_log, exact
 
 #: one (bpods, costs, target) residual covering problem; ``bpods`` int64
-#: (all >= 1), ``costs`` float64 (may contain +inf), ``target`` >= 1
+#: (all >= 1), ``costs`` int64 (all >= 0), ``target`` >= 1
 CoverGroup = Tuple[np.ndarray, np.ndarray, int]
+
+#: the repository checkout (``src/repro/core/backend.py`` → root): the
+#: fixed home of the persistent compilation cache
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,17 +119,14 @@ class SolverBackend:
     name = "abstract"
 
     #: engine hint: decode in slices of at most this many DP groups so the
-    #: bits arrays of one slice die before the next is computed (the host
-    #: path is cache/allocator-sensitive; accelerator backends want the
-    #: whole stack in one dispatch and override with a large value)
+    #: bits arrays of one slice die before the next is computed
     max_group_batch = 1 << 30
 
     def cover_bits(self, groups: Sequence[CoverGroup],
                    ) -> List[Tuple[np.ndarray, np.ndarray]]:
-        """For each group return ``(dp, bits)`` — ``dp`` float64 of shape
+        """For each group return ``(dp, bits)`` — ``dp`` int64 of shape
         ``(target+1,)`` and ``bits`` bool of shape ``(B, target+1)`` — per
-        the canonical kernel above.  Implementations may stack groups into
-        one padded dispatch; returned arrays are trimmed numpy arrays."""
+        the canonical kernel above."""
         raise NotImplementedError
 
     def cover_values(self, groups: Sequence[CoverGroup]) -> List[np.ndarray]:
@@ -154,35 +140,29 @@ class NumpyBackend(SolverBackend):
 
     Runs each group's forward pass with preallocated scratch rows (the
     pass is memory-bandwidth-bound; allocator churn is the only other
-    cost worth removing) and skips +inf bundles outright — an inert
-    bundle's candidates never beat the running ``dp``, so skipping is
-    exact.
+    cost worth removing).
     """
 
     name = "numpy"
     max_group_batch = 8      # keep the live bits working set cache-sized
 
     def cover_bits(self, groups):
-        scratch = np.empty(max((g[2] for g in groups), default=0) + 1)
+        scratch = _scratch(groups)
         return [self._one(bpods, costs, target, scratch)
                 for bpods, costs, target in groups]
 
     def cover_values(self, groups):
-        scratch = np.empty(max((g[2] for g in groups), default=0) + 1)
+        scratch = _scratch(groups)
         return [self._values(bpods, costs, target, scratch)
                 for bpods, costs, target in groups]
 
     @staticmethod
     def _values(bpods: np.ndarray, costs: np.ndarray, target: int,
-                scratch: Optional[np.ndarray] = None) -> np.ndarray:
-        if scratch is None:
-            scratch = np.empty(target + 1)
-        dp = np.full(target + 1, np.inf)
-        dp[0] = 0.0
+                scratch: np.ndarray) -> np.ndarray:
+        dp = np.full(target + 1, exact.INF, dtype=np.int64)
+        dp[0] = 0
         for b in range(len(bpods)):
             cb = costs[b]
-            if not np.isfinite(cb):
-                continue
             pb = int(bpods[b])
             if pb <= target:
                 k = target + 1 - pb
@@ -196,21 +176,15 @@ class NumpyBackend(SolverBackend):
 
     @staticmethod
     def _one(bpods: np.ndarray, costs: np.ndarray, target: int,
-             scratch: Optional[np.ndarray] = None,
-             ) -> Tuple[np.ndarray, np.ndarray]:
+             scratch: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         B = len(bpods)
-        if scratch is None:
-            scratch = np.empty(target + 1)
-        dp = np.full(target + 1, np.inf)
-        dp[0] = 0.0
-        # every finite bundle's row is fully written below (j >= 1) and the
-        # j = 0 column is blanked at the end, so empty beats zeros here
+        dp = np.full(target + 1, exact.INF, dtype=np.int64)
+        dp[0] = 0
+        # every bundle's row is fully written below (j >= 1) and the j = 0
+        # column is blanked at the end, so empty beats zeros here
         bits = np.empty((B, target + 1), dtype=bool)
         for b in range(B):
             cb = costs[b]
-            if not np.isfinite(cb):
-                bits[b] = False   # cand = x + inf never beats dp
-                continue
             pb = int(bpods[b])
             if pb <= target:
                 # j in [pb, target]: cand = dp[j - pb] + cb (pre-update dp;
@@ -229,6 +203,11 @@ class NumpyBackend(SolverBackend):
         return dp, bits
 
 
+def _scratch(groups: Sequence[CoverGroup]) -> np.ndarray:
+    return np.empty(max((g[2] for g in groups), default=0) + 1,
+                    dtype=np.int64)
+
+
 def _bucket(n: int, steps: Sequence[int]) -> int:
     """Round ``n`` up to the smallest bucket (bounds jit recompilation)."""
     for s in steps:
@@ -239,16 +218,15 @@ def _bucket(n: int, steps: Sequence[int]) -> int:
 
 
 def _ensure_x64(jax) -> None:
-    """Backend-init x64 check: the float64 kernel contract (module
-    docstring) requires ``jax_enable_x64``.  Enabling it is *process-wide*
-    — a global-config mutation co-resident JAX code in the embedding
-    application may not expect (float32 default semantics change, programs
-    compiled before the flip retrace) — so the flip is announced with a
-    one-time ``RuntimeWarning`` (counted in ``repro.core.events_log``),
-    and ``KUBEPACS_JAX_X64=0`` forbids it outright: the embedder must then
-    enable x64 itself before constructing a jax backend, and construction
-    fails loudly rather than silently running the solver outside its
-    float64 contract."""
+    """Backend-init x64 check: the device plane's int64 costs require
+    ``jax_enable_x64``.  Enabling it is *process-wide* — a global-config
+    mutation co-resident JAX code in the embedding application may not
+    expect (default dtypes change, programs compiled before the flip
+    retrace) — so the flip is announced with a one-time ``RuntimeWarning``
+    (counted in ``repro.core.events_log``), and ``KUBEPACS_JAX_X64=0``
+    forbids it outright: the embedder must then enable x64 itself before
+    constructing a jax backend, and construction fails loudly rather than
+    silently running the solver outside its int64 contract."""
     if jax.config.jax_enable_x64:
         return
     if os.environ.get("KUBEPACS_JAX_X64", "1").lower() in ("0", "false",
@@ -261,173 +239,40 @@ def _ensure_x64(jax) -> None:
     events_log.warn_once(
         "backend_x64_flip",
         "KubePACS jax backend is enabling jax_enable_x64 process-wide "
-        "(the solver's float64 bit-identity contract); set "
-        "KUBEPACS_JAX_X64=0 to forbid this and manage the flag in the "
-        "embedding application instead", RuntimeWarning, stacklevel=3)
+        "(the decision plane's int64 costs); set KUBEPACS_JAX_X64=0 to "
+        "forbid this and manage the flag in the embedding application "
+        "instead", RuntimeWarning, stacklevel=3)
     jax.config.update("jax_enable_x64", True)
 
 
-class JaxBackend(SolverBackend):
-    """``jax.lax.scan`` cover-DP, jitted, batched over padded groups.
+def compile_cache_dir() -> pathlib.Path:
+    """Where compiled device programs persist: ``JAX_COMPILATION_CACHE_DIR``
+    when set (JAX reads the variable itself), else ``<checkout>/.jax_cache``
+    — a fixed path, since the directory is part of the cache key."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return pathlib.Path(env) if env else _CHECKOUT / ".jax_cache"
 
-    Groups are stacked into one ``(G, B_pad, R_pad)`` dispatch per call;
-    pad bundles carry ``pods=1, cost=+inf`` (inert), pad target columns are
-    never read back (the kernel's ``j``-prefix is padding-independent).
-    ``G``/``B``/``R`` are bucketed so the jit cache stays small across the
-    varying shapes of a simulation run.  All arithmetic runs in float64:
-    constructing any jax backend enables x64 *process-wide* once (an
-    idempotent ``jax.config.update`` at init, announced by a one-time
-    ``RuntimeWarning``; ``KUBEPACS_JAX_X64=0`` forbids the mutation and
-    makes the embedding application responsible for the flag — see
-    :func:`_ensure_x64`).  The earlier per-dispatch ``enable_x64`` scoping
-    flipped global trace state between callers, which forced jit re-traces
-    of long-lived programs (the fused ``while_loop`` below most of all)
-    whenever a non-x64 caller ran in between; a process-level init check
-    costs nothing and keeps every compiled program valid for the life of
-    the process.
 
-    ``pallas=True`` swaps the inner relaxation step for a Pallas kernel
-    (`repro.kernels` idiom); on CPU it runs in interpreter mode — a
-    correctness/bring-up path, not a fast one — while TPU/GPU lower it.
-    """
-
-    name = "jax"
-
-    #: bucket ladders: fine at small sizes, coarse (multiples of the last
-    #: step) beyond, keeping padding waste and recompiles both bounded
-    _G_STEPS = (1, 2, 4, 8, 16, 32, 64)
-    _B_STEPS = (16, 32, 64, 128, 256, 512)
-    _R_STEPS = (256, 512, 1024, 2048)
-
-    def __init__(self, pallas: bool = False):
-        import jax  # deferred: jax is optional for the solver path
-
-        _ensure_x64(jax)
-        self._jax = jax
-        self._jnp = jax.numpy
-        self.pallas = bool(pallas)
-        if pallas:
-            self.name = "jax:pallas"
-        self._jit_cache: dict = {}
-
-    # -- kernel construction -------------------------------------------------
-    def _step_fn(self, interpret: bool):
-        jnp = self._jnp
-        if not self.pallas:
-            def step(dp, xs):
-                pb, cb = xs                                  # (G,), (G,)
-                jidx = jnp.arange(dp.shape[1])
-                idx = jnp.maximum(jidx[None, :] - pb[:, None], 0)
-                cand = jnp.take_along_axis(dp, idx, axis=1) + cb[:, None]
-                cand = cand.at[:, 0].set(jnp.inf)            # dp[0] pinned
-                bit = cand < dp
-                return jnp.minimum(dp, cand), bit
-            return step
-
-        from jax.experimental import pallas as pl
-
-        def relax_kernel(dp_ref, pb_ref, cb_ref, out_ref, bit_ref):
-            dp = dp_ref[...]                                 # (G, R+1)
-            pb = pb_ref[...]                                 # (G, 1)
-            cb = cb_ref[...]                                 # (G, 1)
-            jidx = self._jax.lax.broadcasted_iota(
-                jnp.int64, dp.shape, dimension=1)
-            idx = jnp.maximum(jidx - pb, 0)
-            cand = jnp.take_along_axis(dp, idx, axis=1) + cb
-            cand = jnp.where(jidx == 0, jnp.inf, cand)
-            bit_ref[...] = cand < dp
-            out_ref[...] = jnp.minimum(dp, cand)
-
-        def step(dp, xs):
-            pb, cb = xs
-            new_dp, bit = pl.pallas_call(
-                relax_kernel,
-                out_shape=(
-                    self._jax.ShapeDtypeStruct(dp.shape, dp.dtype),
-                    self._jax.ShapeDtypeStruct(dp.shape, jnp.bool_),
-                ),
-                interpret=interpret,
-            )(dp, pb[:, None], cb[:, None].astype(dp.dtype))
-            return new_dp, bit
-        return step
-
-    def _compiled(self, G: int, B: int, R: int, with_bits: bool = True):
-        key = (G, B, R, with_bits)
-        fn = self._jit_cache.get(key)
-        if fn is None:
-            jax, jnp = self._jax, self._jnp
-            interpret = jax.default_backend() == "cpu"
-            step = self._step_fn(interpret)
-
-            def run(bpods, costs):                  # (G, B) int64 / float64
-                dp0 = jnp.full((G, R + 1), jnp.inf,
-                               dtype=jnp.float64).at[:, 0].set(0.0)
-                if with_bits:
-                    dp, bits = jax.lax.scan(step, dp0, (bpods.T, costs.T))
-                    return dp, jnp.moveaxis(bits, 0, 1)      # (G, B, R+1)
-                dp, _ = jax.lax.scan(
-                    lambda d, xs: (step(d, xs)[0], None), dp0,
-                    (bpods.T, costs.T))
-                return dp
-
-            fn = jax.jit(run)
-            self._jit_cache[key] = fn
-        return fn
-
-    # -- public API ----------------------------------------------------------
-    def cover_bits(self, groups):
-        return self._dispatch(groups, with_bits=True)
-
-    def cover_values(self, groups):
-        return self._dispatch(groups, with_bits=False)
-
-    def _dispatch(self, groups, with_bits: bool):
-        if not groups:
-            return []
-        # partition groups into (B, R) shape buckets so one outlier group
-        # does not pad every other dispatch up to its size
-        buckets: dict = {}
-        for i, (bp, _bc, t) in enumerate(groups):
-            key = (_bucket(len(bp), self._B_STEPS),
-                   _bucket(t, self._R_STEPS))
-            buckets.setdefault(key, []).append(i)
-        out: List = [None] * len(groups)
-        for (B, R), idxs in buckets.items():
-            G = _bucket(len(idxs), self._G_STEPS)
-            bpods = np.ones((G, B), dtype=np.int64)
-            costs = np.full((G, B), np.inf)
-            for g, i in enumerate(idxs):
-                bp, bc, _t = groups[i]
-                bpods[g, :len(bp)] = bp
-                costs[g, :len(bc)] = bc
-            res = self._compiled(G, B, R, with_bits)(bpods, costs)
-            if with_bits:
-                dp = np.asarray(res[0])
-                bits = np.asarray(res[1])
-                for g, i in enumerate(idxs):
-                    bp, _bc, t = groups[i]
-                    out[i] = (dp[g, :t + 1], bits[g, :len(bp), :t + 1])
-            else:
-                dp = np.asarray(res)
-                for g, i in enumerate(idxs):
-                    out[i] = dp[g, :groups[i][2] + 1]
-        return out
+def _configure_compile_cache(jax) -> None:
+    """Point JAX's persistent compilation cache at :func:`compile_cache_dir`
+    before the first device program compiles (no-op when the variable is
+    set, or when the embedding application chose a directory itself)."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    if jax.config.jax_compilation_cache_dir:
+        return
+    jax.config.update("jax_compilation_cache_dir", str(compile_cache_dir()))
 
 
 # ---------------------------------------------------------------------------
 # Fused device-resident decision plane (DESIGN.md §13)
 # ---------------------------------------------------------------------------
 
-#: golden ratio shrink factor — the same expression as ``repro.core.gss.PHI``
-#: (both evaluate ``(sqrt(5)-1)/2`` in float64, so the constants are
-#: bit-identical; gss cannot import it from here without a cycle)
-_PHI = (math.sqrt(5.0) - 1.0) / 2.0
-
 _MISS = object()      # lookup sentinel (stored values include None)
 
 
 def _rc_tiers(RC: int) -> List[int]:
-    """Geometric DP-width ladder ``129, 257, 513, …, RC``.
+    """Geometric DP-width ladder ``129, 513, 2049, …, RC``.
 
     The cover DP is prefix-closed in the pod index ``j``: every value the
     solver reads for a row with residual ``r`` lives in ``dp[: r + 1]``,
@@ -446,19 +291,12 @@ def _rc_tiers(RC: int) -> List[int]:
     tiers.append(RC)
     return tiers
 
-#: device-market array order (one tuple per cache entry, jit-stable)
-_MD_FIELDS = ("pods", "bound", "perf", "price", "structural", "real",
-              "b_item", "b_pods", "b_podsf", "b_copies", "b_copiesf",
-              "b_struct")
 
-
-class FusedJaxBackend(JaxBackend):
+class FusedJaxBackend(NumpyBackend):
     """Fully device-resident decision plane (``make_backend("jax:fused")``).
 
-    Instead of dispatching one cover-DP per golden-section probe (the
-    per-round host↔device round-trips that made PR 5's jax path lose to
-    NumPy), this backend runs the *entire* bracketed GSS on device as two
-    jitted programs:
+    Runs the *entire* bracketed GSS of a ``bracketed_gss_many`` batch on
+    device as two jitted programs:
 
     * **prescan** — every (decision, grid-α) objective row solved in one
       program: saturation analysis, LP-bound bundle pruning, core-DP bound
@@ -467,63 +305,47 @@ class FusedJaxBackend(JaxBackend):
     * **golden** — a single ``lax.while_loop`` over golden rounds advancing
       all decisions in lockstep: per round one fused solve of each active
       decision's probe α plus on-device pool scoring (the ``e_total``
-      formula) to steer the bracket update — no host round-trips between
-      probes.
+      formula, float32) to steer the bracket update — no host round-trips
+      between probes.
 
-    **Bit-identical-by-construction contract.**  The device never *decides*
-    anything the host cannot check: every probe's (α, counts) pair is
-    recorded on device and read back once, and the host replay
-    (:class:`_FusedGssRecord` driven by ``bracketed_gss_many``) re-runs the
-    sequential control flow with exact host floats, consuming recorded
-    counts via exact-bitwise α lookup.  Recorded counts are bitwise equal to
-    the host engine's because every arithmetic step of the device row
-    solver mirrors ``repro.core.ilp._solve_rows`` op-for-op (same float64
-    elementwise ops in the same order — sequential-scan cumsums, stable
-    argsorts, identical prune thresholds), with one hazard actively
-    defused: XLA:CPU's LLVM backend contracts ``a*b`` feeding ``c+...``
-    into an FMA inside fused loops, which rounds once where NumPy rounds
-    twice.  Every value-critical product therefore goes through ``rmul`` —
-    round, then bitcast to int64 and XOR with a runtime-zero argument —
-    which is opaque to constant folding and instruction combining, so the
-    product reaches the add pre-rounded exactly like the host's.  A startup
-    self-check verifies this on the live XLA build and disables the fused
-    path (falling back to per-round dispatch) if it fails.  If device
-    control ever diverges from host control (speculation scores disagree
-    with exact scores on a bracket comparison), the host replay simply
-    misses a lookup and solves that α on the NumPy backend — a counted
-    performance event (``fallback_solves``), never a correctness one.
+    **Exact by construction.**  Every value that decides a selection is
+    an integer of :mod:`repro.core.exact` — grid-index α, quantized
+    coefficients, int64 costs, prefix sums, LP bounds and DP values — and
+    the device row solver computes the same expressions as
+    ``repro.core.ilp._solve_rows`` (same stable integer sort, same prune
+    comparisons, same backtrack).  Recorded counts therefore equal the
+    host engine's on any platform with exact integer arithmetic; the chip
+    supplies that, while its float64 is a double-float32 emulation.  Only
+    the speculative scores that steer the device's bracket updates are
+    float32; the host replay (:class:`_FusedGssRecord`) re-runs the
+    control flow with exact host scores and resolves every probe by
+    grid-index lookup.  A lookup miss (device scores ordered two probes
+    differently) is solved on the host engine and counted
+    (``fallback_solves``); every batch also re-solves one sampled prescan
+    row on the host and raises :class:`PrescanMismatch` if it differs.
+
+    Device errors propagate: no path here turns a compile or run failure
+    into a host solve.  The plane implements ``bracketed_gss_many``; the
+    documented limits run on this backend's inherited NumPy cover DP and
+    are counted: a batch that needs the approximate coarsening tier (or
+    has an empty market) is *declined* (``declined_batches``), and every
+    cover-DP group solved through the inherited path — declined batches
+    and entry points without a device program, such as a single
+    ``solve_ilp`` — counts in ``host_dp_groups``.
 
     **Device residency.**  ``CompiledMarket`` arrays are uploaded once and
     cached on device keyed by ``(market.digest, N_pad, B_pad)`` (LRU,
     ``device_cache_info()`` exposes hit/miss counters), so FleetSim ticks
-    re-dispatch onto resident arrays; per-item state (masks, demands,
-    brackets) is the only per-tick upload.
-
-    ``pallas=True`` (spec ``"jax:fused:pallas"``) swaps the scan cover-DP
-    stage for a Pallas kernel — grid over bundle blocks, BlockSpec-tiled
-    value rows, improvement bits emitted in-kernel — plus a Pallas scoring
-    kernel; on CPU both run in interpreter mode (a bring-up path), off-CPU
-    they lower (f64 Pallas does not lower on TPU).  With the default
-    ``"jax:fused"`` spec, Pallas is *requested* automatically off-CPU and
-    the ``lax.scan``/``while_loop`` path is the CPU fallback inside the
-    same fused program — but every Pallas request (auto or forced) is
-    gated on :meth:`_pallas_ok`, a one-time bitwise probe of the cover
-    kernel against the NumPy reference on the live lowering.  The cover
-    kernel's revisited-accumulator idiom requires *sequential* grid
-    execution, which interpret mode and TPU guarantee but the GPU (Triton)
-    lowering does not — there grid programs run concurrently and the
-    loop-carried dp row races — so a lowering that cannot reproduce the
-    host bitwise keeps the scan path instead of silently corrupting
-    selections.
+    re-dispatch onto resident arrays; per-decision coefficient vectors,
+    masks, demands and brackets are the only per-tick upload.
     """
 
     name = "jax:fused"
     supports_fused_gss = True
 
-    #: fused-program bucket ladders.  R is deliberately finer than the base
-    #: backend's (512-multiples beyond 512): every vector op in the fused
-    #: row solver is O(R_pad), so 2048-jump padding would tax each row far
-    #: more than the extra recompiles cost.
+    #: fused-program bucket ladders.  R is deliberately fine (512-multiples
+    #: beyond 512): every vector op in the fused row solver is O(R_pad), so
+    #: coarse padding would tax each row far more than extra recompiles.
     _N_STEPS = (16, 32, 64, 128, 256, 512, 1024)
     _BF_STEPS = (32, 64, 128, 192, 256, 384, 512, 640, 768, 896, 1024,
                  1152, 1280, 1536, 2048)
@@ -531,27 +353,38 @@ class FusedJaxBackend(JaxBackend):
     _D_STEPS = (1, 2, 4, 8, 16, 32, 64)
     _MAX_MARKETS = 8
 
-    def __init__(self, pallas: bool = False):
-        super().__init__(pallas=False)   # base scan path stays the fallback
-        self.fused_pallas = bool(pallas)
-        if pallas:
-            self.name = "jax:fused:pallas"
+    def __init__(self):
+        import jax  # deferred: importing this module never imports jax
+
+        _ensure_x64(jax)
+        _configure_compile_cache(jax)
+        self._jax = jax
+        self._jnp = jax.numpy
         self._market_cache: "collections.OrderedDict" = \
             collections.OrderedDict()
         self._fused_cache: dict = {}
-        self._host_fallback = NumpyBackend()
+        self._host = NumpyBackend()
         self.device_cache_hits = 0
         self.device_cache_misses = 0
         self.fallback_solves = 0
         self.fused_records = 0
+        self.declined_batches = 0
+        self.host_dp_groups = 0
         self.program_builds = 0
         self.verify_solves = 0
-        self._selfcheck_ok: Optional[bool] = None
-        self._pallas_checked: Optional[bool] = None
+
+    def cover_bits(self, groups):
+        self.host_dp_groups += len(groups)
+        return super().cover_bits(groups)
+
+    def cover_values(self, groups):
+        self.host_dp_groups += len(groups)
+        return super().cover_values(groups)
 
     # -- device market cache -------------------------------------------------
     def _device_market(self, market, N: int, B: int):
-        """Upload-once market arrays, keyed on (content digest, pad shape)."""
+        """Upload-once market arrays, keyed on (content digest, pad shape).
+        Pad items have no pods and no bound; pad bundles are not real."""
         key = (market.digest, N, B)
         ent = self._market_cache.get(key)
         if ent is not None:
@@ -559,32 +392,22 @@ class FusedJaxBackend(JaxBackend):
             self._market_cache.move_to_end(key)
             return ent
         self.device_cache_misses += 1
-        jnp = self._jnp
         n, nb = market.n, market.n_bundles
-        pods = np.zeros(N, np.int64)
-        pods[:n] = market.pods
-        bound = np.zeros(N, np.int64)
-        bound[:n] = market.bound
-        perf = np.zeros(N)
-        perf[:n] = market.perf
-        price = np.ones(N)
-        price[:n] = market.price
-        structural = np.zeros(N, bool)
-        structural[:n] = market.structural
-        real = np.zeros(N, bool)
-        real[:n] = True
-        b_item = np.zeros(B, np.int64)
-        b_item[:nb] = market.b_item
-        b_pods = np.ones(B, np.int64)
-        b_pods[:nb] = market.b_pods
-        b_copies = np.zeros(B, np.int64)
-        b_copies[:nb] = market.b_copies
-        b_struct = np.zeros(B, bool)
-        b_struct[:nb] = True
-        ent = tuple(jnp.asarray(a) for a in (
-            pods, bound, perf, price, structural, real, b_item, b_pods,
-            b_pods.astype(np.float64), b_copies,
-            b_copies.astype(np.float64), b_struct))
+
+        def pad(a, size, dtype, fill=0):
+            out = np.full(size, fill, dtype=dtype)
+            out[:len(a)] = a
+            return out
+
+        ent = tuple(self._jnp.asarray(a) for a in (
+            pad(market.pods, N, np.int32),
+            pad(market.bound, N, np.int32),
+            pad(market.b_item, B, np.int32),
+            pad(market.b_pods, B, np.int32, fill=1),
+            pad(market.b_copies, B, np.int32),
+            pad(np.ones(nb, bool), B, bool),
+            pad(market.perf, N, np.float32),
+            pad(market.price, N, np.float32, fill=1.0)))
         self._market_cache[key] = ent
         while len(self._market_cache) > self._MAX_MARKETS:
             self._market_cache.popitem(last=False)
@@ -594,569 +417,316 @@ class FusedJaxBackend(JaxBackend):
         return {"hits": self.device_cache_hits,
                 "misses": self.device_cache_misses,
                 "entries": len(self._market_cache),
+                "fused_records": self.fused_records,
+                "declined_batches": self.declined_batches,
+                "host_dp_groups": self.host_dp_groups,
                 "fallback_solves": self.fallback_solves,
                 "verify_solves": self.verify_solves,
                 "program_builds": self.program_builds}
 
-    def _fused_flags(self) -> Tuple[bool, bool]:
-        on_cpu = self._jax.default_backend() == "cpu"
-        want_pallas = self.fused_pallas or not on_cpu
-        return (want_pallas and self._pallas_ok(on_cpu)), on_cpu
-
-    # -- Pallas cover-DP kernel (shared by the fused programs and the
-    # kernel self-check) ------------------------------------------------------
-    def _pallas_cover_fn(self, W: int, B: int, interpret: bool):
-        """Build ``pallas_cover(pseq, cseq) -> (dp, bits)`` at tier width
-        ``W`` over ``B`` padded bundles: grid over bundle blocks, the
-        (1, W) dp value row revisited as the same output block every grid
-        step (accumulator idiom), improvement bits emitted in-kernel into
-        each block's (block_b, W) tile.  Masked bundles (cost +inf) are
-        inert: cand = sh + inf never beats dp.
-
-        The accumulator idiom makes grid steps *sequentially dependent* —
-        correct wherever the grid executes in order (interpret mode, TPU)
-        and racy under parallel-grid lowerings (GPU/Triton) — which is why
-        every production use is gated on :meth:`_pallas_ok`'s bitwise
-        probe of this very builder."""
-        jax, jnp = self._jax, self._jnp
-        lax = jax.lax
-        f64 = jnp.float64
-        from jax.experimental import pallas as pl
-
-        block_b = min(B, 32)
-        if B % block_b:
-            raise ValueError(
-                f"pallas cover kernel: bundle pad B={B} is not a multiple "
-                f"of block_b={block_b} — grid=(B // block_b,) would "
-                "silently drop the remainder bundles; every _BF_STEPS "
-                "rung (and the beyond-ladder rounding step) must stay a "
-                "multiple of 32")
-
-        def _cover_kernel(pb_ref, cb_ref, dp_ref, bits_ref):
-            @pl.when(pl.program_id(0) == 0)
-            def _init():
-                dp_ref[...] = jnp.full((1, W), jnp.inf,
-                                       dtype=f64).at[0, 0].set(0.0)
-
-            jcol = lax.broadcasted_iota(jnp.int32, (1, W), 1)
-
-            def body(i, dp):
-                pb = pb_ref[i]
-                cb = cb_ref[i]
-                pbc = jnp.clip(pb, 0, W).astype(jnp.int32)
-                ext = jnp.concatenate(
-                    [jnp.zeros((1, W), f64), dp], axis=1)
-                sh = lax.dynamic_slice(
-                    ext, (jnp.int32(0), W - pbc), (1, W))
-                cand = jnp.where(jcol == 0, jnp.inf, sh + cb)
-                bits_ref[i, :] = (cand < dp)[0]
-                return jnp.minimum(dp, cand)
-
-            dp_ref[...] = lax.fori_loop(0, block_b, body, dp_ref[...])
-
-        def pallas_cover(pseq, cseq):
-            dp, bits = pl.pallas_call(
-                _cover_kernel,
-                grid=(B // block_b,),
-                in_specs=[
-                    pl.BlockSpec((block_b,), lambda k: (k,)),
-                    pl.BlockSpec((block_b,), lambda k: (k,)),
-                ],
-                out_specs=(
-                    pl.BlockSpec((1, W), lambda k: (0, 0)),
-                    pl.BlockSpec((block_b, W), lambda k: (k, 0)),
-                ),
-                out_shape=(
-                    jax.ShapeDtypeStruct((1, W), f64),
-                    jax.ShapeDtypeStruct((B, W), jnp.bool_),
-                ),
-                interpret=interpret,
-            )(pseq, cseq)
-            return dp[0], bits
-
-        return pallas_cover
-
-    def _pallas_ok(self, interpret: bool) -> bool:
-        """One-time bitwise probe of the Pallas cover kernel on the live
-        lowering.  The kernel assumes sequential grid execution (see
-        :meth:`_pallas_cover_fn`); rather than hard-coding platform
-        assumptions, solve a reference bundle sequence through the real
-        kernel — same interpret flag as production — and require dp *and*
-        bits bitwise equal to the NumPy reference.  Any mismatch (e.g. a
-        parallel-grid GPU lowering racing the dp accumulator) or lowering
-        failure keeps the fused programs on the ``lax.scan`` path: same
-        selections, no Pallas."""
-        if self._pallas_checked is None:
-            try:
-                self._pallas_checked = self._run_pallas_check(interpret)
-            except Exception as exc:  # pragma: no cover - lowering-specific
-                events_log.warn_once(
-                    "backend_pallas_disabled",
-                    "pallas cover-DP kernel disabled (self-check raised "
-                    f"{exc!r}); fused programs use the lax.scan path",
-                    RuntimeWarning)
-                self._pallas_checked = False
-        return self._pallas_checked
-
-    def _run_pallas_check(self, interpret: bool) -> bool:
-        W, B = 129, 256     # 8 grid blocks: a parallel lowering must race
-        cover = self._jax.jit(self._pallas_cover_fn(W, B, interpret))
-        rng = np.random.default_rng(17)
-        pods = rng.integers(1, 200, size=B)     # > W rows hit the clip path
-        costs = rng.uniform(0.01, 3.0, size=B)
-        costs[rng.random(B) < 0.25] = np.inf
-        dp_d, bits_d = cover(pods.astype(np.int64), costs)
-        dp_h, bits_h = NumpyBackend._one(pods.astype(np.int64), costs, W - 1)
-        ok = (np.asarray(dp_d).tobytes() == dp_h.tobytes()
-              and np.array_equal(np.asarray(bits_d), bits_h))
-        if not ok:   # pragma: no cover - depends on lowering
-            events_log.warn_once(
-                "backend_pallas_disabled",
-                "pallas cover-DP kernel disabled: device dp/bits do not "
-                "match the host reference on this backend (parallel grid "
-                "execution?); fused programs use the lax.scan path",
-                RuntimeWarning)
-        return ok
-
     # -- the device row solver (traced context) ------------------------------
-    def _solver_core(self, md, z, N: int, B: int, RC: int,
-                     use_pallas: bool, interpret: bool, coarse=None):
-        """Build the traced-closure toolkit shared by both fused programs.
+    def _solver_core(self, md, N: int, B: int, RC: int, coarse):
+        """Build the traced closures shared by both fused programs.
 
-        Returns ``(rmul, prep, solve_row, solve_rows, score)``.
-        ``solve_row(coef, active, req) -> (counts, feasible)`` replicates
-        one ``repro.core.ilp._solve_rows`` row end to end on device; every
-        float op mirrors the host op-for-op (see class docstring).
-        ``solve_rows`` is its batched form.
+        Returns ``(solve_rows, score)``.  ``solve_rows(coefs, actives,
+        reqs)`` solves a stack of engine rows — each one
+        ``repro.core.ilp._solve_rows`` row end to end on its exact int64
+        coefficient row — returning ``(counts int32 (D, N), feasible)``.
 
         ``coarse`` is the traced ``(threshold, max_rows, gcd)`` int64
-        triple of the active :class:`CoarseningConfig` (``None`` =
-        coarsening off).  Rows whose residual exceeds the threshold and
-        whose pods all share the market gcd run the DP stages at
-        granularity ``g`` — exactly the host engine's gcd mode, so
-        recorded counts stay bit-identical (prune math is deliberately
-        left unscaled, matching the host's identical keep sets; only the
-        core-bound DP, decode DP, and backtrack use scaled pods/targets,
-        which the gcd-exactness theorem makes bitwise equal to the
-        unscaled pass).  Traced scalars, not static: changing the config
-        or the market gcd never recompiles the programs.
+        triple of the active :class:`CoarseningConfig`.  Rows whose
+        residual exceeds the threshold and whose pods all share the market
+        gcd run the DP stages at granularity ``g`` — exactly the host
+        engine's gcd mode (prune math stays unscaled, matching the host's
+        identical keep sets; only the core-bound DP, decode DP, and
+        backtrack use scaled pods/targets).  Traced scalars, not static:
+        changing the config or the market gcd never recompiles.
         """
         jax, jnp = self._jax, self._jnp
         lax = jax.lax
-        (pods, bound, perf, price, structural, real, b_item, b_pods,
-         b_podsf, b_copies, b_copiesf, b_struct) = md
-        f64, i64, inf = jnp.float64, jnp.int64, jnp.inf
-        if coarse is None:
-            c_thr, c_maxr, c_gcd = i64(2 ** 62), i64(1), i64(1)
-        else:
-            c_thr, c_maxr, c_gcd = (jnp.asarray(x, i64) for x in coarse)
-
-        def rmul(x, y):
-            # correctly-rounded product exactly as the host computes it:
-            # the bitcast^z detour (z is a runtime int64 zero argument) is
-            # opaque to XLA/LLVM simplification, so the value reaching any
-            # downstream add is the *rounded* product — XLA:CPU's LLVM
-            # backend cannot contract the multiply into an FMA
-            t = x * y
-            return lax.bitcast_convert_type(
-                lax.bitcast_convert_type(t, i64) ^ z, f64)
-
-        def seqsum(v):
-            # np.cumsum semantics: strictly sequential left-to-right adds
-            # (jnp.cumsum reassociates above ~100 elements); unrolled so
-            # the scalar chain is not one XLA loop iteration per element
-            def step(c, x):
-                c = c + x
-                return c, c
-            return lax.scan(step, f64(0.0), v, unroll=64)[1]
-
-        def prep(excl):
-            # per-decision masked normalisation == CompiledMarket.norms:
-            # mins over ~exclude (perf restricted to positive entries),
-            # empty masks degrading to 1.0 exactly like the host
-            mreal = (~excl) & real[None, :]
-            pmask = mreal & (perf > 0.0)[None, :]
-            pmin = jnp.min(jnp.where(pmask, perf[None, :], inf), axis=1)
-            perf_min = jnp.where(jnp.any(pmask, axis=1), pmin, 1.0)
-            smin = jnp.min(jnp.where(mreal, price[None, :], inf), axis=1)
-            sp_min = jnp.where(jnp.isfinite(smin), smin, 1.0)
-            pn = perf[None, :] / perf_min[:, None]
-            qn = price[None, :] / sp_min[:, None]
-            active = structural[None, :] & ~excl
-            return pn, qn, active
+        pods, bound, b_item, b_pods, b_copies, b_real, perf, price = md
+        i32, i64 = jnp.int32, jnp.int64
+        INF = i64(exact.INF)
+        c_thr, c_maxr, c_gcd = coarse
+        pods64 = pods.astype(i64)
+        item_nodes = pods64 * bound.astype(i64)
+        podsf = pods.astype(jnp.float32)
 
         # -- cover DP toolkit, one instance per residual-tier width ----------
         # dp lives as the back half of a (2*W,) extended vector whose front
         # half is zeros: the shifted read dp[j - pb] (with dp[0] = 0 for
         # j < pb) becomes one dynamic_slice at start W - clip(pb) — no
-        # gather — and 0.0 + cb is bitwise the host's dp[0] + cb.  W is a
-        # static tier width > the row's residual (``_rc_tiers``): the DP
-        # recurrence is prefix-closed in j, so dp[j <= residual] — all a
-        # row ever reads — is identical at any W > residual, while the
-        # vector work per relax shrinks from O(RC) to O(W), matching the
-        # host engine's residual-sized dp rows.
+        # gather — and 0 + cb is the host's dp[0] + cb.  W is a static tier
+        # width > the row's residual (``_rc_tiers``): the DP recurrence is
+        # prefix-closed in j, so dp[j <= residual] — all a row ever reads
+        # — is identical at any W > residual.
         def dp_tools(W):
-            ext0 = jnp.concatenate(
-                [jnp.zeros(W), jnp.full(W, inf).at[0].set(0.0)])
+            ext0 = jnp.concatenate([jnp.zeros(W, i64),
+                                    jnp.full(W, INF).at[0].set(0)])
             first = jnp.arange(W) == 0
 
             def _relax(ext, pb, cb):
                 pbc = jnp.clip(pb, 0, W)
                 dp = lax.dynamic_slice(ext, (W,), (W,))
                 sh = lax.dynamic_slice(ext, (W - pbc,), (W,))
-                # dp[0] pinned at 0: where() fuses into the add pass
-                # (an .at[0].set copies the whole W vector per relax)
-                cand = jnp.where(first, inf, sh + cb)
-                bit = cand < dp
-                return lax.dynamic_update_slice(
-                    ext, jnp.minimum(dp, cand), (W,)), bit
+                cand = jnp.where(first, INF, sh + cb)
+                return (lax.dynamic_update_slice(
+                    ext, jnp.minimum(dp, cand), (W,)), cand < dp)
 
-            def cover_values(pseq, cseq, trip, residual):
+            def cover_value(pseq, cseq, trip, target):
                 def body(st):
                     i, ext = st
-                    ext, _bit = _relax(ext, pseq[i], cseq[i])
-                    return i + 1, ext
+                    return i + 1, _relax(ext, pseq[i], cseq[i])[0]
                 _i, ext = lax.while_loop(lambda st: st[0] < trip, body,
-                                         (i64(0), ext0))
-                return ext[W + residual]
+                                         (i32(0), ext0))
+                return ext[W + target]
 
-            def cover_bits_scan(kp, kc, trip, KB):
+            def cover_bits(kp, kc, trip):
                 def body(st):
                     i, ext, bits = st
                     ext, bit = _relax(ext, kp[i], kc[i])
                     bits = lax.dynamic_update_slice(bits, bit[None, :],
-                                                    (i, i64(0)))
+                                                    (i, i32(0)))
                     return i + 1, ext, bits
                 _i, _e, bits = lax.while_loop(
                     lambda st: st[0] < trip, body,
-                    (i64(0), ext0, jnp.zeros((KB, W), dtype=bool)))
+                    (i32(0), ext0, jnp.zeros((B, W), dtype=bool)))
                 return bits
 
-            if not use_pallas:
-                return cover_values, cover_bits_scan, None
-            return (cover_values, cover_bits_scan,
-                    self._pallas_cover_fn(W, B, interpret))
+            return cover_value, cover_bits
 
         tiers = _rc_tiers(RC)
         tier_tools = [dp_tools(W) for W in tiers]
 
-        # -- pool scoring ----------------------------------------------------
-        if use_pallas:
-            from jax.experimental import pallas as pl
-
-            def _score_kernel(cnt_ref, perf_ref, price_ref, pods_ref,
-                              req_ref, out_ref):
-                c = cnt_ref[0, :]
-                sp = jnp.sum(c * perf_ref[0, :])
-                sc = jnp.sum(c * price_ref[0, :])
-                sq = jnp.sum(c * pods_ref[0, :])
-                rq = req_ref[0]
-                ok = (sq >= rq) & (sc > 0.0) & (sq > 0.0)
-                out_ref[0] = jnp.where(ok, (sp / sc) * (rq / sq), 0.0)
-
-            def score(cnts, reqf):
-                D = cnts.shape[0]
-                return pl.pallas_call(
-                    _score_kernel,
-                    grid=(D,),
-                    in_specs=[
-                        pl.BlockSpec((1, N), lambda k: (k, 0)),
-                        pl.BlockSpec((1, N), lambda k: (0, 0)),
-                        pl.BlockSpec((1, N), lambda k: (0, 0)),
-                        pl.BlockSpec((1, N), lambda k: (0, 0)),
-                        pl.BlockSpec((1,), lambda k: (k,)),
-                    ],
-                    out_specs=pl.BlockSpec((1,), lambda k: (k,)),
-                    out_shape=jax.ShapeDtypeStruct((D,), f64),
-                    interpret=interpret,
-                )(cnts, perf[None, :], price[None, :],
-                  pods.astype(f64)[None, :], reqf)
-        else:
-            def score(cnts, reqf):
-                # speculation-only e_total: steers device bracket control,
-                # never replayed to the host (which rescores exactly)
-                sp = cnts @ perf
-                sc = cnts @ price
-                sq = cnts @ pods.astype(f64)
-                ok = (sq >= reqf) & (sc > 0.0) & (sq > 0.0)
-                return jnp.where(ok, (sp / sc) * (reqf / sq), 0.0)
+        def cumsum(v):
+            # integer prefix sums are exact in any association; the TPU
+            # compiler's reduce-window lowering of jnp.cumsum on int64
+            # overflows its scoped vector memory at B = 512
+            return lax.associative_scan(jnp.add, v)
 
         # -- one engine row on device ----------------------------------------
         def solve_row(coef, active, req):
-            neg = (coef < 0.0) & active
-            sat = jnp.where(neg, bound, i64(0))
-            covered = jnp.sum(jnp.where(neg, pods * bound, i64(0)))
-            residual = jnp.maximum(req - covered, 0)
+            neg = (coef < 0) & active
+            sat = jnp.where(neg, bound, 0)
+            covered = jnp.sum(jnp.where(neg, item_nodes, 0))
+            residual = jnp.maximum(req.astype(i64) - covered, 0)
             in_dp = active & ~neg
-            capacity = jnp.sum(jnp.where(in_dp, pods * bound, i64(0)))
+            capacity = jnp.sum(jnp.where(in_dp, item_nodes, 0))
+            feasible = capacity >= residual
 
             # gcd-mode coarsening decision, mirroring the host engine's
             # _plan_scale: the gcd divides every structural pod count, so
             # scaled DP/backtrack columns are bitwise the unscaled ones
-            # (DESIGN.md §14) — eff_g stays 1 (an exact identity: x // 1)
-            # below the threshold, keeping pre-coarsening numerics intact
+            # (DESIGN.md §14); eff_g = 1 below the threshold
             rs_g = (residual + c_gcd - 1) // c_gcd
             use_g = (residual > c_thr) & (c_gcd > 1) & (rs_g <= c_maxr)
-            eff_g = jnp.where(use_g, c_gcd, i64(1))
-            eff_res = (residual + eff_g - 1) // eff_g
+            eff_g = jnp.where(use_g, c_gcd, 1).astype(i64)
+            eff_res = ((residual + eff_g - 1) // eff_g).astype(i32)
 
-            def make_dp_case(tools):
-                cover_values, cover_bits_scan, pallas_cover = tools
+            def dp_part(_):
+                # masked-not-compacted: non-DP bundles sort last (key INF)
+                # with zero pods and cost, so the sorted prefix and its
+                # prefix sums are the host's compacted arrays while shapes
+                # stay static
+                bmask = in_dp[b_item] & b_real
+                bpods = jnp.where(bmask, b_pods, 0)
+                bcost = jnp.where(bmask, coef[b_item] * b_copies, 0)
+                rate = jnp.where(bmask, bcost // b_pods, INF)
+                order = jnp.argsort(rate, stable=True)
+                p_sorted = bpods[order]
+                c_sorted = bcost[order]
+                r_sorted = rate[order]
+                cum_p = cumsum(p_sorted.astype(i64))
+                cum_c = cumsum(c_sorted)
+                cum_r = cumsum(r_sorted * p_sorted)
 
-                def dp_case(_):
-                    # masked-not-compacted: excluded/saturated bundles get
-                    # cost +inf, so their rate sorts to the end and the
-                    # finite sorted prefix (and its sequential cumsums) is
-                    # bitwise the host's compacted arrays while shapes
-                    # stay static
-                    bmask = in_dp[b_item] & b_struct
-                    bcosts = jnp.where(bmask,
-                                       rmul(coef[b_item], b_copiesf), inf)
-                    rate = bcosts / b_podsf
-                    order = jnp.argsort(rate, stable=True)
-                    p_sorted = b_podsf[order]
-                    c_sorted = bcosts[order]
-                    cum_p = seqsum(p_sorted)
-                    cum_c = seqsum(c_sorted)
-                    k_ub = jnp.searchsorted(cum_p, residual.astype(f64))
-                    ub = cum_c[k_ub]
-                    rb = jnp.maximum(residual - b_pods, 0).astype(f64)
-                    kk = jnp.searchsorted(cum_p, rb)
-                    km = jnp.maximum(kk - 1, 0)
-                    prev_p = jnp.where(kk > 0, cum_p[km], 0.0)
-                    prev_c = jnp.where(kk > 0, cum_c[km], 0.0)
-                    lp = prev_c + rmul(rb - prev_p,
-                                       c_sorted[kk] / p_sorted[kk])
-                    lp = jnp.where(rb <= 0.0, 0.0, lp)
-                    keep = (bcosts + lp) <= rmul(ub, 1.0 + 1e-12) + 1e-9
-                    n_active = jnp.sum(bmask)
-                    # DP stages run at granularity eff_g (1 = exact); the
-                    # prune math above deliberately stays unscaled so the
-                    # keep set is the exact engine's
-                    b_pods_s = b_pods // eff_g
-                    pods_ord = b_pods_s[order]
+                def lp_lower(need):
+                    k = jnp.searchsorted(cum_p, need)
+                    km = jnp.maximum(k - 1, 0)
+                    prev_p = jnp.where(k > 0, cum_p[km], 0)
+                    prev_r = jnp.where(k > 0, cum_r[km], 0)
+                    return prev_r + (need - prev_p) * r_sorted[k]
 
-                    def core_case(_o):
-                        K = jnp.minimum(
-                            n_active,
-                            jnp.maximum(k_ub + _CORE_PAD, _CORE_MIN))
-                        if use_pallas:
-                            ccosts = jnp.where(jnp.arange(B) < K,
-                                               c_sorted, inf)
-                            dp, _bits = pallas_cover(pods_ord, ccosts)
-                            return dp[eff_res]
-                        return cover_values(pods_ord, c_sorted, K,
-                                            eff_res)
+                k_ub = jnp.searchsorted(cum_p, residual)
+                ub = cum_c[k_ub]
+                lp = lp_lower(jnp.maximum(residual - b_pods, 0))
+                keep0 = bmask & (bcost + lp <= ub)
+                # DP stages run at granularity eff_g (1 = exact); the prune
+                # math above stays unscaled so the keep set is the exact
+                # engine's
+                b_pods_s = (b_pods // eff_g).astype(i32)
+                # the core DP runs only when the greedy bound leaves more
+                # than _CORE_TRIGGER bundles alive: otherwise its loop takes
+                # zero trips and leaves dp[eff_res] = INF
+                K = jnp.minimum(jnp.sum(bmask),
+                                jnp.maximum(k_ub + _CORE_PAD, _CORE_MIN))
+                core_trip = jnp.where(jnp.sum(keep0) > _CORE_TRIGGER, K,
+                                      0).astype(i32)
 
-                    core_ub = lax.cond(jnp.sum(keep) > _CORE_TRIGGER,
-                                       core_case, lambda _o: inf, None)
-                    keep = jnp.where(
-                        core_ub < ub,
-                        (bcosts + lp) <= rmul(core_ub, 1.0 + 1e-12) + 1e-9,
-                        keep)
+                def tier_case(tools):
+                    cover_value, cover_bits = tools
 
-                    # kept-first stable permutation preserves market bundle
-                    # order within the kept prefix — the decode order the
-                    # backtracker's tie-breaking contract depends on.
-                    # Built from two exact integer cumsums + one scatter
-                    # instead of a second stable argsort (~0.5 ms/row at
-                    # B=2048 on CPU)
-                    ki = jnp.cumsum(keep.astype(jnp.int64))
-                    ni = jnp.cumsum((~keep).astype(jnp.int64))
-                    kept_n = ki[B - 1]
-                    pos = jnp.where(keep, ki - 1, kept_n + ni - 1)
-                    perm = jnp.zeros(B, jnp.int64).at[pos].set(
-                        jnp.arange(B, dtype=jnp.int64))
-                    kp = b_pods_s[perm]
-                    kc = jnp.where(keep[perm], bcosts[perm], inf)
+                    def run(_o):
+                        core_ub = cover_value(b_pods_s[order], c_sorted,
+                                              core_trip, eff_res)
+                        keep = jnp.where(core_ub < ub,
+                                         bmask & (bcost + lp <= core_ub),
+                                         keep0)
+                        # kept-first stable permutation preserves market
+                        # bundle order within the kept prefix — the decode
+                        # order the backtracker's tie-breaking depends on
+                        ki = jnp.cumsum(keep.astype(i32))
+                        ni = jnp.cumsum((~keep).astype(i32))
+                        kept_n = ki[B - 1]
+                        pos = jnp.where(keep, ki - 1, kept_n + ni - 1)
+                        perm = jnp.zeros(B, i32).at[pos].set(
+                            jnp.arange(B, dtype=i32))
+                        kp = b_pods_s[perm]
+                        bits = cover_bits(kp, bcost[perm], kept_n)
 
-                    def decode(KB):
-                        # bits buffer sized to a kept-bound rung, not B:
-                        # the decode working set mirrors the host's
-                        # (kept_n, residual)-sized bits rows
-                        def run(_o):
-                            kpk = kp[:KB]
-                            if use_pallas:
-                                _dp, bits = pallas_cover(kp, kc)
-                            else:
-                                bits = cover_bits_scan(
-                                    kpk, kc[:KB], kept_n, KB)
+                        def bt_body(st):
+                            i, j, take = st
+                            bit = bits[i, j]
+                            take = take.at[i].set(bit)
+                            j = jnp.where(bit, jnp.maximum(j - kp[i], 0), j)
+                            return i - 1, j, take
 
-                            def bt_body(st):
-                                i, j, take = st
-                                bit = bits[i, j]
-                                take = take.at[i].set(bit)
-                                j = jnp.where(
-                                    bit, jnp.maximum(j - kpk[i], 0), j)
-                                return i - 1, j, take
+                        _i, _j, take = lax.while_loop(
+                            lambda st: (st[0] >= 0) & (st[1] > 0), bt_body,
+                            (kept_n - 1, eff_res, jnp.zeros(B, dtype=bool)))
+                        return sat.at[b_item[perm]].add(
+                            jnp.where(take, b_copies[perm], 0))
+                    return run
 
-                            _i, _j, take = lax.while_loop(
-                                lambda st: (st[0] >= 0) & (st[1] > 0),
-                                bt_body,
-                                (kept_n - 1, eff_res,
-                                 jnp.zeros(KB, dtype=bool)))
-                            return sat.at[b_item[perm[:KB]]].add(
-                                jnp.where(take, b_copies[perm[:KB]],
-                                          i64(0)))
-                        return run
-
-                    if use_pallas or B <= 256:
-                        counts = decode(B)(None)
-                    else:
-                        counts = lax.cond(kept_n <= 256,
-                                          decode(256), decode(B), None)
-                    return counts, jnp.bool_(True)
-
-                return dp_case
-
-            def after_sat(_):
                 # route the row to the narrowest tier wider than its
-                # *effective* (coarsened) residual; lax.map preserves real
+                # effective (coarsened) residual; lax.switch preserves real
                 # branching, so a row pays only its own tier's vector width
-                t_idx = jnp.searchsorted(
-                    jnp.asarray(tiers), eff_res, side="right")
-                t_idx = jnp.minimum(t_idx, len(tiers) - 1)
-                return lax.cond(
-                    capacity < residual,
-                    lambda _o: (sat, jnp.bool_(False)),
-                    lambda _o: lax.switch(
-                        t_idx, [make_dp_case(t) for t in tier_tools], _o),
-                    _)
+                t_idx = jnp.searchsorted(jnp.asarray(tiers, i32), eff_res,
+                                         side="right")
+                return lax.switch(jnp.minimum(t_idx, len(tiers) - 1),
+                                  [tier_case(t) for t in tier_tools], None)
 
-            return lax.cond(residual == 0,
-                            lambda _o: (sat, jnp.bool_(True)),
-                            after_sat, None)
+            counts = lax.cond((residual > 0) & feasible, dp_part,
+                              lambda _o: sat, None)
+            return counts, feasible
 
         # -- row batching ----------------------------------------------------
         def solve_rows(coefs, actives, reqs):
             """Solve a stack of engine rows sequentially (``lax.fori_loop``
-            writing into preallocated outputs — measured ~12% faster than
-            ``lax.map``'s scan plumbing).  Sequential, not vmapped, so real
-            ``lax.cond``/``lax.switch`` branching survives (the saturation
-            fast path and the residual-tier ladder) and every while_loop
-            carry stays un-batched, letting XLA update the dp/bits buffers
-            in place.  A vmapped row solver was measured ~200x slower here:
-            batching the dynamic-trip while_loops forces a masking
-            ``select`` copy of the (lanes, B, RC) bits carry on every
-            iteration."""
+            writing into preallocated outputs).  Sequential, not vmapped,
+            so real ``lax.switch``/``lax.cond`` branching survives (the
+            saturation fast path and the residual-tier ladder) and every
+            while_loop carry stays un-batched, letting XLA update the
+            dp/bits buffers in place."""
             D = coefs.shape[0]
+
             def body(i, out):
                 cnts, feas = out
                 c, f = solve_row(coefs[i], actives[i], reqs[i])
                 return cnts.at[i].set(c), feas.at[i].set(f)
             return lax.fori_loop(
-                0, D, body,
-                (jnp.zeros((D, N), jnp.int64), jnp.zeros(D, bool)))
+                0, D, body, (jnp.zeros((D, N), i32), jnp.zeros(D, bool)))
 
-        return rmul, prep, solve_row, solve_rows, score
+        # -- pool scoring ----------------------------------------------------
+        def score(cnts, feas, reqf):
+            # speculation-only e_total (float32): steers device bracket
+            # control, never replayed to the host (which rescores exactly);
+            # elementwise sums, so no matmul precision mode is involved
+            c = cnts.astype(jnp.float32)
+            sp = jnp.sum(c * perf, axis=1)
+            sc = jnp.sum(c * price, axis=1)
+            sq = jnp.sum(c * podsf, axis=1)
+            ok = (sq >= reqf) & (sc > 0.0) & (sq > 0.0)
+            s = jnp.where(ok, (sp / sc) * (reqf / sq), 0.0)
+            return jnp.where(feas, s, -jnp.inf)
+
+        return solve_rows, score
 
     # -- fused programs ------------------------------------------------------
-    def _prescan_compiled(self, N, B, RC, D, G):
-        key = ("prescan", N, B, RC, D, G) + self._fused_flags()
+    def _prescan_program(self, N, B, RC, D, G):
+        key = ("prescan", N, B, RC, D, G)
         fn = self._fused_cache.get(key)
         if fn is None:
-            jax, jnp = self._jax, self._jnp
-            lax = jax.lax
-            use_pallas, on_cpu = self._fused_flags()
+            jnp = self._jnp
 
-            def run(md, reqs, excl, alphas, z, thr, maxr, gran):
-                rmul, prep, _row, solve_rows, _score = self._solver_core(
-                    md, z, N, B, RC, use_pallas, on_cpu,
-                    coarse=(thr, maxr, gran))
-                pn, qn, active = prep(excl)
+            def run(md, w, q, active, reqs, ks, coarse):
+                solve_rows, _score = self._solver_core(md, N, B, RC, coarse)
                 di = jnp.arange(D * G) // G
-                a = alphas[jnp.arange(D * G) % G][:, None]
-                coefs = rmul(-a, pn[di]) + rmul(1.0 - a, qn[di])
+                k = ks[jnp.arange(D * G) % G][:, None]
+                coefs = exact.coefficients(k, w[di], q[di])
                 counts, feas = solve_rows(coefs, active[di], reqs[di])
                 return counts.reshape(D, G, N), feas.reshape(D, G)
 
-            fn = jax.jit(run)
+            fn = self._jax.jit(run)
             self._fused_cache[key] = fn
             self.program_builds += 1
         return fn
 
-    def _golden_compiled(self, N, B, RC, D, MAXR):
-        key = ("golden", N, B, RC, D, MAXR) + self._fused_flags()
+    def _golden_program(self, N, B, RC, D, MAXR):
+        key = ("golden", N, B, RC, D, MAXR)
         fn = self._fused_cache.get(key)
         if fn is None:
             jax, jnp = self._jax, self._jnp
             lax = jax.lax
-            use_pallas, on_cpu = self._fused_flags()
+            i32, i64 = jnp.int32, jnp.int64
             ME = MAXR + 2
 
-            def run(md, reqs, excl, a0, b0, tol, z, thr, maxr, gran):
-                rmul, prep, _row, solve_rows, score = self._solver_core(
-                    md, z, N, B, RC, use_pallas, on_cpu,
-                    coarse=(thr, maxr, gran))
-                pn, qn, active = prep(excl)
-                reqf = reqs.astype(jnp.float64)
+            def run(md, w, q, active, reqs, a0, b0, tolk, coarse):
+                solve_rows, score = self._solver_core(md, N, B, RC, coarse)
+                reqf = reqs.astype(jnp.float32)
                 dn = jnp.arange(D)
+                g0 = exact.golden_width(b0 - a0)
+                x1, x2 = b0 - g0, a0 + g0     # the host's bracket init
+                neg_inf = jnp.full(D, -jnp.inf, jnp.float32)
 
-                def solve_vec(alphas, reqv):
-                    coefs = (rmul(-alphas[:, None], pn)
-                             + rmul(1.0 - alphas[:, None], qn))
-                    return solve_rows(coefs, active, reqv)
-
-                def spec(counts, feas):
-                    s = score(counts.astype(jnp.float64), reqf)
-                    return jnp.where(feas, s, -jnp.inf)
-
-                # bracket init: exactly the host's x1/x2 update formulas
-                # (rmul keeps PHI*(b-a) rounded before the subtract/add)
-                w0 = rmul(jnp.float64(_PHI), b0 - a0)
-                x1 = b0 - w0
-                x2 = a0 + w0
-                c1, fe1 = solve_vec(x1, reqs)
-                c2, fe2 = solve_vec(x2, reqs)
-                f1 = spec(c1, fe1)
-                f2 = spec(c2, fe2)
-
-                ev_a = (jnp.zeros((D, ME))
-                        .at[:, 0].set(x1).at[:, 1].set(x2))
-                ev_c = (jnp.zeros((D, ME, N), dtype=jnp.int64)
-                        .at[:, 0, :].set(c1).at[:, 1, :].set(c2))
-                ev_f = (jnp.zeros((D, ME), dtype=bool)
-                        .at[:, 0].set(fe1).at[:, 1].set(fe2))
-                evn = jnp.full((D,), 2, dtype=jnp.int64)
-
+                # rounds 0 and 1 solve every decision's x1 and x2; each
+                # later round advances the active brackets exactly like
+                # the host loop and solves their one new probe — a single
+                # row-solver instance in the program
                 def cond(st):
-                    return (st[0] < MAXR) & jnp.any((st[2] - st[1]) > tol)
+                    r, a, b = st[0], st[1], st[2]
+                    return (r < 2) | ((r < MAXR + 2)
+                                      & jnp.any((b - a) > tolk))
 
                 def body(st):
-                    (r, a, b, x1, x2, f1, f2,
-                     ev_a, ev_c, ev_f, evn) = st
-                    act = (b - a) > tol
-                    right = (f1 >= f2) & act     # shrink from the right
-                    left = act & ~(f1 >= f2)     # shrink from the left
+                    (r, a, b, x1, x2, f1, f2, ev_k, ev_c, ev_f, evn) = st
+                    init = r < 2
+                    act = init | ((b - a) > tolk)
+                    right = ~init & act & (f1 >= f2)  # shrink from right
+                    left = ~init & act & ~(f1 >= f2)  # shrink from left
                     nb = jnp.where(right, x2, b)
                     na = jnp.where(left, x1, a)
-                    w = rmul(jnp.float64(_PHI), nb - na)
-                    nx1 = jnp.where(right, nb - w, jnp.where(left, x2, x1))
-                    nx2 = jnp.where(left, na + w, jnp.where(right, x1, x2))
+                    g = exact.golden_width(nb - na)
+                    nx1 = jnp.where(right, nb - g, jnp.where(left, x2, x1))
+                    nx2 = jnp.where(left, na + g, jnp.where(right, x1, x2))
                     pf1 = jnp.where(left, f2, f1)
                     pf2 = jnp.where(right, f1, f2)
-                    probe = jnp.where(right, nx1,
-                                      jnp.where(left, nx2, 0.0))
+                    probe = jnp.where(
+                        init, jnp.where(r == 0, x1, x2),
+                        jnp.where(right, nx1, jnp.where(left, nx2, 0)))
                     # inactive decisions re-solve req=0 (the cheap
                     # saturation fast path) instead of a full row
-                    reqv = jnp.where(act, reqs, jnp.int64(0))
-                    cp, fep = solve_vec(probe, reqv)
-                    fp = spec(cp, fep)
-                    nf1 = jnp.where(right, fp, pf1)
-                    nf2 = jnp.where(left, fp, pf2)
-                    ev_a = ev_a.at[dn, evn].set(
-                        jnp.where(act, probe, ev_a[dn, evn]))
+                    reqv = jnp.where(act, reqs, 0)
+                    cp, fep = solve_rows(
+                        exact.coefficients(probe[:, None], w, q), active,
+                        reqv)
+                    fp = score(cp, fep, reqf)
+                    nf1 = jnp.where(right | (init & (r == 0)), fp, pf1)
+                    nf2 = jnp.where(left | (init & (r == 1)), fp, pf2)
+                    ev_k = ev_k.at[dn, evn].set(
+                        jnp.where(act, probe, ev_k[dn, evn]))
                     ev_c = ev_c.at[dn, evn, :].set(
                         jnp.where(act[:, None], cp, ev_c[dn, evn, :]))
                     ev_f = ev_f.at[dn, evn].set(
                         jnp.where(act, fep, ev_f[dn, evn]))
-                    evn = evn + act.astype(jnp.int64)
+                    evn = evn + act.astype(i32)
                     return (r + 1, na, nb, nx1, nx2, nf1, nf2,
-                            ev_a, ev_c, ev_f, evn)
+                            ev_k, ev_c, ev_f, evn)
 
                 st = lax.while_loop(cond, body, (
-                    jnp.int64(0), a0, b0, x1, x2, f1, f2,
-                    ev_a, ev_c, ev_f, evn))
+                    i32(0), a0, b0, x1, x2, neg_inf, neg_inf,
+                    jnp.zeros((D, ME), i64), jnp.zeros((D, ME, N), i32),
+                    jnp.zeros((D, ME), bool), jnp.zeros(D, i32)))
                 return st[7], st[8], st[9], st[10]
 
             fn = jax.jit(run)
@@ -1180,35 +750,45 @@ class FusedJaxBackend(JaxBackend):
         D = _bucket(max(n_dec, 1), self._D_STEPS)
         return N, B, RC, D
 
-    def _coarse_scalars(self, market, coarsening):
-        """The ``(threshold, max_rows, gcd)`` int64 triple handed to the
-        compiled programs as *traced* scalars (config or market changes
-        never force a recompile).  Coarsening off → an unreachable
-        threshold, so every row takes the exact path."""
+    @staticmethod
+    def _coarse_scalars(market, coarsening):
+        """The ``(threshold, max_rows, gcd)`` triple handed to the compiled
+        programs as *traced* scalars (config or market changes never force
+        a recompile).  Coarsening off → an unreachable threshold, so every
+        row takes the exact path."""
         if coarsening is None or not coarsening.enabled:
-            return np.int64(2 ** 62), np.int64(1), np.int64(1)
-        return (np.int64(coarsening.threshold),
-                np.int64(coarsening.max_rows),
-                np.int64(max(market.pods_gcd, 1)))
+            return np.asarray([2 ** 62, 1, 1], np.int64)
+        return np.asarray([coarsening.threshold, coarsening.max_rows,
+                           max(market.pods_gcd, 1)], np.int64)
 
-    def _pad_decisions(self, market, reqs, excludes, N, D):
+    def _decision_arrays(self, market, reqs, excludes, N, D):
+        """Per-decision uploads: padded ``(W, Q, active)`` of each
+        decision's mask (:meth:`CompiledMarket.solve_inputs`, one host
+        quantization per distinct mask) and demands.  Pad decisions have
+        no active item and zero demand."""
+        n = market.n
+        w = np.zeros((D, N), np.int64)
+        q = np.zeros((D, N), np.int64)
+        active = np.zeros((D, N), bool)
         rq = np.zeros(D, np.int64)
         rq[:len(reqs)] = reqs
-        ex = np.zeros((D, N), bool)
+        seen: dict = {}
         for d, mask in enumerate(excludes):
-            if mask is not None:
-                ex[d, :market.n] = mask
-        return rq, ex
+            mkey = None if mask is None else mask.tobytes()
+            if mkey not in seen:
+                seen[mkey] = market.solve_inputs(mask)
+            w[d, :n], q[d, :n], active[d, :n] = seen[mkey]
+        return w, q, active, rq
 
-    def _run_prescan(self, market, reqs, excludes, grid, coarsening=None):
-        Dr, G = len(reqs), len(grid)
+    def _run_prescan(self, market, reqs, excludes, kgrid, coarsening=None):
+        Dr, G = len(reqs), len(kgrid)
         N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
         md = self._device_market(market, N, B)
-        rq, ex = self._pad_decisions(market, reqs, excludes, N, D)
-        thr, maxr, gran = self._coarse_scalars(market, coarsening)
-        fn = self._prescan_compiled(N, B, RC, D, G)
-        counts, feas = fn(md, rq, ex, np.asarray(grid, np.float64),
-                          np.int64(0), thr, maxr, gran)
+        w, q, active, rq = self._decision_arrays(market, reqs, excludes, N,
+                                                 D)
+        fn = self._prescan_program(N, B, RC, D, G)
+        counts, feas = fn(md, w, q, active, rq, np.asarray(kgrid, np.int64),
+                          self._coarse_scalars(market, coarsening))
         return (np.asarray(counts)[:Dr, :, :market.n],
                 np.asarray(feas)[:Dr])
 
@@ -1217,116 +797,50 @@ class FusedJaxBackend(JaxBackend):
         Dr = len(reqs)
         N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
         md = self._device_market(market, N, B)
-        rq, ex = self._pad_decisions(market, reqs, excludes, N, D)
-        thr, maxr, gran = self._coarse_scalars(market, coarsening)
-        # round budget: any bracket is <= 1 wide and shrinks by PHI per
-        # round, so ceil(log(tol)/log(PHI)) rounds suffice (+2 slack)
-        MAXR = (int(math.ceil(math.log(tolerance) / math.log(_PHI))) + 2
+        w, q, active, rq = self._decision_arrays(market, reqs, excludes, N,
+                                                 D)
+        # round budget: any bracket is <= 1 wide and shrinks by at most
+        # PHI per round, so ceil(log(tol)/log(PHI)) rounds suffice (+2)
+        phi = exact.PHI_Q / (1 << exact.PHI_BITS)
+        MAXR = (int(math.ceil(math.log(tolerance) / math.log(phi))) + 2
                 if 0.0 < tolerance < 1.0 else 3)
-        a0 = np.zeros(D)
+        a0 = np.zeros(D, np.int64)
         a0[:Dr] = a_list
-        b0 = np.zeros(D)
+        b0 = np.zeros(D, np.int64)
         b0[:Dr] = b_list
-        fn = self._golden_compiled(N, B, RC, D, MAXR)
-        ev_a, ev_c, ev_f, evn = fn(md, rq, ex, a0, b0,
-                                   np.float64(tolerance), np.int64(0),
-                                   thr, maxr, gran)
-        return (np.asarray(ev_a)[:Dr], np.asarray(ev_c)[:Dr, :, :market.n],
+        fn = self._golden_program(N, B, RC, D, MAXR)
+        ev_k, ev_c, ev_f, evn = fn(
+            md, w, q, active, rq, a0, b0,
+            np.int64(exact.tolerance_k(tolerance)),
+            self._coarse_scalars(market, coarsening))
+        return (np.asarray(ev_k)[:Dr], np.asarray(ev_c)[:Dr, :, :market.n],
                 np.asarray(ev_f)[:Dr], np.asarray(evn)[:Dr])
 
-    # -- rounding self-check + record entry point ----------------------------
-    def _fused_ok(self) -> bool:
-        """One-time probe that this XLA build's rmul-guarded products are
-        bitwise the host's (the FMA-contraction defense holds)."""
-        if self._selfcheck_ok is None:
-            try:
-                self._selfcheck_ok = self._run_selfcheck()
-            except Exception as exc:   # pragma: no cover - defensive
-                events_log.warn_once(
-                    "backend_fused_disabled",
-                    "fused jax decision plane disabled (self-check raised "
-                    f"{exc!r}); falling back to per-round dispatch",
-                    RuntimeWarning)
-                self._selfcheck_ok = False
-        return self._selfcheck_ok
-
-    def _run_selfcheck(self) -> bool:
-        jax, jnp = self._jax, self._jnp
-        lax = jax.lax
-        rng = np.random.default_rng(0)
-        pn = rng.uniform(0.5, 4.0, 64)
-        qn = rng.uniform(0.5, 4.0, 64)
-        alphas = rng.uniform(0.0, 1.0, 16)
-
-        def dev(a, p, q, z):
-            def rm(x, y):
-                t = x * y
-                return lax.bitcast_convert_type(
-                    lax.bitcast_convert_type(t, jnp.int64) ^ z,
-                    jnp.float64)
-            coef = (rm(-a[:, None], p[None, :])
-                    + rm(1.0 - a[:, None], q[None, :]))
-            thr = rm(coef, 1.0 + 1e-12) + 1e-9
-            w = a[:, None] - rm(jnp.float64(_PHI), coef)
-            return coef, thr, w
-
-        coef_d, thr_d, w_d = jax.jit(dev)(
-            jnp.asarray(alphas), jnp.asarray(pn), jnp.asarray(qn),
-            np.int64(0))
-        a2 = alphas[:, None]
-        coef_h = -a2 * pn[None, :] + (1.0 - a2) * qn[None, :]
-        thr_h = coef_h * (1.0 + 1e-12) + 1e-9
-        w_h = a2 - _PHI * coef_h
-        ok = (np.asarray(coef_d).tobytes() == coef_h.tobytes()
-              and np.asarray(thr_d).tobytes() == thr_h.tobytes()
-              and np.asarray(w_d).tobytes() == w_h.tobytes())
-        if not ok:   # pragma: no cover - depends on XLA build
-            events_log.warn_once(
-                "backend_fused_disabled",
-                "fused jax decision plane disabled: device float products "
-                "do not match host rounding on this XLA build; falling "
-                "back to per-round dispatch", RuntimeWarning)
-        return ok
-
-    def fused_gss_record(self, items, market, reqs, excludes, grid,
+    # -- record entry point --------------------------------------------------
+    def fused_gss_record(self, items, market, reqs, excludes, kgrid,
                          tolerance,
                          coarsening=None) -> Optional["_FusedGssRecord"]:
         """Run the device-resident prescan for a ``bracketed_gss_many``
-        batch and return the replay record, or None to decline (empty
-        market, failed self-check, a device error, or a batch whose
-        coarsening ladder would need the approx tier — the device plane
-        only implements the exact and gcd modes, so approx-regime batches
-        stay on the host engine)."""
-        if market.n == 0 or market.n_bundles == 0:
-            return None
+        batch and return the replay record.  Returns None — a counted
+        decline — only for an empty market or a batch whose coarsening
+        ladder would need the approx tier, which the device does not
+        implement; device errors propagate."""
         cfg = DEFAULT_COARSENING if coarsening is None else coarsening
         max_req = max((int(r) for r in reqs), default=0)
-        if cfg.enabled and max_req > cfg.threshold:
-            g = market.pods_gcd
-            if not (g > 1 and -(-max_req // g) <= cfg.max_rows):
-                return None
-        if not self._fused_ok():
+        approx = (cfg.enabled and max_req > cfg.threshold
+                  and not (market.pods_gcd > 1
+                           and -(-max_req // market.pods_gcd)
+                           <= cfg.max_rows))
+        if market.n == 0 or market.n_bundles == 0 or approx:
+            self.declined_batches += 1
             return None
-        try:
-            rec = _FusedGssRecord(self, items, market, reqs, excludes,
-                                  grid, tolerance, cfg)
-        except _PrescanMismatch:
-            # the sampled host cross-check failed: device counts cannot be
-            # trusted on this build — disable the fused path for the
-            # process (already warned in _verify_sample)
-            self._selfcheck_ok = False
-            return None
-        except Exception as exc:
-            events_log.warn_once(
-                "backend_fused_record_fallback",
-                f"fused GSS device path failed ({exc!r}); falling back "
-                "to per-round dispatch", RuntimeWarning)
-            return None
+        rec = _FusedGssRecord(self, items, market, reqs, excludes, kgrid,
+                              tolerance, cfg)
         self.fused_records += 1
         return rec
 
 
-class _PrescanMismatch(RuntimeError):
+class PrescanMismatch(RuntimeError):
     """Device prescan counts failed the sampled host cross-check."""
 
 
@@ -1335,16 +849,16 @@ class _FusedGssRecord:
     control loop (DESIGN.md §13).
 
     Construction runs the fused prescan; :meth:`run_golden` runs the fused
-    golden program once the host has chosen brackets.  Both fill an
-    exact-bitwise α → counts lookup per decision.  The host replay
+    golden program once the host has chosen brackets.  Both fill a
+    grid-index → counts lookup per decision.  The host replay
     (``bracketed_gss_many``) then re-executes the sequential control flow
-    with exact host floats and resolves every probe through
+    with exact host scores and resolves every probe through
     :meth:`solve_many`: device-recorded counts on a hit, a counted NumPy
     engine solve on a miss (device/host control divergence) — so a
     speculation mismatch can only cost time, never change a selection.
     """
 
-    def __init__(self, backend, items, market, reqs, excludes, grid,
+    def __init__(self, backend, items, market, reqs, excludes, kgrid,
                  tolerance, coarsening=None):
         self._backend = backend
         self._items = list(items)
@@ -1353,140 +867,112 @@ class _FusedGssRecord:
         self._excludes = list(excludes)
         self._tolerance = float(tolerance)
         self._coarsening = coarsening
+        kgrid = [int(k) for k in kgrid]
         counts, feas = backend._run_prescan(market, self._reqs,
-                                            self._excludes, list(grid),
+                                            self._excludes, kgrid,
                                             coarsening=coarsening)
         self.prescan = [
             [list(map(int, counts[d, g])) if feas[d, g] else None
-             for g in range(len(grid))]
+             for g in range(len(kgrid))]
             for d in range(len(self._reqs))]
-        self._lookup: List[dict] = [{} for _ in self._reqs]
-        for d, row in enumerate(self.prescan):
-            for a, c in zip(grid, row):
-                self._lookup[d].setdefault(float(a), c)
-        self._verify_sample(list(grid))
+        self._lookup: List[dict] = [dict(zip(kgrid, row))
+                                    for row in self.prescan]
+        self._verify_sample(kgrid)
 
-    def _verify_sample(self, grid: List[float]) -> None:
-        """Prescan fail-safe, mirroring the golden phase's lookup-miss
-        host solve: before the record is trusted, one sampled
+    def _host_solve(self, reqs, k_lists, excludes):
+        from .ilp import solve_ilp_many   # deferred: no import cycle
+        return solve_ilp_many(
+            self._items, reqs,
+            [[exact.k_alpha(k) for k in ks] for ks in k_lists],
+            market=self._market, excludes=excludes,
+            backend=self._backend._host, coarsening=self._coarsening)
+
+    def _verify_sample(self, kgrid: List[int]) -> None:
+        """Prescan cross-check: before the record is trusted, one sampled
         (decision, α) row per batch — rotated through decisions and grid
         points by the backend's ``verify_solves`` counter — is re-solved
-        on the NumPy engine and compared exactly.  Any divergence (an
-        XLA build or lowering whose numerics the rmul/Pallas self-checks
-        did not anticipate) raises :class:`_PrescanMismatch`, which
-        permanently disables the fused path — a warned, counted event —
-        instead of silently changing selections."""
-        if not self._reqs or not grid:
+        on the NumPy engine and compared exactly.  A divergence means the
+        device broke the exact-integer contract: it raises
+        :class:`PrescanMismatch` rather than let a selection change."""
+        if not self._reqs or not kgrid:
             return
         be = self._backend
         d = be.verify_solves % len(self._reqs)
-        g = be.verify_solves % len(grid)
+        g = be.verify_solves % len(kgrid)
         be.verify_solves += 1
-        from .ilp import solve_ilp_many   # deferred: no import cycle
-        ref = solve_ilp_many(
-            self._items, [self._reqs[d]], [[float(grid[g])]],
-            market=self._market, excludes=[self._excludes[d]],
-            backend=be._host_fallback,
-            coarsening=self._coarsening)[0][0]
+        ref = self._host_solve([self._reqs[d]], [[kgrid[g]]],
+                               [self._excludes[d]])[0][0]
         if ref != self.prescan[d][g]:
-            events_log.warn_once(
-                "backend_fused_prescan_mismatch",
-                "fused jax decision plane disabled: device prescan counts "
-                f"diverged from the host engine (decision {d}, alpha "
-                f"{float(grid[g])!r}); falling back to per-round dispatch",
-                RuntimeWarning)
-            raise _PrescanMismatch(
-                f"prescan verification mismatch at decision {d}, "
-                f"alpha {float(grid[g])!r}")
+            raise PrescanMismatch(
+                f"device prescan counts diverged from the host engine at "
+                f"decision {d}, alpha {exact.k_alpha(kgrid[g])!r}")
 
     def run_golden(self, a_list, b_list) -> None:
-        ev_a, ev_c, ev_f, evn = self._backend._run_golden(
-            self._market, self._reqs, self._excludes,
-            [float(a) for a in a_list], [float(b) for b in b_list],
+        ev_k, ev_c, ev_f, evn = self._backend._run_golden(
+            self._market, self._reqs, self._excludes, a_list, b_list,
             self._tolerance, coarsening=self._coarsening)
         for d in range(len(self._reqs)):
             lut = self._lookup[d]
             for s in range(int(evn[d])):
                 cnt = (list(map(int, ev_c[d, s])) if ev_f[d, s] else None)
-                lut.setdefault(float(ev_a[d, s]), cnt)
+                lut.setdefault(int(ev_k[d, s]), cnt)
 
-    def solve_many(self, idxs, alpha_lists):
+    def solve_many(self, idxs, k_lists):
         """``solve_ilp_many``-shaped resolution of a golden round's probes:
-        one counts-or-None list per (decision index, α list) pair."""
-        out = [[None] * len(al) for al in alpha_lists]
+        one counts-or-None list per (decision index, grid-index list)."""
+        out = [[None] * len(ks) for ks in k_lists]
         miss_pos: List[Tuple[int, List[int]]] = []
-        miss_reqs: List[int] = []
-        miss_alphas: List[List[float]] = []
-        miss_excl: List[Optional[np.ndarray]] = []
-        for k, (d, alist) in enumerate(zip(idxs, alpha_lists)):
-            lut = self._lookup[d]
+        for i, (d, ks) in enumerate(zip(idxs, k_lists)):
             missing = []
-            for j, a in enumerate(alist):
-                hit = lut.get(float(a), _MISS)
+            for j, k in enumerate(ks):
+                hit = self._lookup[d].get(k, _MISS)
                 if hit is _MISS:
                     missing.append(j)
                 else:
-                    out[k][j] = hit
+                    out[i][j] = hit
             if missing:
-                miss_pos.append((k, missing))
-                miss_reqs.append(self._reqs[d])
-                miss_alphas.append([alist[j] for j in missing])
-                miss_excl.append(self._excludes[d])
+                miss_pos.append((i, missing))
         if miss_pos:
-            self._backend.fallback_solves += sum(
-                len(js) for _k, js in miss_pos)
-            from .ilp import solve_ilp_many   # deferred: no import cycle
-            solved = solve_ilp_many(
-                self._items, miss_reqs, miss_alphas, market=self._market,
-                excludes=miss_excl, backend=self._backend._host_fallback,
-                coarsening=self._coarsening)
-            for (k, js), counts_d in zip(miss_pos, solved):
+            self._backend.fallback_solves += sum(len(js) for _i, js in
+                                                 miss_pos)
+            solved = self._host_solve(
+                [self._reqs[idxs[i]] for i, _js in miss_pos],
+                [[k_lists[i][j] for j in js] for i, js in miss_pos],
+                [self._excludes[idxs[i]] for i, _js in miss_pos])
+            for (i, js), counts_d in zip(miss_pos, solved):
                 for j, c in zip(js, counts_d):
-                    out[k][j] = c
-                    self._lookup[idxs[k]].setdefault(
-                        float(alpha_lists[k][j]), c)
+                    out[i][j] = c
+                    self._lookup[idxs[i]].setdefault(k_lists[i][j], c)
         return out
 
 
 # ---------------------------------------------------------------------------
-# Default-backend registry (env-overridable, numpy fallback with a warning)
+# Default-backend registry (env-overridable)
 # ---------------------------------------------------------------------------
 
 _DEFAULT: Optional[SolverBackend] = None
+
+#: spec → backend class of :func:`make_backend`
+_SPECS = {"numpy": NumpyBackend, "jax:fused": FusedJaxBackend}
 
 
 def jax_available() -> bool:
     try:
         import jax  # noqa: F401
         return True
-    except Exception:
+    except ImportError:
         return False
 
 
 def make_backend(spec: str) -> SolverBackend:
-    """Build a backend from a spec string: ``numpy`` | ``jax`` |
-    ``jax:pallas`` | ``jax:fused`` | ``jax:fused:pallas``.  A jax spec
-    without jax installed warns once (counted in
-    ``repro.core.events_log``) and returns the numpy backend (the solver
-    path treats jax as optional)."""
-    if spec == "numpy":
-        return NumpyBackend()
-    if spec in ("jax", "jax:pallas", "jax:fused", "jax:fused:pallas"):
-        try:
-            if spec.startswith("jax:fused"):
-                return FusedJaxBackend(pallas=spec.endswith(":pallas"))
-            return JaxBackend(pallas=spec.endswith(":pallas"))
-        except ImportError:
-            events_log.warn_once(
-                "backend_numpy_fallback",
-                "KubePACS solver backend %r requested but jax is not "
-                "installed; falling back to the NumPy backend (install "
-                "jax, or set KUBEPACS_SOLVER_BACKEND=numpy to silence "
-                "this)" % spec, RuntimeWarning, stacklevel=2)
-            return NumpyBackend()
-    raise ValueError(f"unknown solver backend spec {spec!r} "
-                     "(expected numpy | jax | jax:pallas | jax:fused | "
-                     "jax:fused:pallas)")
+    """Build a backend from a spec string: ``numpy`` | ``jax:fused``.
+    ``jax:fused`` imports jax and raises ``ImportError`` where it is
+    missing."""
+    cls = _SPECS.get(spec)
+    if cls is None:
+        raise ValueError(f"unknown solver backend spec {spec!r} "
+                         f"(expected {' | '.join(_SPECS)})")
+    return cls()
 
 
 def get_backend() -> SolverBackend:

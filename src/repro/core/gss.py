@@ -4,8 +4,11 @@ GSS maximizes E_Total(α) = E_PerfCost × E_OverPods of the ILP solution at α
 over α ∈ [0, 1], shrinking the bracket by φ = (√5−1)/2 ≈ 0.618 per step and
 reusing one interior evaluation per iteration (one ILP solve per iteration
 after the two initial solves; ≈ 5n+1 iterations for tolerance ε = 10⁻ⁿ,
-Eq. 6–7).  The best pool over *all* evaluated α is returned (Alg. 1's S*),
-which also guards against mild non-unimodality of the empirical E_Total(α).
+Eq. 6–7).  Brackets and probes live on the exact dyadic α grid of
+:mod:`repro.core.exact` (integer golden update), so the device plane
+reproduces every probe bit for bit.  The best pool over *all* evaluated α
+is returned (Alg. 1's S*), which also guards against mild
+non-unimodality of the empirical E_Total(α).
 
 Engine wiring (DESIGN.md §8 + §12): with the default solver,
 ``bracketed_gss`` is the one-decision case of :func:`bracketed_gss_many`,
@@ -30,6 +33,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import exact
 from .backend import CoarseningConfig, SolverBackend
 from .efficiency import (CandidateItem, NodePool, e_total,
                          score_counts_batch, score_counts_many)
@@ -65,13 +69,10 @@ def _make_evaluator(items: Sequence[CandidateItem], req_pods: int,
                     backend: Optional[SolverBackend] = None,
                     coarsening: Optional[CoarseningConfig] = None,
                     ) -> Callable:
-    """One (α → (pool, E_Total)) evaluator shared by both searches.
+    """One (grid index → (pool, E_Total)) evaluator of the pure search.
 
-    The engine path solves against the compiled market with the objective
-    row rebuilt from normalised vectors cached *once* per (market, mask) —
-    ``market.norms(exclude)`` — instead of re-deriving the masked
-    normalisation on every α probe (bit-identical by construction).  A
-    custom ``solver`` keeps the seed calling convention for tests and
+    The engine path solves against the compiled market; a custom
+    ``solver`` keeps the seed calling convention (float α) for tests and
     alternative backends.
     """
     use_engine = solver is solve_ilp
@@ -80,17 +81,14 @@ def _make_evaluator(items: Sequence[CandidateItem], req_pods: int,
                          "(custom solvers have no exclusion channel)")
     if use_engine and market is None:
         market = compile_market(items)
-    if use_engine:
-        perf_norm, price_norm = market.norms(exclude)
 
-    def evaluate(alpha: float) -> Tuple[Optional[NodePool], float]:
-        key = round(alpha, 12)
-        if key in cache:
-            return cache[key]
+    def evaluate(k: int) -> Tuple[Optional[NodePool], float]:
+        if k in cache:
+            return cache[k]
+        alpha = exact.k_alpha(k)
         if use_engine:
-            coef = -alpha * perf_norm + (1.0 - alpha) * price_norm
             counts = solve_ilp(items, req_pods, alpha, market=market,
-                               exclude=exclude, backend=backend, coef=coef,
+                               exclude=exclude, backend=backend,
                                coarsening=coarsening)
         else:
             counts = solver(items, req_pods, alpha)
@@ -102,7 +100,7 @@ def _make_evaluator(items: Sequence[CandidateItem], req_pods: int,
             score = e_total(pool, req_pods)
         trace.alphas.append(alpha)
         trace.e_totals.append(score if score != float("-inf") else 0.0)
-        cache[key] = (pool, score)
+        cache[k] = (pool, score)
         return pool, score
 
     return evaluate
@@ -128,29 +126,30 @@ def golden_section_search(
     content)."""
     trace = GssTrace()
     t0 = timer()
-    cache: dict[float, Tuple[Optional[NodePool], float]] = {}
+    cache: dict[int, Tuple[Optional[NodePool], float]] = {}
     evaluate = _make_evaluator(items, req_pods, solver, market, exclude,
                                trace, cache, backend, coarsening)
 
-    a, b = alpha_lo, alpha_hi
-    x1 = b - PHI * (b - a)
-    x2 = a + PHI * (b - a)
+    a, b = exact.alpha_k(alpha_lo), exact.alpha_k(alpha_hi)
+    tol = exact.tolerance_k(tolerance)
+    w = exact.golden_width(b - a)
+    x1, x2 = b - w, a + w
     pool1, f1 = evaluate(x1)
     pool2, f2 = evaluate(x2)
     best_pool, best_f = (pool1, f1) if f1 >= f2 else (pool2, f2)
 
-    while (b - a) > tolerance:
+    while (b - a) > tol:
         if f1 >= f2:
             b = x2
             x2, f2, pool2 = x1, f1, pool1
-            x1 = b - PHI * (b - a)
+            x1 = b - exact.golden_width(b - a)
             pool1, f1 = evaluate(x1)
             if f1 > best_f:
                 best_pool, best_f = pool1, f1
         else:
             a = x1
             x1, f1, pool1 = x2, f2, pool2
-            x2 = a + PHI * (b - a)
+            x2 = a + exact.golden_width(b - a)
             pool2, f2 = evaluate(x2)
             if f2 > best_f:
                 best_pool, best_f = pool2, f2
@@ -197,7 +196,7 @@ def bracketed_gss(
         raise ValueError("exclude masks require the default solve_ilp "
                          "solver (custom solvers have no exclusion "
                          "channel)")
-    grid = [i / (prescan - 1) for i in range(prescan)]
+    grid = [exact.k_alpha(k) for k in exact.alpha_grid(prescan)]
     scan_trace = GssTrace()
     t0 = timer()
     scores, pools = [], []
@@ -288,7 +287,9 @@ def bracketed_gss_many(
         excludes = [None] * n_dec
     if len(excludes) != n_dec:
         raise ValueError("excludes must match len(req_pods_list)")
-    grid = [i / (prescan - 1) for i in range(prescan)]
+    kgrid = exact.alpha_grid(prescan)
+    grid = [exact.k_alpha(k) for k in kgrid]
+    tol = exact.tolerance_k(tolerance)
     if market is None:
         market = compile_market(items)
 
@@ -305,7 +306,7 @@ def bracketed_gss_many(
     record = None
     if backend is not None and getattr(backend, "supports_fused_gss", False):
         record = backend.fused_gss_record(items, market, list(req_pods_list),
-                                          list(excludes), grid, tolerance,
+                                          list(excludes), kgrid, tolerance,
                                           coarsening=coarsening)
 
     # -- prescan: one stacked engine invocation over every (decision, α) --
@@ -331,10 +332,11 @@ def bracketed_gss_many(
             st.scan_trace.e_totals.append(max(score, 0.0))
             if score > st.scan_f:
                 st.scan_pool, st.scan_f, best_idx = pool, score, gi
-        st.a = grid[max(0, best_idx - 1)]
-        st.b = grid[min(len(grid) - 1, best_idx + 1)]
-        st.x1 = st.b - PHI * (st.b - st.a)
-        st.x2 = st.a + PHI * (st.b - st.a)
+        st.a = kgrid[max(0, best_idx - 1)]
+        st.b = kgrid[min(len(kgrid) - 1, best_idx + 1)]
+        w = exact.golden_width(st.b - st.a)
+        st.x1 = st.b - w
+        st.x2 = st.a + w
 
     if record is not None:
         # speculative device golden rounds over the chosen brackets; the
@@ -343,38 +345,39 @@ def bracketed_gss_many(
         record.run_golden([st.a for st in states], [st.b for st in states])
 
     # -- lockstep golden-section refinement --------------------------------
-    def eval_round(requests: List[Tuple[_GssState, List[float]]]) -> None:
-        """Evaluate each state's pending α list with sequential-evaluate
-        semantics (cache first, one engine row per miss, per-state append
-        order), batching all misses into one solve_ilp_many call."""
+    def eval_round(requests: List[Tuple[_GssState, List[int]]]) -> None:
+        """Evaluate each state's pending grid-index list with
+        sequential-evaluate semantics (cache first, one engine row per
+        miss, per-state append order), batching all misses into one
+        solve_ilp_many call."""
         miss_states: List[_GssState] = []
         miss_reqs: List[int] = []
-        miss_alphas: List[List[float]] = []
+        miss_ks: List[List[int]] = []
         miss_excludes: List[Optional[np.ndarray]] = []
-        for st, alist in requests:
-            pending: List[float] = []
-            seen = set()
-            for alpha in alist:
-                key = round(alpha, 12)
-                if key not in st.cache and key not in seen:
-                    seen.add(key)
-                    pending.append(alpha)
+        for st, klist in requests:
+            pending: List[int] = []
+            for k in klist:
+                if k not in st.cache and k not in pending:
+                    pending.append(k)
             if pending:
                 miss_states.append(st)
                 miss_reqs.append(st.req)
-                miss_alphas.append(pending)
+                miss_ks.append(pending)
                 miss_excludes.append(st.exclude)
         if not miss_states:
             return
         if record is not None:
             solved = record.solve_many([st.idx for st in miss_states],
-                                       miss_alphas)
+                                       miss_ks)
         else:
-            solved = solve_ilp_many(items, miss_reqs, miss_alphas,
-                                    market=market, excludes=miss_excludes,
-                                    backend=backend, coarsening=coarsening)
-        for st, alphas_d, counts_d in zip(miss_states, miss_alphas, solved):
-            for alpha, counts in zip(alphas_d, counts_d):
+            solved = solve_ilp_many(
+                items, miss_reqs,
+                [[exact.k_alpha(k) for k in ks] for ks in miss_ks],
+                market=market, excludes=miss_excludes, backend=backend,
+                coarsening=coarsening)
+        for st, ks_d, counts_d in zip(miss_states, miss_ks, solved):
+            for k, counts in zip(ks_d, counts_d):
+                alpha = exact.k_alpha(k)
                 st.trace.ilp_solves += 1
                 if counts is None:
                     pool, score = None, float("-inf")
@@ -385,12 +388,12 @@ def bracketed_gss_many(
                 st.trace.alphas.append(alpha)
                 st.trace.e_totals.append(
                     score if score != float("-inf") else 0.0)
-                st.cache[round(alpha, 12)] = (pool, score)
+                st.cache[k] = (pool, score)
 
     eval_round([(st, [st.x1, st.x2]) for st in states])
     for st in states:
-        st.pool1, st.f1 = st.cache[round(st.x1, 12)]
-        st.pool2, st.f2 = st.cache[round(st.x2, 12)]
+        st.pool1, st.f1 = st.cache[st.x1]
+        st.pool2, st.f2 = st.cache[st.x2]
         if st.f1 >= st.f2:
             st.best_pool, st.best_f = st.pool1, st.f1
         else:
@@ -398,28 +401,28 @@ def bracketed_gss_many(
 
     while True:
         active = [st for st in states
-                  if not st.done and (st.b - st.a) > tolerance]
+                  if not st.done and (st.b - st.a) > tol]
         for st in states:
-            if not st.done and (st.b - st.a) <= tolerance:
+            if not st.done and (st.b - st.a) <= tol:
                 st.done = True
         if not active:
             break
-        probes: List[Tuple[_GssState, List[float]]] = []
+        probes: List[Tuple[_GssState, List[int]]] = []
         for st in active:
             if st.f1 >= st.f2:
                 st.b = st.x2
                 st.x2, st.f2, st.pool2 = st.x1, st.f1, st.pool1
-                st.x1 = st.b - PHI * (st.b - st.a)
+                st.x1 = st.b - exact.golden_width(st.b - st.a)
                 probes.append((st, [st.x1]))
             else:
                 st.a = st.x1
                 st.x1, st.f1, st.pool1 = st.x2, st.f2, st.pool2
-                st.x2 = st.a + PHI * (st.b - st.a)
+                st.x2 = st.a + exact.golden_width(st.b - st.a)
                 probes.append((st, [st.x2]))
         eval_round(probes)
-        for st, alist in probes:
-            pool, f = st.cache[round(alist[0], 12)]
-            if alist[0] == st.x1:
+        for st, klist in probes:
+            pool, f = st.cache[klist[0]]
+            if klist[0] == st.x1:
                 st.pool1, st.f1 = pool, f
                 if f > st.best_f:
                     st.best_pool, st.best_f = pool, f

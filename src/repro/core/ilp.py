@@ -25,8 +25,9 @@ One exact engine behind three entry points (DESIGN.md §8 + §12):
   keeps each slice's working set cache-sized).
 
 All three produce *bit-identical selections* for a given row regardless of
-batching and backend: the value pass is a fixed sequence of elementwise
-float64 ops (see :mod:`repro.core.backend`) and tie-breaking lives entirely
+batching and backend: every value that decides a selection is an exact
+integer (:mod:`repro.core.exact`: α on a dyadic grid, quantized objective
+coefficients, int64 costs and DP values), and tie-breaking lives entirely
 in the shared improvement-bit backtracker.
 
 :func:`solve_ilp_reference` preserves the seed history-matrix solver
@@ -53,6 +54,7 @@ from typing import Hashable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import exact
 from .backend import (_CORE_MIN, _CORE_PAD, _CORE_TRIGGER,
                       DEFAULT_COARSENING, CoarseningConfig, SolverBackend,
                       get_backend)
@@ -191,10 +193,7 @@ class CompiledMarket:
         With an ``exclude`` mask the Perf_min/SP_min normalisation is taken
         over the surviving candidates only — identical to rebuilding the
         candidate set without the excluded offerings (§4.1 cache semantics).
-        GSS evaluators cache this pair once per (market, mask) and rebuild
-        per-α coefficient rows as ``-α·pn + (1-α)·qn`` — the same
-        elementwise float64 ops :meth:`coefficients` performs, so the
-        cached path is bit-identical to the uncached one.
+        :meth:`solve_inputs` quantizes this pair once per (market, mask).
         """
         if exclude is None or not np.any(exclude):
             return self.perf_norm, self.price_norm
@@ -209,10 +208,49 @@ class CompiledMarket:
 
     def coefficients(self, alphas: np.ndarray,
                      exclude: Optional[np.ndarray] = None) -> np.ndarray:
-        """Broadcast Eq. 4–5 over an α grid: (n_alpha, n_items)."""
+        """Broadcast Eq. 4–5 over an α grid: (n_alpha, n_items), float64
+        (reporting only: the solver decides on :meth:`int_coefficients`)."""
         a = np.asarray(alphas, dtype=np.float64).reshape(-1, 1)
         perf_norm, price_norm = self.norms(exclude)
         return -a * perf_norm + (1.0 - a) * price_norm
+
+    @functools.cached_property
+    def scale_bits(self) -> int:
+        """Fraction bits of the quantized objective (:func:`exact.scale_bits`):
+        the unmasked norms bound every masked one, and the structural node
+        count bounds every selection."""
+        norms = np.concatenate([self.perf_norm, self.price_norm, [1.0]])
+        return exact.scale_bits(float(np.max(norms[np.isfinite(norms)])),
+                                int(np.sum(self.bound[self.structural])))
+
+    def solve_inputs(self, exclude: Optional[np.ndarray] = None,
+                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(W, Q, active)`` for one exclusion mask: the int64 vectors of
+        :func:`exact.quantize` — all any backend needs to rebuild an α's
+        coefficients exactly — and the items a solve may select
+        (structural, not excluded, finite objective terms).  Items outside
+        ``active`` carry zeros: they never enter a solve, and their norms
+        (corrupt, or renormalised by the unit fallback of :meth:`norms`)
+        are not bounded by :attr:`scale_bits`."""
+        perf_norm, price_norm = self.norms(exclude)
+        active = (self.structural & np.isfinite(perf_norm)
+                  & np.isfinite(price_norm))
+        if exclude is not None:
+            active &= ~exclude
+        w, q = exact.quantize(np.where(active, perf_norm, 0.0),
+                              np.where(active, price_norm, 0.0),
+                              self.scale_bits)
+        return w, q, active
+
+    def int_coefficients(self, ks: Sequence[int],
+                         exclude: Optional[np.ndarray] = None,
+                         ) -> Tuple[np.ndarray, np.ndarray]:
+        """Exact Eq. 4–5 coefficients at grid indices ``ks`` — the values
+        every backend solves on — as (n_alpha, n_items) int64, with the
+        mask's ``active`` items (:meth:`solve_inputs`)."""
+        w, q, active = self.solve_inputs(exclude)
+        k = np.asarray(ks, dtype=np.int64).reshape(-1, 1)
+        return exact.coefficients(k, w, q), active
 
 
 def compile_market(items: Sequence[CandidateItem]) -> CompiledMarket:
@@ -291,100 +329,102 @@ def reweight_market(market: CompiledMarket, perf: np.ndarray,
 
 def _cover_dp(bpods: np.ndarray, bcosts: np.ndarray, target: int,
               ) -> np.ndarray:
-    """Reference forward value pass: dp[j] = min cost of a bundle subset
-    with ≥ j pods.  Kept as the plain-numpy spec of the backend kernel
-    (``repro.core.backend``) for tests; the production path uses the
-    backend's fused value-pass-with-bits instead.
+    """Reference forward value pass: dp[j] = min int64 cost of a bundle
+    subset with ≥ j pods (``exact.INF`` = unreachable).  Kept as the
+    plain-numpy spec of the backend kernel (``repro.core.backend``) for
+    tests; the production path uses the backend's fused value pass with
+    improvement bits instead.
     """
-    dp = np.full(target + 1, _INF)
-    dp[0] = 0.0
-    scratch = np.empty(target + 1)
+    dp = np.full(target + 1, exact.INF, dtype=np.int64)
+    dp[0] = 0
     for b in range(len(bpods)):
         pb = int(bpods[b])
         cb = bcosts[b]
-        if not np.isfinite(cb):
-            continue
         if pb > target:
             np.minimum(dp[1:], cb, out=dp[1:])
             continue
-        k = target + 1 - pb
-        cand = np.add(dp[:k], cb, out=scratch[:k])
+        cand = dp[:target + 1 - pb] + cb
         np.minimum(dp[pb:], cand, out=dp[pb:])
         if pb > 1:
-            np.minimum(dp[1:pb], dp[0] + cb, out=dp[1:pb])
+            np.minimum(dp[1:pb], cb, out=dp[1:pb])
     return dp
 
 
 #: core-DP upper-bound tuning for :func:`_lp_prune` (``_CORE_PAD``,
-#: ``_CORE_MIN``, ``_CORE_TRIGGER``) now lives in :mod:`repro.core.backend`
+#: ``_CORE_MIN``, ``_CORE_TRIGGER``) lives in :mod:`repro.core.backend`
 #: — the fused device solver replicates the same pruning decisions and
-#: importing them from here would create a cycle.  Re-exported above.
+#: importing them from here would create a cycle.
+
+
+def _rate_order(bpods: np.ndarray, bcosts: np.ndarray):
+    """Rate-order view of a bundle set: ``(order, p_sorted, c_sorted,
+    r_sorted, cum_p, cum_c, cum_r)``.  Rates are the integer unit costs
+    ``floor(cost / pods)`` (= ``floor(C_i / Pod_i)`` of the bundle's
+    item); ``cum_r`` sums the *relaxed* costs ``rate·pods <= cost`` whose
+    fractional greedy is the LP bound (rates are exact for the relaxed
+    costs, so the stable integer sort is their true rate order and the
+    bound is valid)."""
+    rates = bcosts // bpods
+    order = np.argsort(rates, kind="stable")
+    p_sorted = bpods[order]
+    c_sorted = bcosts[order]
+    r_sorted = rates[order]
+    return (order, p_sorted, c_sorted, r_sorted, np.cumsum(p_sorted),
+            np.cumsum(c_sorted), np.cumsum(r_sorted * p_sorted))
+
+
+def _lp_lower(cum_p: np.ndarray, cum_r: np.ndarray, r_sorted: np.ndarray,
+              need):
+    """Fractional greedy lower bound on the cost of covering ``need`` pods
+    (scalar or array, each ``0 <= need <= cum_p[-1]``), in int64."""
+    k = np.searchsorted(cum_p, need)
+    prev_p = np.where(k > 0, cum_p[np.maximum(k - 1, 0)], 0)
+    prev_r = np.where(k > 0, cum_r[np.maximum(k - 1, 0)], 0)
+    return prev_r + (need - prev_p) * r_sorted[k]
 
 
 def _lp_prune(bpods: np.ndarray, bcosts: np.ndarray, target: int,
-              ub_cache: Optional[dict] = None) -> np.ndarray:
+              ) -> np.ndarray:
     """Exact LP-bound pruning: drop bundles no optimal solution can use.
 
-    Sort by unit cost; the fractional greedy gives a lower bound LP(j) for
-    covering j pods and the integral greedy a feasible upper bound UB.  Any
-    solution containing bundle b costs ≥ c_b + LP(target − p_b), so bundles
-    with c_b + LP(target − p_b) > UB are provably absent from *every*
-    optimum and can be removed before the decode DP.  All optimal solutions
-    survive for any valid UB, hence the pruned instance stays feasible and
-    exact.
+    Sort by integer unit cost; the relaxed fractional greedy gives a lower
+    bound LP(j) for covering j pods and the integral greedy a feasible
+    upper bound UB.  Any solution containing bundle b costs ≥ c_b +
+    LP(target − p_b), so bundles with c_b + LP(target − p_b) > UB are
+    provably absent from *every* optimum and can be removed before the
+    decode DP.  All optimal solutions survive for any valid UB, hence the
+    pruned instance stays feasible and exact.  Integer arithmetic: the
+    test needs no guard band.
 
     The greedy prefix can overshoot badly at awkward targets (a loose UB
     lets almost every bundle survive), so when it leaves more than
     ``_CORE_TRIGGER`` bundles alive the bound is tightened by a *core DP*:
     the exact cover DP over the best-rate core bundles (which contain the
     greedy prefix, so the core optimum covers the target and its cost is a
-    valid — near-optimal in practice — UB).  ``ub_cache`` memoises the
-    core bound per target across repeated calls on one objective.
+    valid — near-optimal in practice — UB).
 
     This standalone function is the reference statement of the prune rule
     (and the form the test suite exercises); the production engine inlines
     the same ingredients in :func:`_solve_rows`, where the argsort and
     cumulative arrays are shared across every residual of an objective.
-    Every ingredient is a deterministic function of (costs, target), so
-    pruning — like everything else in the engine — is
-    batch-composition-invariant.
     """
     B = len(bpods)
     if B == 0 or target <= 0:
         return np.ones(B, dtype=bool)
-    rate = bcosts / bpods
-    order = np.argsort(rate, kind="stable")
-    p_sorted = bpods[order].astype(np.float64)
-    c_sorted = bcosts[order]
-    cum_p = np.cumsum(p_sorted)
-    cum_c = np.cumsum(c_sorted)
+    order, p_sorted, c_sorted, r_sorted, cum_p, cum_c, cum_r = _rate_order(
+        bpods, bcosts)
     if cum_p[-1] < target:                      # infeasible: caller handles
         return np.ones(B, dtype=bool)
-
-    # integral greedy upper bound: first prefix that covers the target
     k_ub = int(np.searchsorted(cum_p, target))
-    ub = float(cum_c[k_ub])
-
-    # fractional lower bound LP(j), evaluated at j = target − p_b for all b
-    resid = np.maximum(target - bpods, 0).astype(np.float64)
-    k = np.searchsorted(cum_p, resid)
-    prev_p = np.where(k > 0, cum_p[np.maximum(k - 1, 0)], 0.0)
-    prev_c = np.where(k > 0, cum_c[np.maximum(k - 1, 0)], 0.0)
-    lp = prev_c + (resid - prev_p) * (c_sorted[k] / p_sorted[k])
-    lp[resid <= 0] = 0.0
-    keep = bcosts + lp <= ub * (1.0 + 1e-12) + 1e-9
+    ub = int(cum_c[k_ub])                       # integral greedy prefix
+    lp = _lp_lower(cum_p, cum_r, r_sorted, np.maximum(target - bpods, 0))
+    keep = bcosts + lp <= ub
     if int(np.sum(keep)) <= _CORE_TRIGGER:
         return keep
-
-    core_ub = ub_cache.get(target) if ub_cache is not None else None
-    if core_ub is None:
-        K = min(B, max(k_ub + _CORE_PAD, _CORE_MIN))
-        core_ub = float(_cover_dp(bpods[order[:K]], c_sorted[:K],
-                                  target)[target])
-        if ub_cache is not None:
-            ub_cache[target] = core_ub
+    K = min(B, max(k_ub + _CORE_PAD, _CORE_MIN))
+    core_ub = int(_cover_dp(p_sorted[:K], c_sorted[:K], target)[target])
     if core_ub < ub:
-        keep = bcosts + lp <= core_ub * (1.0 + 1e-12) + 1e-9
+        keep = bcosts + lp <= core_ub
     return keep
 
 
@@ -393,10 +433,9 @@ def _backtrack_bits(bits: np.ndarray, bpods: np.ndarray, target: int,
     """Greedy improvement-bit backtrack (the seed backtracker's rule).
 
     Walking bundles last-to-first with remaining target ``j``: bundle ``b``
-    is taken iff it *strictly improved* (plain ``<``, no epsilon — dp
-    values are exact) the value at coverage ``j`` when the forward pass
-    processed it — equivalently, every optimal solution over bundles
-    ``0..b`` uses it.
+    is taken iff it *strictly improved* the value at coverage ``j`` when
+    the forward pass processed it — equivalently, every optimal solution
+    over bundles ``0..b`` uses it.
     This single rule is the engine's entire tie-breaking: backends produce
     bit-identical ``bits``, so selections are backend-invariant
     (DESIGN.md §12).
@@ -450,14 +489,15 @@ class SolveRow:
     """One (demand, objective) instance of the stacked engine invocation.
 
     ``key`` identifies the objective: rows with equal ``key`` MUST carry
-    identical ``coef``/``active`` arrays (the caller's contract) and then
-    share saturation analysis, bundle compaction, and — when their
-    LP-pruned bundle sets coincide — one padded backend DP row.
+    identical ``coef``/``icoef``/``active`` arrays (the caller's contract)
+    and then share saturation analysis, bundle compaction, and — when
+    their LP-pruned bundle sets coincide — one padded backend DP row.
     """
 
     req_pods: int
     alpha: float
-    coef: np.ndarray                       # (n,) Eq. 4–5 objective row
+    coef: np.ndarray                       # (n,) float Eq. 4–5 row (stats)
+    icoef: np.ndarray                      # (n,) int64 exact row (solves)
     active: np.ndarray                     # (n,) structural & ~exclude
     key: Hashable                          # objective identity for grouping
 
@@ -475,27 +515,29 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
     the paper's scenarios under the default config — is byte-for-byte the
     uncoarsened engine.
 
-    Pipeline (DESIGN.md §12).  Per objective key: saturation mask, covered
-    capacity, residual-DP bundle compaction, and one rate-order argsort.
-    Per unique (key, residual): LP pruning — any bundle b with
-    ``c_b + LP(residual − p_b)`` above a feasible upper bound is provably
-    in no optimal solution.  The bound starts as the integral greedy
-    prefix; when that alone leaves more than ``_CORE_TRIGGER`` bundles
-    alive, a *core DP* (value-only, over the ``max(k_greedy + _CORE_PAD,
-    _CORE_MIN)`` best-rate bundles, where optimal solutions live in
-    practice) tightens it to near-optimal, and the surviving set of the
-    tighter test is re-derived (always a subset of the greedy keep).  The
-    final improvement-bit DP then runs over each plan's kept bundles in
-    market order and its bits decode the selection.  Both backend phases
-    stack all plans into one dispatch each.  Every choice is a
-    deterministic function of (objective, residual), so a row's selection
-    is independent of what else shares the batch — the scalar path IS the
-    one-row batch.
+    Pipeline (DESIGN.md §12), all on the exact int64 row ``icoef``.  Per
+    objective key: saturation mask, covered capacity, residual-DP bundle
+    compaction, and one integer rate-order argsort.  Per unique (key,
+    residual): LP pruning — any bundle b with ``c_b + LP(residual − p_b)``
+    above a feasible upper bound is provably in no optimal solution.  The
+    bound starts as the integral greedy prefix; when that alone leaves
+    more than ``_CORE_TRIGGER`` bundles alive, a *core DP* (value-only,
+    over the ``max(k_greedy + _CORE_PAD, _CORE_MIN)`` best-rate bundles,
+    where optimal solutions live in practice) tightens it to near-optimal,
+    and the surviving set of the tighter test is re-derived (always a
+    subset of the greedy keep).  The final improvement-bit DP then runs
+    over each plan's kept bundles in market order and its bits decode the
+    selection.  Both backend phases stack all plans into one dispatch
+    each.  Every choice is a deterministic function of (objective,
+    residual), so a row's selection is independent of what else shares the
+    batch — the scalar path IS the one-row batch.  ``IlpStats.objective``
+    is the float Eq. 4–5 objective of the returned counts.
     """
     backend = backend or get_backend()
     cfg = DEFAULT_COARSENING if coarsening is None else coarsening
     gcd = market.pods_gcd
     n = market.n
+    unit = float(1 << market.scale_bits)   # integer cost units per 1.0
     results: List[Optional[List[int]]] = [None] * len(rows)
     stats: List[Optional[IlpStats]] = [None] * len(rows)
 
@@ -504,30 +546,28 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
     for r in rows:
         o = obj_cache.get(r.key)
         if o is None:
-            neg = (r.coef < 0) & r.active
+            neg = (r.icoef < 0) & r.active
             covered = int(np.sum(market.pods[neg] * market.bound[neg]))
             in_dp = r.active & ~neg
             capacity = int(np.sum(market.pods[in_dp] * market.bound[in_dp]))
             obj_cache[r.key] = o = {
                 "neg": neg, "covered": covered, "in_dp": in_dp,
-                "capacity": capacity, "coef": r.coef, "sat_counts": None,
-                "sat_obj": None, "bundles": None, "rate": None,
+                "capacity": capacity, "icoef": r.icoef, "sat_counts": None,
+                "bundles": None, "rate": None,
             }
 
-    def _saturated(o) -> Tuple[np.ndarray, float]:
+    def _saturated(o) -> np.ndarray:
         if o["sat_counts"] is None:
             counts = np.zeros(n, dtype=np.int64)
             counts[o["neg"]] = market.bound[o["neg"]]
             o["sat_counts"] = counts
-            o["sat_obj"] = float(np.sum(o["coef"][o["neg"]]
-                                        * market.bound[o["neg"]]))
-        return o["sat_counts"], o["sat_obj"]
+        return o["sat_counts"]
 
     def _bundles(o) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         if o["bundles"] is None:
             bidx = np.flatnonzero(o["in_dp"][market.b_item])
             o["bundles"] = (bidx, market.b_pods[bidx],
-                            o["coef"][market.b_item[bidx]]
+                            o["icoef"][market.b_item[bidx]]
                             * market.b_copies[bidx])
         return o["bundles"]
 
@@ -535,37 +575,15 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
         """Rate-order view of the objective's DP bundles (argsort shared
         across every residual of the objective)."""
         if o["rate"] is None:
-            _, bpods, bcosts = _bundles(o)
-            order = np.argsort(bcosts / bpods, kind="stable")
-            p_sorted = bpods[order].astype(np.float64)
-            c_sorted = bcosts[order]
-            o["rate"] = (order, p_sorted, c_sorted,
-                         np.cumsum(p_sorted), np.cumsum(c_sorted))
+            o["rate"] = _rate_order(*_bundles(o)[1:])
         return o["rate"]
 
     def _lp_bound(o, residual: int) -> np.ndarray:
         """Fractional greedy lower bound LP(residual − p_b) per bundle."""
         _, bpods, _bc = _bundles(o)
-        order, p_sorted, c_sorted, cum_p, cum_c = _rate(o)
-        rb = np.maximum(residual - bpods, 0).astype(np.float64)
-        kk = np.searchsorted(cum_p, rb)
-        prev_p = np.where(kk > 0, cum_p[np.maximum(kk - 1, 0)], 0.0)
-        prev_c = np.where(kk > 0, cum_c[np.maximum(kk - 1, 0)], 0.0)
-        lp = prev_c + (rb - prev_p) * (c_sorted[kk] / p_sorted[kk])
-        lp[rb <= 0] = 0.0
-        return lp
-
-    def _lp_at(o, residual: int) -> float:
-        """Scalar LP(residual): the fractional greedy lower bound on the
-        exact optimum — the approx tier's suboptimality certificate."""
-        if residual <= 0:
-            return 0.0
-        _order, p_sorted, c_sorted, cum_p, cum_c = _rate(o)
-        k = int(np.searchsorted(cum_p, float(residual)))
-        prev_p = float(cum_p[k - 1]) if k > 0 else 0.0
-        prev_c = float(cum_c[k - 1]) if k > 0 else 0.0
-        return prev_c + (residual - prev_p) * float(c_sorted[k]
-                                                    / p_sorted[k])
+        _o, _p, _c, r_sorted, cum_p, _cc, cum_r = _rate(o)
+        return _lp_lower(cum_p, cum_r, r_sorted,
+                         np.maximum(residual - bpods, 0))
 
     # -- classify rows; one plan per unique (objective, residual) ----------
     plans: dict = {}
@@ -583,7 +601,8 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
         pkey = (r.key, residual)
         plan = plans.get(pkey)
         if plan is None:
-            order, _p, _c, cum_p, cum_c = _rate(o)
+            order, _p, _c, r_sorted, cum_p, cum_c, cum_r = _rate(o)
+            _, _bp, bcosts = _bundles(o)
             if mode == "approx":
                 # greedy rate-order prefix down to the boundary window:
                 # the minimal prefix covering residual − window pods (its
@@ -596,43 +615,34 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
                 cov = int(cum_p[k_cut - 1]) if k_cut else 0
                 tres = max(0, residual - cov)
                 tail = order[k_cut:]
-                _, _bp, bcosts = _bundles(o)
                 # the window DP is the exact engine restated on the tail
                 # subproblem (tail capacity ≥ tres by construction), so it
                 # reuses the same greedy-UB / per-bundle-LP prune and the
-                # phase-1 core tightening; lp = +inf off-tail keeps the
+                # phase-1 core tightening; lp = INF off-tail keeps the
                 # committed prefix out of the DP (binary bundles are
                 # use-once).
-                lp = np.full(len(bcosts), _INF)
-                ub, core, keep = 0.0, None, np.zeros(len(bcosts), bool)
+                lp = np.full(len(bcosts), exact.INF, dtype=np.int64)
+                ub, core, keep = 0, None, np.zeros(len(bcosts), bool)
                 if tres > 0 and len(tail):
-                    tp, tc = _p[k_cut:], _c[k_cut:]
-                    base_p = float(cum_p[k_cut - 1]) if k_cut else 0.0
-                    base_c = float(cum_c[k_cut - 1]) if k_cut else 0.0
-                    cum_tp = cum_p[k_cut:] - base_p
-                    cum_tc = cum_c[k_cut:] - base_c
-                    k_ub = int(np.searchsorted(cum_tp, float(tres)))
-                    ub = float(cum_tc[k_ub])
-                    rb = np.maximum(tres - tp, 0).astype(np.float64)
-                    kk = np.searchsorted(cum_tp, rb)
-                    prev_p = np.where(kk > 0, cum_tp[np.maximum(kk - 1, 0)],
-                                      0.0)
-                    prev_c = np.where(kk > 0, cum_tc[np.maximum(kk - 1, 0)],
-                                      0.0)
-                    lp_t = prev_c + (rb - prev_p) * (tc[kk] / tp[kk])
-                    lp_t[rb <= 0] = 0.0
-                    lp[tail] = lp_t
-                    keep = bcosts + lp <= ub * (1.0 + 1e-12) + 1e-9
+                    def _tail(cum):
+                        return cum[k_cut:] - (cum[k_cut - 1] if k_cut else 0)
+                    cum_tp, cum_tc, cum_tr = (_tail(cum_p), _tail(cum_c),
+                                              _tail(cum_r))
+                    k_ub = int(np.searchsorted(cum_tp, tres))
+                    ub = int(cum_tc[k_ub])
+                    lp[tail] = _lp_lower(cum_tp, cum_tr, r_sorted[k_cut:],
+                                         np.maximum(tres - _p[k_cut:], 0))
+                    keep = bcosts + lp <= ub
                     if int(np.sum(keep)) > _CORE_TRIGGER:
                         K = min(len(tail), max(k_ub + _CORE_PAD, _CORE_MIN))
                         core = tail[:K]
                 plans[pkey] = plan = {
                     "o": o, "resid": residual, "mode": "approx",
                     "window": param, "prefix": order[:k_cut],
-                    "pcost": float(cum_c[k_cut - 1]) if k_cut else 0.0,
+                    "pcost": int(cum_c[k_cut - 1]) if k_cut else 0,
                     "tres": tres, "scale": 1, "sres": tres,
                     "lp": lp, "ub": ub, "core": core, "keep": keep,
-                    "counts": None, "objective": _INF, "n_bundles": 0,
+                    "counts": None, "value": exact.INF, "n_bundles": 0,
                     "coarse": "approx", "gap": 0.0}
                 row_plan.append(("dp", plan, residual))
                 continue
@@ -645,9 +655,8 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
             sres = -(-residual // scale)
             k_ub = int(np.searchsorted(cum_p, residual))
             lp = _lp_bound(o, residual)
-            _, _bp, bcosts = _bundles(o)
-            ub = float(cum_c[k_ub])            # integral greedy prefix
-            keep = bcosts + lp <= ub * (1.0 + 1e-12) + 1e-9
+            ub = int(cum_c[k_ub])              # integral greedy prefix
+            keep = bcosts + lp <= ub
             core = None
             if int(np.sum(keep)) > _CORE_TRIGGER:
                 # loose greedy bound: plan a core DP to tighten it first
@@ -657,7 +666,7 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
                 "o": o, "resid": residual, "mode": mode, "scale": scale,
                 "sres": sres, "lp": lp, "ub": ub,
                 "core": core, "keep": keep, "counts": None,
-                "objective": _INF, "n_bundles": 0,
+                "value": exact.INF, "n_bundles": 0,
                 "coarse": "gcd" if scale > 1 else "exact", "gap": 0.0}
         row_plan.append(("dp", plan, residual))
 
@@ -680,38 +689,38 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
             # the core contains the greedy cover prefix, so its optimum is
             # finite and ≤ the greedy bound; survivors of the tighter test
             # are a subset of the greedy keep
-            core_ub = float(dp[p["sres"]])
+            core_ub = int(dp[p["sres"]])
             if core_ub < p["ub"]:
                 p["ub"] = core_ub
                 _, _bp, bcosts = _bundles(p["o"])
-                p["keep"] = bcosts + p["lp"] <= core_ub * (1.0 + 1e-12) + 1e-9
+                p["keep"] = bcosts + p["lp"] <= core_ub
 
     def _exact_plan(o, residual: int):
         """One-row exact prune + DP + decode — the approx tier's fallback.
         A deterministic function of (objective, residual), identical to
         what the batched exact path produces for the same pair."""
-        order, _p, _c, cum_p, cum_c = _rate(o)
+        order, _p, _c, _r, cum_p, cum_c, _cr = _rate(o)
         bidx, bpods, bcosts = _bundles(o)
         k_ub = int(np.searchsorted(cum_p, residual))
         lp = _lp_bound(o, residual)
-        ub = float(cum_c[k_ub])
-        keep = bcosts + lp <= ub * (1.0 + 1e-12) + 1e-9
+        ub = int(cum_c[k_ub])
+        keep = bcosts + lp <= ub
         if int(np.sum(keep)) > _CORE_TRIGGER:
             K = min(len(order), max(k_ub + _CORE_PAD, _CORE_MIN))
             core = order[:K]
             dp = backend.cover_values(
                 [(bpods[core], bcosts[core], residual)])[0]
-            core_ub = float(dp[residual])
+            core_ub = int(dp[residual])
             if core_ub < ub:
-                keep = bcosts + lp <= core_ub * (1.0 + 1e-12) + 1e-9
+                keep = bcosts + lp <= core_ub
         kept = np.flatnonzero(keep)
         dp, bits = backend.cover_bits(
             [(bpods[kept], bcosts[kept], residual)])[0]
         take = _backtrack_bits(bits, bpods[kept], residual)
-        return bidx[kept[take]], float(dp[residual]), len(kept)
+        return bidx[kept[take]], int(dp[residual]), len(kept)
 
     def _approx_finish(p, tail_taken: Optional[np.ndarray],
-                       tail_obj: float) -> None:
+                       tail_value: int) -> None:
         """Assemble an approx plan from its greedy prefix + boundary-DP
         take (``tail_taken`` in market bundle order), then check the LP
         certificate: the prefix + exact-window total is a feasible
@@ -720,34 +729,35 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
         Certificate violated → exact fallback."""
         o = p["o"]
         bidx, _bp, _bc = _bundles(o)
-        total = p["pcost"] + tail_obj
-        lp = _lp_at(o, p["resid"])
+        _o, _p, _c, r_sorted, cum_p, _cc, cum_r = _rate(o)
+        total = p["pcost"] + tail_value
+        lp = int(_lp_lower(cum_p, cum_r, r_sorted, p["resid"]))
         gap = total - lp
-        if gap <= cfg.rel_gap * max(abs(lp), 1e-9):
+        if gap <= cfg.rel_gap * max(abs(lp), 1):
             taken = (p["prefix"] if tail_taken is None else
                      np.concatenate([p["prefix"], tail_taken]))
             p["counts"] = bidx[taken]
-            p["objective"] = total
+            p["value"] = total
             p["n_bundles"] += len(p["prefix"])
-            p["gap"] = max(gap, 0.0)
+            p["gap"] = max(gap, 0) / unit
         else:
-            p["counts"], p["objective"], p["n_bundles"] = _exact_plan(
+            p["counts"], p["value"], p["n_bundles"] = _exact_plan(
                 o, p["resid"])
             p["coarse"] = "approx_fallback"
             p["gap"] = 0.0
 
     # -- phase 2: the decode DP over each plan's kept set ------------------
     # dispatched in backend-preferred slices: the host backend keeps the
-    # live bits working set small, accelerator backends take it all at
-    # once.  Approx plans ride the same dispatch: their req is the exact
-    # boundary-window DP over the pruned non-prefix bundles.
+    # live bits working set small.  Approx plans ride the same dispatch:
+    # their req is the exact boundary-window DP over the pruned non-prefix
+    # bundles.
     chunk = max(1, getattr(backend, "max_group_batch", len(plan_list) or 1))
     for lo in range(0, len(plan_list), chunk):
         part = plan_list[lo:lo + chunk]
         reqs, ready = [], []
         for p in part:
             if p["mode"] == "approx" and p["tres"] == 0:
-                _approx_finish(p, None, 0.0)  # prefix covers the demand
+                _approx_finish(p, None, 0)  # prefix covers the demand
                 continue
             _, bpods, bcosts = _bundles(p["o"])
             p["kept"] = np.flatnonzero(p["keep"])    # market bundle order
@@ -760,10 +770,10 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
             take = _backtrack_bits(
                 bits, _scaled(bpods, p["scale"])[p["kept"]], p["sres"])
             if p["mode"] == "approx":
-                _approx_finish(p, p["kept"][take], float(dp[p["sres"]]))
+                _approx_finish(p, p["kept"][take], int(dp[p["sres"]]))
                 continue
             p["counts"] = bidx[p["kept"][take]]
-            p["objective"] = float(dp[p["sres"]])
+            p["value"] = int(dp[p["sres"]])
 
     # -- assemble rows (duplicates share decoded plans) --------------------
     for i, (r, (kind, ctx, residual)) in enumerate(zip(rows, row_plan)):
@@ -771,18 +781,18 @@ def _solve_rows(market: CompiledMarket, rows: Sequence[SolveRow],
         if kind == "none":
             stats[i] = IlpStats(n, 0, residual, _INF)
             continue
-        sat_counts, sat_obj = _saturated(o)
+        counts = _saturated(o)
         if kind == "sat":
-            results[i] = list(map(int, sat_counts))
-            stats[i] = IlpStats(n, 0, 0, sat_obj)
+            results[i] = list(map(int, counts))
+            stats[i] = IlpStats(n, 0, 0, float(np.dot(r.coef, counts)))
             continue
         plan = ctx
-        counts = sat_counts.copy()
+        counts = counts.copy()
         taken = plan["counts"]
         np.add.at(counts, market.b_item[taken], market.b_copies[taken])
         results[i] = list(map(int, counts))
         stats[i] = IlpStats(
-            n, plan["n_bundles"], residual, sat_obj + plan["objective"],
+            n, plan["n_bundles"], residual, float(np.dot(r.coef, counts)),
             coarse=plan["coarse"],
             granularity=(plan["window"] if plan["mode"] == "approx"
                          else plan["scale"]),
@@ -815,7 +825,6 @@ def solve_ilp(items: Sequence[CandidateItem], req_pods: int, alpha: float,
               market: Optional[CompiledMarket] = None,
               exclude: Optional[np.ndarray] = None,
               backend: Optional[SolverBackend] = None,
-              coef: Optional[np.ndarray] = None,
               coarsening: Optional[CoarseningConfig] = None,
               ) -> Optional[List[int]] | Tuple[Optional[List[int]], IlpStats]:
     """Exact solver for Eq. 5.  Returns x_i per item (None if infeasible).
@@ -823,25 +832,15 @@ def solve_ilp(items: Sequence[CandidateItem], req_pods: int, alpha: float,
     ``market`` reuses a :class:`CompiledMarket` (skips preprocessing);
     ``exclude`` is a per-item boolean mask of offerings barred from the
     solution (the §4.1 interrupted-offerings cache), applied at solve time
-    so the compiled market survives interrupt churn.  ``coef`` optionally
-    supplies the precomputed objective row (GSS evaluators cache
-    ``market.norms(exclude)`` and rebuild rows per probe — bit-identical
-    to the uncached path); it must equal
-    ``market.coefficients([alpha], exclude)[0]``.  ``coarsening``
-    overrides the demand-coarsening policy (default
+    so the compiled market survives interrupt churn.  α is solved at its
+    nearest grid point :func:`exact.alpha_k` (exact on the GSS grid).
+    ``coarsening`` overrides the demand-coarsening policy (default
     :data:`DEFAULT_COARSENING`, inert below 8192 residual pods).
     """
-    market = _checked_market(items, market)
-    if market.n == 0:
-        return _empty_market_result(req_pods, return_stats)
-    if coef is None:
-        coef = market.coefficients(np.array([alpha]), exclude)[0]
-    active = market.structural if exclude is None else (
-        market.structural & ~exclude)
-    results, stats = _solve_rows(
-        market, [SolveRow(req_pods, alpha, coef, active, key=0)], backend,
-        coarsening=coarsening)
-    return (results[0], stats[0]) if return_stats else results[0]
+    out = solve_ilp_batch(items, req_pods, [alpha], market=market,
+                          exclude=exclude, return_stats=True,
+                          backend=backend, coarsening=coarsening)
+    return (out[0][0], out[1][0]) if return_stats else out[0][0]
 
 
 def solve_ilp_batch(items: Sequence[CandidateItem], req_pods: int,
@@ -868,9 +867,9 @@ def solve_ilp_batch(items: Sequence[CandidateItem], req_pods: int,
         stats = [single[1] for _ in grid]
         return (results, stats) if return_stats else results
     coef2d = market.coefficients(np.asarray(grid, dtype=np.float64), exclude)
-    active = market.structural if exclude is None else (
-        market.structural & ~exclude)
-    rows = [SolveRow(req_pods, a, coef2d[k], active, key=a)
+    icoef2d, active = market.int_coefficients(
+        [exact.alpha_k(a) for a in grid], exclude)
+    rows = [SolveRow(req_pods, a, coef2d[k], icoef2d[k], active, key=a)
             for k, a in enumerate(grid)]
     results, stats = _solve_rows(market, rows, backend,
                                  coarsening=coarsening)
@@ -946,19 +945,23 @@ def solve_ilp_many(items: Sequence[CandidateItem],
                 per_tok_seen[tok][a] = len(per_tok_alphas[tok])
                 per_tok_alphas[tok].append(a)
     coef_rows: List[np.ndarray] = []
+    icoef_rows: List[np.ndarray] = []
     actives: List[np.ndarray] = []
     for tok, mask in enumerate(masks):
         coef_rows.append(market.coefficients(
             np.asarray(per_tok_alphas[tok], dtype=np.float64), mask))
-        actives.append(market.structural if mask is None
-                       else market.structural & ~mask)
+        icoef, active = market.int_coefficients(
+            [exact.alpha_k(a) for a in per_tok_alphas[tok]], mask)
+        icoef_rows.append(icoef)
+        actives.append(active)
 
     rows: List[SolveRow] = []
     for d in range(n_dec):
         tok = token_of[d]
         for a in grids[d]:
+            j = per_tok_seen[tok][a]
             rows.append(SolveRow(
-                requests[d], a, coef_rows[tok][per_tok_seen[tok][a]],
+                requests[d], a, coef_rows[tok][j], icoef_rows[tok][j],
                 actives[tok], key=(tok, a)))
     flat, flat_stats = _solve_rows(market, rows, backend,
                                    coarsening=coarsening)

@@ -138,6 +138,7 @@ def _roofline_token_s(profile: ServingProfile) -> float:
     import jax.numpy as jnp
 
     from repro import serving, sharding
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import SHAPES, InputShape, get_config
     from repro.data.pipeline import batch_pspecs, batch_specs
     from repro.models import transformer
@@ -151,7 +152,7 @@ def _roofline_token_s(profile: ServingProfile) -> float:
                       global_batch=min(profile.batch_per_pod, 8),
                       kind="decode")
     rules = sharding.single_pod_rules()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with sharding.mesh_context(mesh, rules):
         aparams = transformer.abstract_params(cfg)
         acache = transformer.abstract_cache(cfg, cell.global_batch,

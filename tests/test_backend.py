@@ -1,7 +1,8 @@
-"""Solver-backend layer (DESIGN.md §12): numpy ≡ jax at the level of
-*selected pools*, cross-decision batching ≡ per-decision solving, the
-collect-then-solve fleet tick phase ≡ the sequential one, the NumPy
-fallback when jax is absent, and the heterogeneous-demand jitter contract.
+"""Solver-backend layer (DESIGN.md §12–13): numpy ≡ the fused device plane
+at the level of *selected pools*, cross-decision batching ≡ per-decision
+solving, the collect-then-solve fleet tick phase ≡ the sequential one, the
+loud failures that replaced every silent fallback, the compile-cache
+placement, and the heterogeneous-demand jitter contract.
 """
 
 import dataclasses
@@ -12,7 +13,7 @@ import pytest
 
 from repro.core import (NumpyBackend, Request,
                         compile_market, preprocess, generate_catalog,
-                        make_backend, objective_coefficients, solve_ilp,
+                        make_backend, objective_coefficients,
                         solve_ilp_batch, solve_ilp_many)
 from repro.core import backend as backend_mod
 from repro.core.gss import bracketed_gss, bracketed_gss_many
@@ -25,48 +26,15 @@ from .strategies import random_exclude as _random_exclude
 from .strategies import random_market as _random_market
 
 NUMPY = NumpyBackend()
-JAX = make_backend("jax") if HAVE_JAX else None
+FUSED = make_backend("jax:fused") if HAVE_JAX else None
 
 
-# ---------------------------------------------------------- numpy ≡ jax ----
-
-@requires_jax
-def test_jax_equals_numpy_selected_pools_100_markets():
-    """≥100 randomized markets × α grid incl. {0, 1} edges, with and
-    without exclusion masks, empty and infeasible targets: the jax backend
-    must return the *identical count vectors* (not merely equal
-    objectives) as the numpy backend — the bit-identical-selection
-    contract."""
-    rng = np.random.default_rng(11)
-    n_markets = 110
-    n_infeasible = n_masked = 0
-    for _ in range(n_markets):
-        items = _random_market(rng)
-        market = compile_market(items)
-        req = int(rng.integers(0, 90))
-        exclude = _random_exclude(rng, len(items))
-        if exclude is not None:
-            n_masked += 1
-        alphas = [0.0, 1.0] + [float(a) for a in rng.uniform(0, 1, size=3)]
-        got_n = solve_ilp_batch(items, req, alphas, market=market,
-                                exclude=exclude, backend=NUMPY)
-        got_j = solve_ilp_batch(items, req, alphas, market=market,
-                                exclude=exclude, backend=JAX)
-        assert got_n == got_j
-        n_infeasible += sum(c is None for c in got_n)
-    assert n_infeasible > 0 and n_masked > 10
-
-
-@requires_jax
-def test_jax_equals_numpy_empty_market():
-    assert solve_ilp([], 0, 0.5, backend=JAX) == []
-    assert solve_ilp([], 5, 0.5, backend=JAX) is None
-
+# -------------------------------------------------- numpy ≡ device plane ----
 
 @requires_jax
 def test_jax_backend_on_real_catalog_cycle():
     """A full guarded-GSS cycle on a generated catalog returns the same
-    pool and trace through either backend."""
+    pool and trace through the NumPy engine and the fused device plane."""
     cat = generate_catalog(seed=3, max_offerings=150)
     items = preprocess(cat, Request(pods=800, cpu_per_pod=2, mem_per_pod=2))
     market = compile_market(items)
@@ -74,37 +42,9 @@ def test_jax_backend_on_real_catalog_cycle():
     (pn, tn), = bracketed_gss_many(items, [800], market=market, timer=fake,
                                    backend=NUMPY)
     (pj, tj), = bracketed_gss_many(items, [800], market=market, timer=fake,
-                                   backend=JAX)
+                                   backend=FUSED)
     assert pn.as_dict() == pj.as_dict() and pn.alpha == pj.alpha
     assert tn.alphas == tj.alphas and tn.e_totals == tj.e_totals
-
-
-@requires_jax
-def test_pallas_flag_matches_plain_jax():
-    """The Pallas step kernel (interpret mode on CPU) is bit-identical to
-    the plain scan step."""
-    pallas = make_backend("jax:pallas")
-    rng = np.random.default_rng(5)
-    bpods = rng.integers(1, 40, size=24).astype(np.int64)
-    costs = rng.uniform(0, 3, size=24)
-    costs[rng.random(24) < 0.2] = np.inf
-    (dp_j, bits_j), = JAX.cover_bits([(bpods, costs, 120)])
-    (dp_p, bits_p), = pallas.cover_bits([(bpods, costs, 120)])
-    (dp_n, bits_n), = NUMPY.cover_bits([(bpods, costs, 120)])
-    assert np.array_equal(dp_j, dp_n) and np.array_equal(dp_p, dp_n)
-    assert np.array_equal(bits_j, bits_n) and np.array_equal(bits_p, bits_n)
-
-
-@requires_jax
-def test_jax_cover_values_matches_cover_bits_dp():
-    rng = np.random.default_rng(9)
-    groups = [(rng.integers(1, 30, size=17).astype(np.int64),
-               rng.uniform(0, 2, size=17), int(rng.integers(1, 200)))
-              for _ in range(5)]
-    dps = JAX.cover_values(groups)
-    full = JAX.cover_bits(groups)
-    for dp, (dp2, _bits) in zip(dps, full):
-        assert np.array_equal(dp, dp2)
 
 
 # ------------------------------------------------- cross-decision batch ----
@@ -267,11 +207,12 @@ def test_jitter_replay_reproduces_decisions():
     assert res.decision_records() == replay.decision_records()
 
 
-# ---------------------------------------------------------- jax fallback ----
+# --------------------------------------------------------- loud failures ----
 
-def test_backend_falls_back_to_numpy_with_warning(monkeypatch):
-    """Requesting the jax backend without jax installed warns once and
-    returns the numpy backend — core/ilp.py never imports jax itself."""
+def test_jax_spec_without_jax_raises(monkeypatch):
+    """Requesting the device plane without jax installed fails loudly —
+    no NumPy stand-in, no warning — while core/ilp.py never imports jax
+    itself and the numpy spec still builds."""
     import builtins
     real_import = builtins.__import__
 
@@ -280,18 +221,15 @@ def test_backend_falls_back_to_numpy_with_warning(monkeypatch):
             raise ImportError("no jax in this environment")
         return real_import(name, *args, **kwargs)
 
-    from repro.core import events_log
     monkeypatch.setattr(builtins, "__import__", no_jax)
-    events_log.reset()                        # drop the warn-once latch
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        be = backend_mod.make_backend("jax")
-    assert isinstance(be, NumpyBackend)
-    assert events_log.counters()["backend_numpy_fallback"] == 1
     with warnings.catch_warnings():
-        warnings.simplefilter("error")        # second request: warn once
-        assert isinstance(backend_mod.make_backend("jax"), NumpyBackend)
-    # ... but every occurrence is still counted (DESIGN.md §16)
-    assert events_log.counters()["backend_numpy_fallback"] == 2
+        warnings.simplefilter("error")
+        with pytest.raises(ImportError, match="no jax"):
+            backend_mod.make_backend("jax:fused")
+        assert isinstance(backend_mod.make_backend("numpy"), NumpyBackend)
+    for gone in ("jax", "jax:pallas", "jax:fused:pallas"):
+        with pytest.raises(ValueError, match="unknown solver backend"):
+            backend_mod.make_backend(gone)
 
 
 def test_env_selects_default_backend(monkeypatch):
@@ -332,7 +270,7 @@ def test_x64_flip_env_opt_out_and_warning():
         "os.environ['KUBEPACS_JAX_X64'] = '0'\n"
         "from repro.core import make_backend\n"
         "try:\n"
-        "    make_backend('jax')\n"
+        "    make_backend('jax:fused')\n"
         "    raise SystemExit('opt-out did not refuse')\n"
         "except RuntimeError as e:\n"
         "    assert 'jax_enable_x64' in str(e)\n"
@@ -340,7 +278,7 @@ def test_x64_flip_env_opt_out_and_warning():
         "del os.environ['KUBEPACS_JAX_X64']\n"
         "with warnings.catch_warnings(record=True) as w:\n"
         "    warnings.simplefilter('always')\n"
-        "    make_backend('jax')\n"
+        "    make_backend('jax:fused')\n"
         "assert any('x64' in str(x.message) for x in w)\n"
         "print('WARNED')\n"
     )
@@ -354,9 +292,6 @@ def test_x64_flip_env_opt_out_and_warning():
 
 # ------------------------------------------------- fused decision plane ----
 
-FUSED = make_backend("jax:fused") if HAVE_JAX else None
-
-
 def _gss_summary(results):
     """(pool dict, alpha, trace alphas, trace e_totals) per decision —
     the full byte-comparable decision record."""
@@ -368,10 +303,10 @@ def _gss_summary(results):
 @requires_jax
 def test_fused_equals_numpy_pools_110_markets():
     """The device-resident GSS (one jitted while_loop, counts read back
-    once) selects the identical pools/alphas/traces as the host engine and
-    the per-dispatch jax backend over 110 randomized markets with masks,
-    infeasible and zero demands — and resolves every probe from the device
-    record (zero host-fallback solves)."""
+    once) selects the identical pools/alphas/traces as the host engine
+    over 110 randomized markets with masks, infeasible and zero demands —
+    and resolves every probe from the device record (zero host-fallback
+    solves)."""
     rng = np.random.default_rng(11)
     fake = lambda: 0.0                                     # noqa: E731
     base_fb = FUSED.device_cache_info()["fallback_solves"]
@@ -389,11 +324,7 @@ def test_fused_equals_numpy_pools_110_markets():
         got_f = bracketed_gss_many(items, reqs, market=market,
                                    excludes=excludes, timer=fake,
                                    backend=FUSED)
-        got_j = bracketed_gss_many(items, reqs, market=market,
-                                   excludes=excludes, timer=fake,
-                                   backend=JAX)
-        sn = _gss_summary(got_n)
-        assert sn == _gss_summary(got_f) == _gss_summary(got_j)
+        assert _gss_summary(got_n) == _gss_summary(got_f)
         n_infeasible += sum(p is None for p, _t in got_n)
     assert n_infeasible > 0 and n_masked > 10
     assert FUSED.device_cache_info()["fallback_solves"] == base_fb
@@ -406,25 +337,6 @@ def test_fused_empty_market_and_zero_demand():
     assert p0 is not None and p0.as_dict() == {}
     (p1, _t), = bracketed_gss_many([], [5], timer=fake, backend=FUSED)
     assert p1 is None
-
-
-@requires_jax
-def test_fused_pallas_spec_matches_numpy():
-    """``jax:fused:pallas`` (real cover-DP + scoring kernels, interpret
-    mode on CPU) selects the identical pools; small markets only — the
-    interpreter is slow."""
-    pallas = make_backend("jax:fused:pallas")
-    rng = np.random.default_rng(23)
-    fake = lambda: 0.0                                     # noqa: E731
-    for _ in range(3):
-        items = _random_market(rng, max_items=6, max_t3=4)
-        market = compile_market(items)
-        reqs = [int(rng.integers(0, 40))]
-        got_n = bracketed_gss_many(items, reqs, market=market, timer=fake,
-                                   backend=NUMPY)
-        got_p = bracketed_gss_many(items, reqs, market=market, timer=fake,
-                                   backend=pallas)
-        assert _gss_summary(got_n) == _gss_summary(got_p)
 
 
 @requires_jax
@@ -456,48 +368,11 @@ def test_fused_device_cache_hit_and_invalidation():
 
 
 @requires_jax
-def test_pallas_cover_block_divisibility_guard():
-    """A bundle pad that is not a multiple of the 32-wide kernel block
-    must fail loudly at build time, not silently truncate the grid."""
-    with pytest.raises(ValueError, match="multiple"):
-        FUSED._pallas_cover_fn(129, 33, True)
-    for rung in backend_mod.FusedJaxBackend._BF_STEPS:
-        assert rung % 32 == 0 or rung < 32   # the invariant the guard pins
-
-
-@requires_jax
-def test_pallas_kernel_selfcheck_bitwise_on_live_lowering():
-    """The cover kernel's sequential-grid accumulator idiom is only
-    trusted after a bitwise dp+bits probe against the NumPy reference on
-    the live lowering (interpret mode here); a failing probe silently
-    drops the fused programs back to the lax.scan path — selections
-    unchanged."""
-    be = make_backend("jax:fused:pallas")
-    assert be._run_pallas_check(interpret=True) is True
-    assert be._fused_flags() == (True, True)
-
-    # simulate a racy lowering (GPU/Triton parallel grid): the kernel is
-    # refused and the scan path still selects numpy's pools
-    be_bad = make_backend("jax:fused:pallas")
-    be_bad._run_pallas_check = lambda interpret: False
-    assert be_bad._fused_flags()[0] is False
-    rng = np.random.default_rng(31)
-    fake = lambda: 0.0                                     # noqa: E731
-    items = _random_market(rng, max_items=6, max_t3=4)
-    market = compile_market(items)
-    got_n = bracketed_gss_many(items, [15], market=market, timer=fake,
-                               backend=NUMPY)
-    got_b = bracketed_gss_many(items, [15], market=market, timer=fake,
-                               backend=be_bad)
-    assert _gss_summary(got_n) == _gss_summary(got_b)
-
-
-@requires_jax
-def test_prescan_host_crosscheck_disables_fused_on_divergence():
+def test_prescan_host_crosscheck_raises_on_divergence():
     """Device prescan counts are never consumed unverified: each batch
     cross-checks one sampled (decision, α) row against the NumPy engine,
-    and a mismatch warns, permanently disables the fused path, and leaves
-    selections on the host engine — bit-identical, never corrupted."""
+    and a mismatch raises — it neither changes a selection nor quietly
+    hands the batch to the host.  The backend stays usable."""
     be = make_backend("jax:fused")
     orig = be._run_prescan
 
@@ -513,22 +388,64 @@ def test_prescan_host_crosscheck_disables_fused_on_divergence():
     fake = lambda: 0.0                                     # noqa: E731
     items = _random_market(rng, max_items=6)
     market = compile_market(items)
-    got_n = bracketed_gss_many(items, [20], market=market, timer=fake,
+    with pytest.raises(backend_mod.PrescanMismatch, match="diverged"):
+        bracketed_gss_many(items, [20], market=market, timer=fake,
+                           backend=be)
+    assert be.fused_records == 0
+    be._run_prescan = orig
+    got_f = bracketed_gss_many(items, [25], market=market, timer=fake,
+                               backend=be)
+    got_n = bracketed_gss_many(items, [25], market=market, timer=fake,
                                backend=NUMPY)
-    with pytest.warns(RuntimeWarning, match="diverged"):
-        got_f = bracketed_gss_many(items, [20], market=market, timer=fake,
-                                   backend=be)
     assert _gss_summary(got_n) == _gss_summary(got_f)
-    assert be._fused_ok() is False           # disabled for the process
-    # subsequent batches decline the fused path outright (no new warning,
-    # no record) and stay correct
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        got_f2 = bracketed_gss_many(items, [25], market=market, timer=fake,
-                                    backend=be)
-    got_n2 = bracketed_gss_many(items, [25], market=market, timer=fake,
-                                backend=NUMPY)
-    assert _gss_summary(got_n2) == _gss_summary(got_f2)
+    assert be.fused_records == 1
+
+
+@requires_jax
+def test_fused_device_error_propagates():
+    """A failing device program raises out of bracketed_gss_many: no
+    except in the plane turns it into a host or per-dispatch solve."""
+    be = make_backend("jax:fused")
+
+    def broken(*_a, **_k):
+        raise RuntimeError("device program failed")
+
+    be._run_golden = broken
+    rng = np.random.default_rng(43)
+    items = _random_market(rng, max_items=6)
+    with pytest.raises(RuntimeError, match="device program failed"):
+        bracketed_gss_many(items, [20], market=compile_market(items),
+                           timer=lambda: 0.0, backend=be)
+    assert be.fallback_solves == 0 and be.declined_batches == 0
+
+
+@requires_jax
+def test_fused_prescan_rows_equal_numpy_110_markets():
+    """The device row solver alone — prescan rows at arbitrary grid
+    indices, not only the GSS grid — returns the host engine's exact
+    count vectors over 110 randomized markets, masks and demands."""
+    from repro.core import exact
+    rng = np.random.default_rng(47)
+    n_infeasible = 0
+    for _ in range(110):
+        items = _random_market(rng)
+        market = compile_market(items)
+        reqs = [int(rng.integers(0, 90))
+                for _ in range(int(rng.integers(1, 4)))]
+        excludes = [_random_exclude(rng, len(items)) for _ in reqs]
+        ks = [0, exact.ALPHA_ONE] + [
+            int(k) for k in rng.integers(0, exact.ALPHA_ONE, 3)]
+        counts, feas = FUSED._run_prescan(market, reqs, excludes, ks)
+        ref = solve_ilp_many(items, reqs,
+                             [exact.k_alpha(k) for k in ks],
+                             market=market, excludes=excludes,
+                             backend=NUMPY)
+        for d in range(len(reqs)):
+            for g in range(len(ks)):
+                got = list(map(int, counts[d, g])) if feas[d, g] else None
+                assert got == ref[d][g], (d, g)
+                n_infeasible += got is None
+    assert n_infeasible > 0
 
 
 @requires_jax
@@ -555,3 +472,49 @@ def test_fleet_fused_traces_byte_identical():
     stats = fused[0].cache_stats
     assert stats.get("device_cache_fallback_solves") == 0
     assert stats.get("device_cache_entries", 0) >= 1
+
+
+# ------------------------------------------------------ compile cache ----
+
+@requires_jax
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(from_env, tmp_path):
+    """Compiled device programs persist where JAX_COMPILATION_CACHE_DIR
+    says, and at the fixed <checkout>/.jax_cache when it is unset (fresh
+    process: the cache directory binds at the first compile)."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    root = pathlib.Path(backend_mod.__file__).resolve().parents[3]
+    expect = tmp_path / "cache" if from_env else root / ".jax_cache"
+    code = (
+        "import jax, pathlib\n"
+        "from repro.core import make_backend, compile_market\n"
+        "from repro.core.gss import bracketed_gss_many\n"
+        "from tests.strategies import random_market\n"
+        "import numpy as np\n"
+        "be = make_backend('jax:fused')\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "print(pathlib.Path(jax.config.jax_compilation_cache_dir))\n"
+        "items = random_market(np.random.default_rng(3), max_items=5)\n"
+        "bracketed_gss_many(items, [9], market=compile_market(items),\n"
+        "                   backend=be)\n"
+    )
+    env = dict(os.environ, PYTHONPATH=f"{root / 'src'}:{root}")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(expect)
+    before = set(expect.iterdir()) if expect.is_dir() else set()
+    res = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr
+    assert pathlib.Path(res.stdout.strip().splitlines()[-1]) == expect
+    assert backend_mod.compile_cache_dir() == (
+        pathlib.Path(os.environ["JAX_COMPILATION_CACHE_DIR"])
+        if os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        else root / ".jax_cache")
+    after = set(expect.iterdir())
+    # a fresh directory gains entries; the shared checkout cache may
+    # already hold these programs from an earlier run
+    assert (after - before) if from_env else after

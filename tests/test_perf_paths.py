@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from repro import sharding
 from repro.configs import get_config
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 from repro.models import init_params, loss_fn
 from repro.models import moe as moe_mod
 
@@ -93,7 +94,7 @@ def test_full_model_loss_invariant_under_mesh_flags():
     batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 16))),
              "targets": jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 16)))}
     loss0, _ = loss_fn(params, cfg, batch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = dataclasses.replace(sharding.single_pod_rules(fsdp=True),
                                 attn_mode="auto", ep_shard_map=True)
     with sharding.mesh_context(mesh, rules):
