@@ -399,15 +399,16 @@ class FusedJaxBackend(NumpyBackend):
             out[:len(a)] = a
             return out
 
-        ent = tuple(self._jnp.asarray(a) for a in (
-            pad(market.pods, N, np.int32),
-            pad(market.bound, N, np.int32),
-            pad(market.b_item, B, np.int32),
-            pad(market.b_pods, B, np.int32, fill=1),
-            pad(market.b_copies, B, np.int32),
-            pad(np.ones(nb, bool), B, bool),
-            pad(market.perf, N, np.float32),
-            pad(market.price, N, np.float32, fill=1.0)))
+        with events_log.span("kubepacs.device.upload"):
+            ent = tuple(self._jnp.asarray(a) for a in (
+                pad(market.pods, N, np.int32),
+                pad(market.bound, N, np.int32),
+                pad(market.b_item, B, np.int32),
+                pad(market.b_pods, B, np.int32, fill=1),
+                pad(market.b_copies, B, np.int32),
+                pad(np.ones(nb, bool), B, bool),
+                pad(market.perf, N, np.float32),
+                pad(market.price, N, np.float32, fill=1.0)))
         self._market_cache[key] = ent
         while len(self._market_cache) > self._MAX_MARKETS:
             self._market_cache.popitem(last=False)
@@ -444,6 +445,7 @@ class FusedJaxBackend(NumpyBackend):
         """
         jax, jnp = self._jax, self._jnp
         lax = jax.lax
+        scope = jax.named_scope
         pods, bound, b_item, b_pods, b_copies, b_real, perf, price = md
         i32, i64 = jnp.int32, jnp.int64
         INF = i64(exact.INF)
@@ -505,40 +507,44 @@ class FusedJaxBackend(NumpyBackend):
             return lax.associative_scan(jnp.add, v)
 
         # -- one engine row on device ----------------------------------------
+        # each stage runs under a jax.named_scope (DESIGN.md §13: spans and
+        # stage names), which the profiler's trace carries per operation
         def solve_row(coef, active, req):
-            neg = (coef < 0) & active
-            sat = jnp.where(neg, bound, 0)
-            covered = jnp.sum(jnp.where(neg, item_nodes, 0))
-            residual = jnp.maximum(req.astype(i64) - covered, 0)
-            in_dp = active & ~neg
-            capacity = jnp.sum(jnp.where(in_dp, item_nodes, 0))
-            feasible = capacity >= residual
+            with scope("saturate"):
+                neg = (coef < 0) & active
+                sat = jnp.where(neg, bound, 0)
+                covered = jnp.sum(jnp.where(neg, item_nodes, 0))
+                residual = jnp.maximum(req.astype(i64) - covered, 0)
+                in_dp = active & ~neg
+                capacity = jnp.sum(jnp.where(in_dp, item_nodes, 0))
+                feasible = capacity >= residual
 
-            # gcd-mode coarsening decision, mirroring the host engine's
-            # _plan_scale: the gcd divides every structural pod count, so
-            # scaled DP/backtrack columns are bitwise the unscaled ones
-            # (DESIGN.md §14); eff_g = 1 below the threshold
-            rs_g = (residual + c_gcd - 1) // c_gcd
-            use_g = (residual > c_thr) & (c_gcd > 1) & (rs_g <= c_maxr)
-            eff_g = jnp.where(use_g, c_gcd, 1).astype(i64)
-            eff_res = ((residual + eff_g - 1) // eff_g).astype(i32)
+                # gcd-mode coarsening decision, mirroring the host engine's
+                # _plan_scale: the gcd divides every structural pod count,
+                # so scaled DP/backtrack columns are bitwise the unscaled
+                # ones (DESIGN.md §14); eff_g = 1 below the threshold
+                rs_g = (residual + c_gcd - 1) // c_gcd
+                use_g = (residual > c_thr) & (c_gcd > 1) & (rs_g <= c_maxr)
+                eff_g = jnp.where(use_g, c_gcd, 1).astype(i64)
+                eff_res = ((residual + eff_g - 1) // eff_g).astype(i32)
 
             def dp_part(_):
                 # masked-not-compacted: non-DP bundles sort last (key INF)
                 # with zero pods and cost, so the sorted prefix and its
                 # prefix sums are the host's compacted arrays while shapes
                 # stay static
-                bmask = in_dp[b_item] & b_real
-                bpods = jnp.where(bmask, b_pods, 0)
-                bcost = jnp.where(bmask, coef[b_item] * b_copies, 0)
-                rate = jnp.where(bmask, bcost // b_pods, INF)
-                order = jnp.argsort(rate, stable=True)
-                p_sorted = bpods[order]
-                c_sorted = bcost[order]
-                r_sorted = rate[order]
-                cum_p = cumsum(p_sorted.astype(i64))
-                cum_c = cumsum(c_sorted)
-                cum_r = cumsum(r_sorted * p_sorted)
+                with scope("sort"):
+                    bmask = in_dp[b_item] & b_real
+                    bpods = jnp.where(bmask, b_pods, 0)
+                    bcost = jnp.where(bmask, coef[b_item] * b_copies, 0)
+                    rate = jnp.where(bmask, bcost // b_pods, INF)
+                    order = jnp.argsort(rate, stable=True)
+                    p_sorted = bpods[order]
+                    c_sorted = bcost[order]
+                    r_sorted = rate[order]
+                    cum_p = cumsum(p_sorted.astype(i64))
+                    cum_c = cumsum(c_sorted)
+                    cum_r = cumsum(r_sorted * p_sorted)
 
                 def lp_lower(need):
                     k = jnp.searchsorted(cum_p, need)
@@ -547,42 +553,48 @@ class FusedJaxBackend(NumpyBackend):
                     prev_r = jnp.where(k > 0, cum_r[km], 0)
                     return prev_r + (need - prev_p) * r_sorted[k]
 
-                k_ub = jnp.searchsorted(cum_p, residual)
-                ub = cum_c[k_ub]
-                lp = lp_lower(jnp.maximum(residual - b_pods, 0))
-                keep0 = bmask & (bcost + lp <= ub)
-                # DP stages run at granularity eff_g (1 = exact); the prune
-                # math above stays unscaled so the keep set is the exact
-                # engine's
-                b_pods_s = (b_pods // eff_g).astype(i32)
-                # the core DP runs only when the greedy bound leaves more
-                # than _CORE_TRIGGER bundles alive: otherwise its loop takes
-                # zero trips and leaves dp[eff_res] = INF
-                K = jnp.minimum(jnp.sum(bmask),
-                                jnp.maximum(k_ub + _CORE_PAD, _CORE_MIN))
-                core_trip = jnp.where(jnp.sum(keep0) > _CORE_TRIGGER, K,
-                                      0).astype(i32)
+                with scope("lp_prune"):
+                    k_ub = jnp.searchsorted(cum_p, residual)
+                    ub = cum_c[k_ub]
+                    lp = lp_lower(jnp.maximum(residual - b_pods, 0))
+                    keep0 = bmask & (bcost + lp <= ub)
+                    # DP stages run at granularity eff_g (1 = exact); the
+                    # prune math above stays unscaled so the keep set is the
+                    # exact engine's
+                    b_pods_s = (b_pods // eff_g).astype(i32)
+                    # the core DP runs only when the greedy bound leaves
+                    # more than _CORE_TRIGGER bundles alive: otherwise its
+                    # loop takes zero trips and leaves dp[eff_res] = INF
+                    K = jnp.minimum(jnp.sum(bmask),
+                                    jnp.maximum(k_ub + _CORE_PAD, _CORE_MIN))
+                    core_trip = jnp.where(jnp.sum(keep0) > _CORE_TRIGGER, K,
+                                          0).astype(i32)
 
                 def tier_case(tools):
                     cover_value, cover_bits = tools
 
                     def run(_o):
-                        core_ub = cover_value(b_pods_s[order], c_sorted,
-                                              core_trip, eff_res)
-                        keep = jnp.where(core_ub < ub,
-                                         bmask & (bcost + lp <= core_ub),
-                                         keep0)
-                        # kept-first stable permutation preserves market
-                        # bundle order within the kept prefix — the decode
-                        # order the backtracker's tie-breaking depends on
-                        ki = jnp.cumsum(keep.astype(i32))
-                        ni = jnp.cumsum((~keep).astype(i32))
-                        kept_n = ki[B - 1]
-                        pos = jnp.where(keep, ki - 1, kept_n + ni - 1)
-                        perm = jnp.zeros(B, i32).at[pos].set(
-                            jnp.arange(B, dtype=i32))
-                        kp = b_pods_s[perm]
-                        bits = cover_bits(kp, bcost[perm], kept_n)
+                        with scope("core_dp"):
+                            core_ub = cover_value(b_pods_s[order], c_sorted,
+                                                  core_trip, eff_res)
+                            keep = jnp.where(core_ub < ub,
+                                             bmask & (bcost + lp <= core_ub),
+                                             keep0)
+                        with scope("compact"):
+                            # kept-first stable permutation preserves market
+                            # bundle order within the kept prefix — the
+                            # decode order the backtracker's tie-breaking
+                            # depends on
+                            ki = jnp.cumsum(keep.astype(i32))
+                            ni = jnp.cumsum((~keep).astype(i32))
+                            kept_n = ki[B - 1]
+                            pos = jnp.where(keep, ki - 1, kept_n + ni - 1)
+                            perm = jnp.zeros(B, i32).at[pos].set(
+                                jnp.arange(B, dtype=i32))
+                            kp = b_pods_s[perm]
+                            kc = bcost[perm]
+                        with scope("cover_dp"):
+                            bits = cover_bits(kp, kc, kept_n)
 
                         def bt_body(st):
                             i, j, take = st
@@ -591,11 +603,14 @@ class FusedJaxBackend(NumpyBackend):
                             j = jnp.where(bit, jnp.maximum(j - kp[i], 0), j)
                             return i - 1, j, take
 
-                        _i, _j, take = lax.while_loop(
-                            lambda st: (st[0] >= 0) & (st[1] > 0), bt_body,
-                            (kept_n - 1, eff_res, jnp.zeros(B, dtype=bool)))
-                        return sat.at[b_item[perm]].add(
-                            jnp.where(take, b_copies[perm], 0))
+                        with scope("backtrack"):
+                            _i, _j, take = lax.while_loop(
+                                lambda st: (st[0] >= 0) & (st[1] > 0),
+                                bt_body,
+                                (kept_n - 1, eff_res,
+                                 jnp.zeros(B, dtype=bool)))
+                            return sat.at[b_item[perm]].add(
+                                jnp.where(take, b_copies[perm], 0))
                     return run
 
                 # route the row to the narrowest tier wider than its
@@ -624,21 +639,26 @@ class FusedJaxBackend(NumpyBackend):
                 cnts, feas = out
                 c, f = solve_row(coefs[i], actives[i], reqs[i])
                 return cnts.at[i].set(c), feas.at[i].set(f)
-            return lax.fori_loop(
-                0, D, body, (jnp.zeros((D, N), i32), jnp.zeros(D, bool)))
+            # "rows": the row loop and each row's branch routing; the
+            # stages nested in it carry their own scopes
+            with scope("rows"):
+                return lax.fori_loop(
+                    0, D, body,
+                    (jnp.zeros((D, N), i32), jnp.zeros(D, bool)))
 
         # -- pool scoring ----------------------------------------------------
         def score(cnts, feas, reqf):
             # speculation-only e_total (float32): steers device bracket
             # control, never replayed to the host (which rescores exactly);
             # elementwise sums, so no matmul precision mode is involved
-            c = cnts.astype(jnp.float32)
-            sp = jnp.sum(c * perf, axis=1)
-            sc = jnp.sum(c * price, axis=1)
-            sq = jnp.sum(c * podsf, axis=1)
-            ok = (sq >= reqf) & (sc > 0.0) & (sq > 0.0)
-            s = jnp.where(ok, (sp / sc) * (reqf / sq), 0.0)
-            return jnp.where(feas, s, -jnp.inf)
+            with scope("score"):
+                c = cnts.astype(jnp.float32)
+                sp = jnp.sum(c * perf, axis=1)
+                sc = jnp.sum(c * price, axis=1)
+                sq = jnp.sum(c * podsf, axis=1)
+                ok = (sq >= reqf) & (sc > 0.0) & (sq > 0.0)
+                s = jnp.where(ok, (sp / sc) * (reqf / sq), 0.0)
+                return jnp.where(feas, s, -jnp.inf)
 
         return solve_rows, score
 
@@ -649,7 +669,9 @@ class FusedJaxBackend(NumpyBackend):
         if fn is None:
             jnp = self._jnp
 
-            def run(md, w, q, active, reqs, ks, coarse):
+            # the function's name is the program's on the trace's
+            # "XLA Modules" line (jit_kubepacs_prescan)
+            def kubepacs_prescan(md, w, q, active, reqs, ks, coarse):
                 solve_rows, _score = self._solver_core(md, N, B, RC, coarse)
                 di = jnp.arange(D * G) // G
                 k = ks[jnp.arange(D * G) % G][:, None]
@@ -657,7 +679,7 @@ class FusedJaxBackend(NumpyBackend):
                 counts, feas = solve_rows(coefs, active[di], reqs[di])
                 return counts.reshape(D, G, N), feas.reshape(D, G)
 
-            fn = self._jax.jit(run)
+            fn = self._jax.jit(kubepacs_prescan)
             self._fused_cache[key] = fn
             self.program_builds += 1
         return fn
@@ -671,65 +693,69 @@ class FusedJaxBackend(NumpyBackend):
             i32, i64 = jnp.int32, jnp.int64
             ME = MAXR + 2
 
-            def run(md, w, q, active, reqs, a0, b0, tolk, coarse):
-                solve_rows, score = self._solver_core(md, N, B, RC, coarse)
-                reqf = reqs.astype(jnp.float32)
-                dn = jnp.arange(D)
-                g0 = exact.golden_width(b0 - a0)
-                x1, x2 = b0 - g0, a0 + g0     # the host's bracket init
-                neg_inf = jnp.full(D, -jnp.inf, jnp.float32)
+            # "control": the bracket updates and the ev_* writes of the
+            # rounds; the row solver and the scoring nest their own scopes
+            def kubepacs_golden(md, w, q, active, reqs, a0, b0, tolk,
+                                coarse):
+                with jax.named_scope("control"):
+                    solve_rows, score = self._solver_core(md, N, B, RC, coarse)
+                    reqf = reqs.astype(jnp.float32)
+                    dn = jnp.arange(D)
+                    g0 = exact.golden_width(b0 - a0)
+                    x1, x2 = b0 - g0, a0 + g0     # the host's bracket init
+                    neg_inf = jnp.full(D, -jnp.inf, jnp.float32)
 
-                # rounds 0 and 1 solve every decision's x1 and x2; each
-                # later round advances the active brackets exactly like
-                # the host loop and solves their one new probe — a single
-                # row-solver instance in the program
-                def cond(st):
-                    r, a, b = st[0], st[1], st[2]
-                    return (r < 2) | ((r < MAXR + 2)
-                                      & jnp.any((b - a) > tolk))
+                    # rounds 0 and 1 solve every decision's x1 and x2; each
+                    # later round advances the active brackets exactly like
+                    # the host loop and solves their one new probe — a single
+                    # row-solver instance in the program
+                    def cond(st):
+                        r, a, b = st[0], st[1], st[2]
+                        return (r < 2) | ((r < MAXR + 2)
+                                          & jnp.any((b - a) > tolk))
 
-                def body(st):
-                    (r, a, b, x1, x2, f1, f2, ev_k, ev_c, ev_f, evn) = st
-                    init = r < 2
-                    act = init | ((b - a) > tolk)
-                    right = ~init & act & (f1 >= f2)  # shrink from right
-                    left = ~init & act & ~(f1 >= f2)  # shrink from left
-                    nb = jnp.where(right, x2, b)
-                    na = jnp.where(left, x1, a)
-                    g = exact.golden_width(nb - na)
-                    nx1 = jnp.where(right, nb - g, jnp.where(left, x2, x1))
-                    nx2 = jnp.where(left, na + g, jnp.where(right, x1, x2))
-                    pf1 = jnp.where(left, f2, f1)
-                    pf2 = jnp.where(right, f1, f2)
-                    probe = jnp.where(
-                        init, jnp.where(r == 0, x1, x2),
-                        jnp.where(right, nx1, jnp.where(left, nx2, 0)))
-                    # inactive decisions re-solve req=0 (the cheap
-                    # saturation fast path) instead of a full row
-                    reqv = jnp.where(act, reqs, 0)
-                    cp, fep = solve_rows(
-                        exact.coefficients(probe[:, None], w, q), active,
-                        reqv)
-                    fp = score(cp, fep, reqf)
-                    nf1 = jnp.where(right | (init & (r == 0)), fp, pf1)
-                    nf2 = jnp.where(left | (init & (r == 1)), fp, pf2)
-                    ev_k = ev_k.at[dn, evn].set(
-                        jnp.where(act, probe, ev_k[dn, evn]))
-                    ev_c = ev_c.at[dn, evn, :].set(
-                        jnp.where(act[:, None], cp, ev_c[dn, evn, :]))
-                    ev_f = ev_f.at[dn, evn].set(
-                        jnp.where(act, fep, ev_f[dn, evn]))
-                    evn = evn + act.astype(i32)
-                    return (r + 1, na, nb, nx1, nx2, nf1, nf2,
-                            ev_k, ev_c, ev_f, evn)
+                    def body(st):
+                        (r, a, b, x1, x2, f1, f2, ev_k, ev_c, ev_f, evn) = st
+                        init = r < 2
+                        act = init | ((b - a) > tolk)
+                        right = ~init & act & (f1 >= f2)  # shrink from right
+                        left = ~init & act & ~(f1 >= f2)  # shrink from left
+                        nb = jnp.where(right, x2, b)
+                        na = jnp.where(left, x1, a)
+                        g = exact.golden_width(nb - na)
+                        nx1 = jnp.where(right, nb - g, jnp.where(left, x2, x1))
+                        nx2 = jnp.where(left, na + g, jnp.where(right, x1, x2))
+                        pf1 = jnp.where(left, f2, f1)
+                        pf2 = jnp.where(right, f1, f2)
+                        probe = jnp.where(
+                            init, jnp.where(r == 0, x1, x2),
+                            jnp.where(right, nx1, jnp.where(left, nx2, 0)))
+                        # inactive decisions re-solve req=0 (the cheap
+                        # saturation fast path) instead of a full row
+                        reqv = jnp.where(act, reqs, 0)
+                        cp, fep = solve_rows(
+                            exact.coefficients(probe[:, None], w, q), active,
+                            reqv)
+                        fp = score(cp, fep, reqf)
+                        nf1 = jnp.where(right | (init & (r == 0)), fp, pf1)
+                        nf2 = jnp.where(left | (init & (r == 1)), fp, pf2)
+                        ev_k = ev_k.at[dn, evn].set(
+                            jnp.where(act, probe, ev_k[dn, evn]))
+                        ev_c = ev_c.at[dn, evn, :].set(
+                            jnp.where(act[:, None], cp, ev_c[dn, evn, :]))
+                        ev_f = ev_f.at[dn, evn].set(
+                            jnp.where(act, fep, ev_f[dn, evn]))
+                        evn = evn + act.astype(i32)
+                        return (r + 1, na, nb, nx1, nx2, nf1, nf2,
+                                ev_k, ev_c, ev_f, evn)
 
-                st = lax.while_loop(cond, body, (
-                    i32(0), a0, b0, x1, x2, neg_inf, neg_inf,
-                    jnp.zeros((D, ME), i64), jnp.zeros((D, ME, N), i32),
-                    jnp.zeros((D, ME), bool), jnp.zeros(D, i32)))
-                return st[7], st[8], st[9], st[10]
+                    st = lax.while_loop(cond, body, (
+                        i32(0), a0, b0, x1, x2, neg_inf, neg_inf,
+                        jnp.zeros((D, ME), i64), jnp.zeros((D, ME, N), i32),
+                        jnp.zeros((D, ME), bool), jnp.zeros(D, i32)))
+                    return st[7], st[8], st[9], st[10]
 
-            fn = jax.jit(run)
+            fn = jax.jit(kubepacs_golden)
             self._fused_cache[key] = fn
             self.program_builds += 1
         return fn
@@ -782,39 +808,49 @@ class FusedJaxBackend(NumpyBackend):
 
     def _run_prescan(self, market, reqs, excludes, kgrid, coarsening=None):
         Dr, G = len(reqs), len(kgrid)
-        N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
-        md = self._device_market(market, N, B)
-        w, q, active, rq = self._decision_arrays(market, reqs, excludes, N,
-                                                 D)
-        fn = self._prescan_program(N, B, RC, D, G)
-        counts, feas = fn(md, w, q, active, rq, np.asarray(kgrid, np.int64),
-                          self._coarse_scalars(market, coarsening))
-        return (np.asarray(counts)[:Dr, :, :market.n],
-                np.asarray(feas)[:Dr])
+        with events_log.span("kubepacs.device.inputs"):
+            N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
+            md = self._device_market(market, N, B)
+            w, q, active, rq = self._decision_arrays(market, reqs, excludes,
+                                                     N, D)
+            fn = self._prescan_program(N, B, RC, D, G)
+            args = (md, w, q, active, rq, np.asarray(kgrid, np.int64),
+                    self._coarse_scalars(market, coarsening))
+        with events_log.span("kubepacs.device.prescan"):
+            out = self._jax.block_until_ready(fn(*args))
+        with events_log.span("kubepacs.device.readback"):
+            counts, feas = out
+            return (np.asarray(counts)[:Dr, :, :market.n],
+                    np.asarray(feas)[:Dr])
 
     def _run_golden(self, market, reqs, excludes, a_list, b_list,
                     tolerance, coarsening=None):
         Dr = len(reqs)
-        N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
-        md = self._device_market(market, N, B)
-        w, q, active, rq = self._decision_arrays(market, reqs, excludes, N,
-                                                 D)
-        # round budget: any bracket is <= 1 wide and shrinks by at most
-        # PHI per round, so ceil(log(tol)/log(PHI)) rounds suffice (+2)
-        phi = exact.PHI_Q / (1 << exact.PHI_BITS)
-        MAXR = (int(math.ceil(math.log(tolerance) / math.log(phi))) + 2
-                if 0.0 < tolerance < 1.0 else 3)
-        a0 = np.zeros(D, np.int64)
-        a0[:Dr] = a_list
-        b0 = np.zeros(D, np.int64)
-        b0[:Dr] = b_list
-        fn = self._golden_program(N, B, RC, D, MAXR)
-        ev_k, ev_c, ev_f, evn = fn(
-            md, w, q, active, rq, a0, b0,
-            np.int64(exact.tolerance_k(tolerance)),
-            self._coarse_scalars(market, coarsening))
-        return (np.asarray(ev_k)[:Dr], np.asarray(ev_c)[:Dr, :, :market.n],
-                np.asarray(ev_f)[:Dr], np.asarray(evn)[:Dr])
+        with events_log.span("kubepacs.device.inputs"):
+            N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
+            md = self._device_market(market, N, B)
+            w, q, active, rq = self._decision_arrays(market, reqs, excludes,
+                                                     N, D)
+            # round budget: any bracket is <= 1 wide and shrinks by at most
+            # PHI per round, so ceil(log(tol)/log(PHI)) rounds suffice (+2)
+            phi = exact.PHI_Q / (1 << exact.PHI_BITS)
+            MAXR = (int(math.ceil(math.log(tolerance) / math.log(phi))) + 2
+                    if 0.0 < tolerance < 1.0 else 3)
+            a0 = np.zeros(D, np.int64)
+            a0[:Dr] = a_list
+            b0 = np.zeros(D, np.int64)
+            b0[:Dr] = b_list
+            fn = self._golden_program(N, B, RC, D, MAXR)
+            args = (md, w, q, active, rq, a0, b0,
+                    np.int64(exact.tolerance_k(tolerance)),
+                    self._coarse_scalars(market, coarsening))
+        with events_log.span("kubepacs.device.golden"):
+            out = self._jax.block_until_ready(fn(*args))
+        with events_log.span("kubepacs.device.readback"):
+            ev_k, ev_c, ev_f, evn = out
+            return (np.asarray(ev_k)[:Dr],
+                    np.asarray(ev_c)[:Dr, :, :market.n],
+                    np.asarray(ev_f)[:Dr], np.asarray(evn)[:Dr])
 
     # -- record entry point --------------------------------------------------
     def fused_gss_record(self, items, market, reqs, excludes, kgrid,
@@ -871,13 +907,15 @@ class _FusedGssRecord:
         counts, feas = backend._run_prescan(market, self._reqs,
                                             self._excludes, kgrid,
                                             coarsening=coarsening)
-        self.prescan = [
-            [list(map(int, counts[d, g])) if feas[d, g] else None
-             for g in range(len(kgrid))]
-            for d in range(len(self._reqs))]
-        self._lookup: List[dict] = [dict(zip(kgrid, row))
-                                    for row in self.prescan]
-        self._verify_sample(kgrid)
+        with events_log.span("kubepacs.device.readback"):
+            self.prescan = [
+                [list(map(int, counts[d, g])) if feas[d, g] else None
+                 for g in range(len(kgrid))]
+                for d in range(len(self._reqs))]
+            self._lookup: List[dict] = [dict(zip(kgrid, row))
+                                        for row in self.prescan]
+        with events_log.span("kubepacs.fused.verify"):
+            self._verify_sample(kgrid)
 
     def _host_solve(self, reqs, k_lists, excludes):
         from .ilp import solve_ilp_many   # deferred: no import cycle
@@ -911,11 +949,13 @@ class _FusedGssRecord:
         ev_k, ev_c, ev_f, evn = self._backend._run_golden(
             self._market, self._reqs, self._excludes, a_list, b_list,
             self._tolerance, coarsening=self._coarsening)
-        for d in range(len(self._reqs)):
-            lut = self._lookup[d]
-            for s in range(int(evn[d])):
-                cnt = (list(map(int, ev_c[d, s])) if ev_f[d, s] else None)
-                lut.setdefault(int(ev_k[d, s]), cnt)
+        with events_log.span("kubepacs.device.readback"):
+            for d in range(len(self._reqs)):
+                lut = self._lookup[d]
+                for s in range(int(evn[d])):
+                    cnt = (list(map(int, ev_c[d, s])) if ev_f[d, s]
+                           else None)
+                    lut.setdefault(int(ev_k[d, s]), cnt)
 
     def solve_many(self, idxs, k_lists):
         """``solve_ilp_many``-shaped resolution of a golden round's probes:
@@ -935,10 +975,11 @@ class _FusedGssRecord:
         if miss_pos:
             self._backend.fallback_solves += sum(len(js) for _i, js in
                                                  miss_pos)
-            solved = self._host_solve(
-                [self._reqs[idxs[i]] for i, _js in miss_pos],
-                [[k_lists[i][j] for j in js] for i, js in miss_pos],
-                [self._excludes[idxs[i]] for i, _js in miss_pos])
+            with events_log.span("kubepacs.fused.fallback"):
+                solved = self._host_solve(
+                    [self._reqs[idxs[i]] for i, _js in miss_pos],
+                    [[k_lists[i][j] for j in js] for i, js in miss_pos],
+                    [self._excludes[idxs[i]] for i, _js in miss_pos])
             for (i, js), counts_d in zip(miss_pos, solved):
                 for j, c in zip(js, counts_d):
                     out[i][j] = c

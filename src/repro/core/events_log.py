@@ -1,4 +1,5 @@
-"""Process-wide degradation-event registry (DESIGN.md §16).
+"""Process-wide registry of degradation events (DESIGN.md §16) and of the
+hot path's spans (DESIGN.md §13).
 
 The solver stack degrades in several deliberate ways — the NumPy fallback
 when a jax backend is requested without jax, the process-wide x64 flip,
@@ -23,13 +24,31 @@ warning flags they replace).  They are deliberately **not** part of any
 decision, trace record, or metric dict — the determinism contract
 (DESIGN.md §9) is untouched; ``cache_stats`` is already exempt from
 trace/equality comparisons.  ``reset`` exists for test isolation only.
+
+Spans
+-----
+``span(name)`` times one stage of the hot path.  It always enters a
+``jax.profiler.TraceAnnotation`` of the same name, so under a profiler
+the stage lands on the trace's host plane on the device's clock, and it
+always adds to in-memory aggregates per name: calls, total and *self*
+nanoseconds (self = duration less the time of the spans nested in it,
+kept with a per-thread stack of open spans).  ``span_totals`` and
+``span_delta_since`` read them as ``snapshot``/``delta_since`` read the
+counters.  A root span (``root=True``) hands the profiler a per-process
+request number (``id``), which the spans nested in it share by time.
+Spans sit at batch or phase granularity, never per probe or per row;
+a span costs about a microsecond when nobody profiles.  This module
+imports no jax: the annotation class is looked up on the first span,
+and without jax a span keeps its aggregates only.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
+import time
 import warnings
-from typing import Dict
+from typing import Dict, List, Tuple
 
 _lock = threading.Lock()
 _counters: Dict[str, int] = {}
@@ -85,12 +104,104 @@ def delta_since(snap: Dict[str, int]) -> Dict[str, int]:
     return out
 
 
+# -- spans --------------------------------------------------------------------
+
+#: per name: [calls, total ns, self ns]
+_spans: Dict[str, List[int]] = {}
+_open = threading.local()            # .stack: this thread's open spans
+_request_ids = itertools.count(1)
+_annotation = None                   # TraceAnnotation class, or a null one
+
+
+class _NullAnnotation:
+    def __init__(self, name, **kwargs):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _annotation_class():
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = _NullAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class span:
+    """Context manager timing one stage under ``name`` (module docstring).
+    An exception inside the span still closes it and is not swallowed."""
+
+    __slots__ = ("name", "child_ns", "_t0", "_ann")
+
+    def __init__(self, name: str, root: bool = False):
+        self.name = name
+        annotation = _annotation or _annotation_class()
+        self._ann = (annotation(name, id=next(_request_ids)) if root
+                     else annotation(name))
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        stack.append(self)
+        self.child_ns = 0
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        took = time.perf_counter_ns() - self._t0
+        stack = _open.stack
+        if stack[-1] is self:
+            stack.pop()
+        else:                        # closed out of order: drop it alone
+            stack.remove(self)
+        if stack:
+            stack[-1].child_ns += took
+        with _lock:
+            agg = _spans.get(self.name)
+            if agg is None:
+                agg = _spans[self.name] = [0, 0, 0]
+            agg[0] += 1
+            agg[1] += took
+            agg[2] += took - self.child_ns
+        self._ann.__exit__(*exc)
+        return False
+
+
+def span_totals() -> Dict[str, Tuple[int, int, int]]:
+    """Per span name: ``(calls, total_ns, self_ns)`` since process start."""
+    with _lock:
+        return {name: tuple(agg) for name, agg in _spans.items()}
+
+
+def span_delta_since(totals: Dict[str, Tuple[int, int, int]]
+                     ) -> Dict[str, Tuple[int, int, int]]:
+    """What each span name added since ``totals`` (names that ran)."""
+    out = {}
+    for name, now in span_totals().items():
+        then = totals.get(name, (0, 0, 0))
+        if now[0] != then[0]:
+            out[name] = tuple(a - b for a, b in zip(now, then))
+    return out
+
+
 def reset() -> None:
-    """Clear all counters and warn-once keys (test isolation only)."""
+    """Clear all counters, warn-once keys and span aggregates (test
+    isolation only)."""
     with _lock:
         _counters.clear()
         _warned_keys.clear()
+        _spans.clear()
 
 
-__all__ = ["count", "counters", "delta_since", "reset", "snapshot",
-           "warn_once"]
+__all__ = ["count", "counters", "delta_since", "reset", "snapshot", "span",
+           "span_delta_since", "span_totals", "warn_once"]

@@ -33,7 +33,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import exact
+from . import events_log, exact
 from .backend import CoarseningConfig, SolverBackend
 from .efficiency import (CandidateItem, NodePool, e_total,
                          score_counts_batch, score_counts_many)
@@ -44,7 +44,11 @@ PHI = (math.sqrt(5.0) - 1.0) / 2.0     # ≈ 0.618
 
 @dataclasses.dataclass
 class GssTrace:
-    """Every (α, E_Total) the search evaluated — Fig. 6's black lines."""
+    """Every (α, E_Total) the search evaluated — Fig. 6's black lines.
+
+    ``wall_seconds`` is the search's host wall time.  Under a batched
+    search (:func:`bracketed_gss_many`, a ``SolveBatch``) every decision
+    of the batch is stamped with the whole batch's wall."""
 
     alphas: List[float] = dataclasses.field(default_factory=list)
     e_totals: List[float] = dataclasses.field(default_factory=list)
@@ -282,170 +286,176 @@ def bracketed_gss_many(
     decision's grid, scalar ``e_total`` per golden probe) so every float
     matches bit-for-bit.
     """
-    n_dec = len(req_pods_list)
-    if excludes is None:
-        excludes = [None] * n_dec
-    if len(excludes) != n_dec:
-        raise ValueError("excludes must match len(req_pods_list)")
-    kgrid = exact.alpha_grid(prescan)
-    grid = [exact.k_alpha(k) for k in kgrid]
-    tol = exact.tolerance_k(tolerance)
-    if market is None:
-        market = compile_market(items)
+    with events_log.span("kubepacs.gss"):
+        n_dec = len(req_pods_list)
+        if excludes is None:
+            excludes = [None] * n_dec
+        if len(excludes) != n_dec:
+            raise ValueError("excludes must match len(req_pods_list)")
+        kgrid = exact.alpha_grid(prescan)
+        grid = [exact.k_alpha(k) for k in kgrid]
+        tol = exact.tolerance_k(tolerance)
+        if market is None:
+            market = compile_market(items)
 
-    states = [_GssState(req, ex) for req, ex in zip(req_pods_list, excludes)]
-    for i, st in enumerate(states):
-        st.idx = i
-        st.t0 = timer()
+        states = [_GssState(req, ex)
+                  for req, ex in zip(req_pods_list, excludes)]
+        for i, st in enumerate(states):
+            st.idx = i
+            st.t0 = timer()
 
-    # -- fused device plane (DESIGN.md §13): backends that support it run
-    # the whole batch (prescan grid + speculative golden rounds) on device
-    # and hand back a replay record; the lockstep loop below then consumes
-    # recorded counts instead of dispatching per round.  Control flow,
-    # scoring, traces, and selections are the sequential path's either way.
-    record = None
-    if backend is not None and getattr(backend, "supports_fused_gss", False):
-        record = backend.fused_gss_record(items, market, list(req_pods_list),
-                                          list(excludes), kgrid, tolerance,
-                                          coarsening=coarsening)
+        # -- fused device plane (DESIGN.md §13): backends that support it run
+        # the whole batch (prescan grid + speculative golden rounds) on device
+        # and hand back a replay record; the lockstep loop below then consumes
+        # recorded counts instead of dispatching per round.  Control flow,
+        # scoring, traces, and selections are the sequential path's either way.
+        record = None
+        if backend is not None and getattr(backend, "supports_fused_gss",
+                                           False):
+            record = backend.fused_gss_record(items, market,
+                                              list(req_pods_list),
+                                              list(excludes), kgrid,
+                                              tolerance,
+                                              coarsening=coarsening)
 
-    # -- prescan: one stacked engine invocation over every (decision, α) --
-    if record is not None:
-        all_counts = record.prescan
-    else:
-        all_counts = solve_ilp_many(items, list(req_pods_list), grid,
-                                    market=market, excludes=list(excludes),
-                                    backend=backend, coarsening=coarsening)
-    all_scores = score_counts_many(items, all_counts, list(req_pods_list),
-                                   none_score=float("-inf"),
-                                   arrays=market.metric_arrays)
-    for st, counts_d, scores in zip(states, all_counts, all_scores):
-        st.scan_trace.ilp_solves += len(grid)
-        pools = [None if counts is None
-                 else NodePool(items=list(items), counts=counts)
-                 for counts in counts_d]
-        best_idx = 0
-        for gi, (alpha, score, pool) in enumerate(zip(grid, scores, pools)):
-            if pool is not None:
-                pool.alpha = alpha
-            st.scan_trace.alphas.append(alpha)
-            st.scan_trace.e_totals.append(max(score, 0.0))
-            if score > st.scan_f:
-                st.scan_pool, st.scan_f, best_idx = pool, score, gi
-        st.a = kgrid[max(0, best_idx - 1)]
-        st.b = kgrid[min(len(kgrid) - 1, best_idx + 1)]
-        w = exact.golden_width(st.b - st.a)
-        st.x1 = st.b - w
-        st.x2 = st.a + w
-
-    if record is not None:
-        # speculative device golden rounds over the chosen brackets; the
-        # probe α sequence is re-derived exactly below, so every cache
-        # miss resolves from the record (host solve only on divergence)
-        record.run_golden([st.a for st in states], [st.b for st in states])
-
-    # -- lockstep golden-section refinement --------------------------------
-    def eval_round(requests: List[Tuple[_GssState, List[int]]]) -> None:
-        """Evaluate each state's pending grid-index list with
-        sequential-evaluate semantics (cache first, one engine row per
-        miss, per-state append order), batching all misses into one
-        solve_ilp_many call."""
-        miss_states: List[_GssState] = []
-        miss_reqs: List[int] = []
-        miss_ks: List[List[int]] = []
-        miss_excludes: List[Optional[np.ndarray]] = []
-        for st, klist in requests:
-            pending: List[int] = []
-            for k in klist:
-                if k not in st.cache and k not in pending:
-                    pending.append(k)
-            if pending:
-                miss_states.append(st)
-                miss_reqs.append(st.req)
-                miss_ks.append(pending)
-                miss_excludes.append(st.exclude)
-        if not miss_states:
-            return
+        # -- prescan: one stacked engine invocation over every (decision, α)
         if record is not None:
-            solved = record.solve_many([st.idx for st in miss_states],
-                                       miss_ks)
+            all_counts = record.prescan
         else:
-            solved = solve_ilp_many(
-                items, miss_reqs,
-                [[exact.k_alpha(k) for k in ks] for ks in miss_ks],
-                market=market, excludes=miss_excludes, backend=backend,
-                coarsening=coarsening)
-        for st, ks_d, counts_d in zip(miss_states, miss_ks, solved):
-            for k, counts in zip(ks_d, counts_d):
-                alpha = exact.k_alpha(k)
-                st.trace.ilp_solves += 1
-                if counts is None:
-                    pool, score = None, float("-inf")
-                else:
-                    pool = NodePool(items=list(items), counts=counts,
-                                    alpha=alpha)
-                    score = e_total(pool, st.req)
-                st.trace.alphas.append(alpha)
-                st.trace.e_totals.append(
-                    score if score != float("-inf") else 0.0)
-                st.cache[k] = (pool, score)
+            all_counts = solve_ilp_many(items, list(req_pods_list), grid,
+                                        market=market, excludes=list(excludes),
+                                        backend=backend, coarsening=coarsening)
+        all_scores = score_counts_many(items, all_counts, list(req_pods_list),
+                                       none_score=float("-inf"),
+                                       arrays=market.metric_arrays)
+        for st, counts_d, scores in zip(states, all_counts, all_scores):
+            st.scan_trace.ilp_solves += len(grid)
+            pools = [None if counts is None
+                     else NodePool(items=list(items), counts=counts)
+                     for counts in counts_d]
+            best_idx = 0
+            for gi, (alpha, score, pool) in enumerate(zip(grid, scores,
+                                                          pools)):
+                if pool is not None:
+                    pool.alpha = alpha
+                st.scan_trace.alphas.append(alpha)
+                st.scan_trace.e_totals.append(max(score, 0.0))
+                if score > st.scan_f:
+                    st.scan_pool, st.scan_f, best_idx = pool, score, gi
+            st.a = kgrid[max(0, best_idx - 1)]
+            st.b = kgrid[min(len(kgrid) - 1, best_idx + 1)]
+            w = exact.golden_width(st.b - st.a)
+            st.x1 = st.b - w
+            st.x2 = st.a + w
 
-    eval_round([(st, [st.x1, st.x2]) for st in states])
-    for st in states:
-        st.pool1, st.f1 = st.cache[st.x1]
-        st.pool2, st.f2 = st.cache[st.x2]
-        if st.f1 >= st.f2:
-            st.best_pool, st.best_f = st.pool1, st.f1
-        else:
-            st.best_pool, st.best_f = st.pool2, st.f2
+        if record is not None:
+            # speculative device golden rounds over the chosen brackets; the
+            # probe α sequence is re-derived exactly below, so every cache
+            # miss resolves from the record (host solve only on divergence)
+            record.run_golden([st.a for st in states], [st.b for st in states])
 
-    while True:
-        active = [st for st in states
-                  if not st.done and (st.b - st.a) > tol]
+        # -- lockstep golden-section refinement ----------------------------
+        def eval_round(requests: List[Tuple[_GssState, List[int]]]) -> None:
+            """Evaluate each state's pending grid-index list with
+            sequential-evaluate semantics (cache first, one engine row per
+            miss, per-state append order), batching all misses into one
+            solve_ilp_many call."""
+            miss_states: List[_GssState] = []
+            miss_reqs: List[int] = []
+            miss_ks: List[List[int]] = []
+            miss_excludes: List[Optional[np.ndarray]] = []
+            for st, klist in requests:
+                pending: List[int] = []
+                for k in klist:
+                    if k not in st.cache and k not in pending:
+                        pending.append(k)
+                if pending:
+                    miss_states.append(st)
+                    miss_reqs.append(st.req)
+                    miss_ks.append(pending)
+                    miss_excludes.append(st.exclude)
+            if not miss_states:
+                return
+            if record is not None:
+                solved = record.solve_many([st.idx for st in miss_states],
+                                           miss_ks)
+            else:
+                solved = solve_ilp_many(
+                    items, miss_reqs,
+                    [[exact.k_alpha(k) for k in ks] for ks in miss_ks],
+                    market=market, excludes=miss_excludes, backend=backend,
+                    coarsening=coarsening)
+            for st, ks_d, counts_d in zip(miss_states, miss_ks, solved):
+                for k, counts in zip(ks_d, counts_d):
+                    alpha = exact.k_alpha(k)
+                    st.trace.ilp_solves += 1
+                    if counts is None:
+                        pool, score = None, float("-inf")
+                    else:
+                        pool = NodePool(items=list(items), counts=counts,
+                                        alpha=alpha)
+                        score = e_total(pool, st.req)
+                    st.trace.alphas.append(alpha)
+                    st.trace.e_totals.append(
+                        score if score != float("-inf") else 0.0)
+                    st.cache[k] = (pool, score)
+
+        eval_round([(st, [st.x1, st.x2]) for st in states])
         for st in states:
-            if not st.done and (st.b - st.a) <= tol:
-                st.done = True
-        if not active:
-            break
-        probes: List[Tuple[_GssState, List[int]]] = []
-        for st in active:
+            st.pool1, st.f1 = st.cache[st.x1]
+            st.pool2, st.f2 = st.cache[st.x2]
             if st.f1 >= st.f2:
-                st.b = st.x2
-                st.x2, st.f2, st.pool2 = st.x1, st.f1, st.pool1
-                st.x1 = st.b - exact.golden_width(st.b - st.a)
-                probes.append((st, [st.x1]))
+                st.best_pool, st.best_f = st.pool1, st.f1
             else:
-                st.a = st.x1
-                st.x1, st.f1, st.pool1 = st.x2, st.f2, st.pool2
-                st.x2 = st.a + exact.golden_width(st.b - st.a)
-                probes.append((st, [st.x2]))
-        eval_round(probes)
-        for st, klist in probes:
-            pool, f = st.cache[klist[0]]
-            if klist[0] == st.x1:
-                st.pool1, st.f1 = pool, f
-                if f > st.best_f:
-                    st.best_pool, st.best_f = pool, f
-            else:
-                st.pool2, st.f2 = pool, f
-                if f > st.best_f:
-                    st.best_pool, st.best_f = pool, f
+                st.best_pool, st.best_f = st.pool2, st.f2
 
-    # -- per-decision finish: exactly the sequential epilogue --------------
-    out: List[Tuple[Optional[NodePool], GssTrace]] = []
-    for st in states:
-        inner_pool = st.best_pool
-        if inner_pool is not None:
-            inner_pool = inner_pool.nonzero()
-        trace = st.trace
-        trace.alphas = st.scan_trace.alphas + trace.alphas
-        trace.e_totals = st.scan_trace.e_totals + trace.e_totals
-        trace.ilp_solves += st.scan_trace.ilp_solves
-        trace.wall_seconds = timer() - st.t0
-        inner_f = (e_total(inner_pool, st.req)
-                   if inner_pool is not None else float("-inf"))
-        if st.scan_pool is not None and st.scan_f > inner_f:
-            out.append((st.scan_pool.nonzero(), trace))
-        else:
-            out.append((inner_pool, trace))
-    return out
+        while True:
+            active = [st for st in states
+                      if not st.done and (st.b - st.a) > tol]
+            for st in states:
+                if not st.done and (st.b - st.a) <= tol:
+                    st.done = True
+            if not active:
+                break
+            probes: List[Tuple[_GssState, List[int]]] = []
+            for st in active:
+                if st.f1 >= st.f2:
+                    st.b = st.x2
+                    st.x2, st.f2, st.pool2 = st.x1, st.f1, st.pool1
+                    st.x1 = st.b - exact.golden_width(st.b - st.a)
+                    probes.append((st, [st.x1]))
+                else:
+                    st.a = st.x1
+                    st.x1, st.f1, st.pool1 = st.x2, st.f2, st.pool2
+                    st.x2 = st.a + exact.golden_width(st.b - st.a)
+                    probes.append((st, [st.x2]))
+            eval_round(probes)
+            for st, klist in probes:
+                pool, f = st.cache[klist[0]]
+                if klist[0] == st.x1:
+                    st.pool1, st.f1 = pool, f
+                    if f > st.best_f:
+                        st.best_pool, st.best_f = pool, f
+                else:
+                    st.pool2, st.f2 = pool, f
+                    if f > st.best_f:
+                        st.best_pool, st.best_f = pool, f
+
+        # -- per-decision finish: exactly the sequential epilogue ----------
+        out: List[Tuple[Optional[NodePool], GssTrace]] = []
+        for st in states:
+            inner_pool = st.best_pool
+            if inner_pool is not None:
+                inner_pool = inner_pool.nonzero()
+            trace = st.trace
+            trace.alphas = st.scan_trace.alphas + trace.alphas
+            trace.e_totals = st.scan_trace.e_totals + trace.e_totals
+            trace.ilp_solves += st.scan_trace.ilp_solves
+            trace.wall_seconds = timer() - st.t0
+            inner_f = (e_total(inner_pool, st.req)
+                       if inner_pool is not None else float("-inf"))
+            if st.scan_pool is not None and st.scan_f > inner_f:
+                out.append((st.scan_pool.nonzero(), trace))
+            else:
+                out.append((inner_pool, trace))
+        return out

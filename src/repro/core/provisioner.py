@@ -23,6 +23,7 @@ from typing import (Callable, Dict, Iterable, List, Optional, Sequence, Set,
 
 import numpy as np
 
+from . import events_log
 from .backend import CoarseningConfig, SolverBackend
 from .efficiency import (CandidateItem, NodePool, Request, decision_metrics,
                          pods_per_instance)
@@ -53,6 +54,12 @@ class UnavailableOfferingsCache:
 
 @dataclasses.dataclass
 class ProvisioningDecision:
+    """One provisioning decision.  ``wall_seconds`` is diagnostic host wall
+    time from the ``provision`` call to the decision being built; under a
+    :class:`SolveBatch` that is the whole batch's wall (every decision of
+    the batch waits for all of it), not this decision's share.  The
+    per-stage split is in ``repro.core.events_log.span_totals()``."""
+
     pool: NodePool
     trace: Optional[GssTrace]
     alpha: Optional[float]
@@ -234,14 +241,17 @@ class SolveBatch:
             gkey = (id(job.market), job.tolerance, id(job.timer),
                     job.coarsening)
             groups.setdefault(gkey, []).append(job)
-        for group in groups.values():
-            results = bracketed_gss_many(
-                group[0].items, [j.req_pods for j in group],
-                tolerance=group[0].tolerance, market=group[0].market,
-                excludes=[j.exclude for j in group], timer=group[0].timer,
-                backend=self.backend, coarsening=group[0].coarsening)
-            for job, (pool, trace) in zip(group, results):
-                job.decision = job.finish(pool, trace)
+        with events_log.span("kubepacs.solve_batch", root=True):
+            for group in groups.values():
+                results = bracketed_gss_many(
+                    group[0].items, [j.req_pods for j in group],
+                    tolerance=group[0].tolerance, market=group[0].market,
+                    excludes=[j.exclude for j in group],
+                    timer=group[0].timer, backend=self.backend,
+                    coarsening=group[0].coarsening)
+                with events_log.span("kubepacs.decision.finish"):
+                    for job, (pool, trace) in zip(group, results):
+                        job.decision = job.finish(pool, trace)
         return len(jobs)
 
 
@@ -354,37 +364,41 @@ class KubePACSProvisioner:
         fleet engine's collect phase) a memo-miss returns a
         :class:`PendingDecision` token instead of solving inline; the
         engine resolves tokens after ``SolveBatch.execute()``."""
-        t0 = self.timer()
-        excluded = self.cache.excluded(self.clock)
-        memo = self.decision_memo
-        mkey = memo.key(request, excluded) if memo is not None else None
-        batch = self.solve_batch if self.guarded_gss else None
-        if mkey is not None:
+        with events_log.span("kubepacs.provision"):
+            t0 = self.timer()
+            excluded = self.cache.excluded(self.clock)
+            memo = self.decision_memo
+            mkey = memo.key(request, excluded) if memo is not None else None
+            batch = self.solve_batch if self.guarded_gss else None
+            if mkey is not None:
+                if batch is not None:
+                    tok = batch.pending(mkey, self.timer() - t0)
+                    if tok is not None:  # same key already collected this
+                        memo.count_hit()  # phase: a memo hit, shared solve
+                        return tok
+                hit = memo.fetch(mkey, self.timer() - t0)
+                if hit is not None:
+                    return hit
+            items, market = self._compiled(request, catalog, precompiled)
+            exclude = exclusion_mask(items, excluded)
             if batch is not None:
-                tok = batch.pending(mkey, self.timer() - t0)
-                if tok is not None:      # same key already collected this
-                    memo.count_hit()     # phase: a memo hit, shared solve
-                    return tok
-            hit = memo.fetch(mkey, self.timer() - t0)
-            if hit is not None:
-                return hit
-        items, market = self._compiled(request, catalog, precompiled)
-        exclude = exclusion_mask(items, excluded)
-        if batch is not None:
-            def finish(pool, trace, _request=request, _excluded=excluded,
-                       _mkey=mkey, _t0=t0):
-                return self._finalize(_request, _excluded, pool, trace,
-                                      _t0, _mkey)
-            return batch.enqueue(mkey, items=items, market=market,
-                                 req_pods=request.pods, exclude=exclude,
-                                 tolerance=self.tolerance, timer=self.timer,
-                                 finish=finish, coarsening=self.coarsening)
-        search = bracketed_gss if self.guarded_gss else golden_section_search
-        pool, trace = search(items, request.pods, tolerance=self.tolerance,
-                             market=market, exclude=exclude, timer=self.timer,
-                             backend=self.backend,
-                             coarsening=self.coarsening)
-        return self._finalize(request, excluded, pool, trace, t0, mkey)
+                def finish(pool, trace, _request=request,
+                           _excluded=excluded, _mkey=mkey, _t0=t0):
+                    return self._finalize(_request, _excluded, pool, trace,
+                                          _t0, _mkey)
+                return batch.enqueue(mkey, items=items, market=market,
+                                     req_pods=request.pods, exclude=exclude,
+                                     tolerance=self.tolerance,
+                                     timer=self.timer, finish=finish,
+                                     coarsening=self.coarsening)
+            search = (bracketed_gss if self.guarded_gss
+                      else golden_section_search)
+            pool, trace = search(items, request.pods,
+                                 tolerance=self.tolerance, market=market,
+                                 exclude=exclude, timer=self.timer,
+                                 backend=self.backend,
+                                 coarsening=self.coarsening)
+            return self._finalize(request, excluded, pool, trace, t0, mkey)
 
     def _finalize(self, request: Request, excluded: Set[str],
                   pool: Optional[NodePool], trace: GssTrace, t0: float,

@@ -31,6 +31,7 @@ learn from the same stream live and under replay.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -306,16 +307,19 @@ def _split_pending(pending: Sequence[InterruptNotice],
 
 
 def shared_precompile(cache: Dict, stats: Dict[str, int], state_idx: int,
-                      snapshot: Sequence[Offering], request: Request):
+                      snapshot: Sequence[Offering], request: Request,
+                      span: Optional[str] = None):
     """The (market state, request shape)-keyed preprocess+compile cache
     shared by ClusterSim replicas and the fleet engine, with hit/miss
-    counters (``SimResult.cache_stats``)."""
+    counters (``SimResult.cache_stats``).  A miss runs under the
+    ``events_log`` span ``span``, where the caller names one."""
     key = (state_idx, request.cpu_per_pod, request.mem_per_pod,
            request.workload)
     if key not in cache:
         stats["compile_misses"] += 1
-        items = preprocess(snapshot, request)
-        cache[key] = (items, compile_market(items))
+        with events_log.span(span) if span else contextlib.nullcontext():
+            items = preprocess(snapshot, request)
+            cache[key] = (items, compile_market(items))
     else:
         stats["compile_hits"] += 1
     return cache[key]

@@ -242,41 +242,43 @@ class FleetSim:
         """Pop the next scripted state; update the shared snapshot; fan the
         refresh out to every replica's observers (policy first, exactly the
         standalone fan-out order)."""
-        spot, t3 = self.states[self._state_pos]
-        self._state_pos += 1
-        self._state_idx += 1
-        # TRUE state: hazards (_spot/_t3/_snap_index) and billing stay in
-        # reality; the policy decides on the chaos-observed snapshot
-        # (DESIGN.md §16) — mirroring ClusterSim._refresh exactly
-        self._spot, self._t3 = spot, t3
-        recs = ([market_state_record(self.time, spot, t3)]
-                if self.record_traces else None)
-        if self.chaos is not None:
-            spot_obs, t3_obs, transitions = self.chaos.observe(
-                self._state_idx, self.time, spot, t3)
-            if recs is not None:
-                recs.extend(fault_record(self.time, kind, phase, idx)
-                            for kind, phase, idx in transitions)
-            self._true_snapshot = snapshot_with(self.catalog, spot, t3)
-            self._snapshot = (self._true_snapshot
-                              if spot_obs is spot and t3_obs is t3
-                              else snapshot_with(self.catalog, spot_obs,
-                                                 t3_obs))
-        else:
-            spot_obs, t3_obs = spot, t3
-            self._snapshot = snapshot_with(self.catalog, spot, t3)
-            self._true_snapshot = self._snapshot
-        self._snap_index = {o.offering_id: o for o in self._true_snapshot}
-        for rep in self.replicas:
-            if recs is not None:
-                for rec in recs:
-                    rep.recorder.write(rec)
-            for obs in rep.observers:
-                obs.observe_market(self.time, spot_obs, t3_obs)
+        with events_log.span("kubepacs.fleet.refresh"):
+            spot, t3 = self.states[self._state_pos]
+            self._state_pos += 1
+            self._state_idx += 1
+            # TRUE state: hazards (_spot/_t3/_snap_index) and billing stay in
+            # reality; the policy decides on the chaos-observed snapshot
+            # (DESIGN.md §16) — mirroring ClusterSim._refresh exactly
+            self._spot, self._t3 = spot, t3
+            recs = ([market_state_record(self.time, spot, t3)]
+                    if self.record_traces else None)
+            if self.chaos is not None:
+                spot_obs, t3_obs, transitions = self.chaos.observe(
+                    self._state_idx, self.time, spot, t3)
+                if recs is not None:
+                    recs.extend(fault_record(self.time, kind, phase, idx)
+                                for kind, phase, idx in transitions)
+                self._true_snapshot = snapshot_with(self.catalog, spot, t3)
+                self._snapshot = (self._true_snapshot
+                                  if spot_obs is spot and t3_obs is t3
+                                  else snapshot_with(self.catalog, spot_obs,
+                                                     t3_obs))
+            else:
+                spot_obs, t3_obs = spot, t3
+                self._snapshot = snapshot_with(self.catalog, spot, t3)
+                self._true_snapshot = self._snapshot
+            self._snap_index = {o.offering_id: o for o in self._true_snapshot}
+            for rep in self.replicas:
+                if recs is not None:
+                    for rec in recs:
+                        rep.recorder.write(rec)
+                for obs in rep.observers:
+                    obs.observe_market(self.time, spot_obs, t3_obs)
 
     def _precompiled(self, request: Request):
         return shared_precompile(self.compile_cache, self.cache_stats,
-                                 self._state_idx, self._snapshot, request)
+                                 self._state_idx, self._snapshot, request,
+                                 span="kubepacs.fleet.precompile")
 
     def _set_pool(self, rep: _Replica, pool: NodePool) -> None:
         rep.pool = pool
@@ -370,23 +372,26 @@ class FleetSim:
     # -- events (each: collect decisions → execute batch → launch) ----------
     def _on_initial(self) -> None:
         self._refresh()
-        staged = []
-        for rep in self.replicas:
-            if self.scenario.demand_jitter:
-                rep.request = dataclasses.replace(
-                    rep.request, pods=self.scenario.effective_pods(
-                        rep.seed, 0.0, self.scenario.pods))
-            if solver_down(self.chaos, rep.policy, self.time):
-                staged.append((rep, failed_decision(rep.request)))
-                continue
-            pre = self._precompiled(rep.request)
-            decision = self._decide(
-                rep, lambda rep=rep, pre=pre: rep.policy.provision(
-                    rep.request, self._snapshot, self.time, precompiled=pre))
-            staged.append((rep, decision))
+        with events_log.span("kubepacs.fleet.collect"):
+            staged = []
+            for rep in self.replicas:
+                if self.scenario.demand_jitter:
+                    rep.request = dataclasses.replace(
+                        rep.request, pods=self.scenario.effective_pods(
+                            rep.seed, 0.0, self.scenario.pods))
+                if solver_down(self.chaos, rep.policy, self.time):
+                    staged.append((rep, failed_decision(rep.request)))
+                    continue
+                pre = self._precompiled(rep.request)
+                decision = self._decide(
+                    rep, lambda rep=rep, pre=pre: rep.policy.provision(
+                        rep.request, self._snapshot, self.time,
+                        precompiled=pre))
+                staged.append((rep, decision))
         self._execute_batch()
-        for rep, decision in staged:
-            self._launch(rep, self._resolved(decision), "initial")
+        with events_log.span("kubepacs.fleet.launch"):
+            for rep, decision in staged:
+                self._launch(rep, self._resolved(decision), "initial")
 
     def _on_shock(self, shock: Shock) -> None:
         if self.record_traces:
@@ -397,33 +402,38 @@ class FleetSim:
         self._refresh()
 
     def _on_demand(self, pods: int) -> None:
-        for rep in self.replicas:
-            self._accrue_cost(rep, self.time)
-        self.request = dataclasses.replace(self.request, pods=pods)
-        staged = []
-        for rep in self.replicas:
-            rpods = self.scenario.effective_pods(rep.seed, self.time, pods)
-            rep.request = dataclasses.replace(rep.request, pods=rpods)
-            if rep.recorder is not None:
-                rep.recorder.write(demand_record(self.time, rpods))
-            shortfall = rpods - rep.pool.total_pods
-            if shortfall <= 0 and rep.pool.total_nodes:
-                continue
-            repl_request = (dataclasses.replace(rep.request, pods=shortfall)
-                            if rep.pool.total_nodes else rep.request)
-            if solver_down(self.chaos, rep.policy, self.time):
-                staged.append((rep, failed_decision(repl_request)))
-                continue
-            pre = self._precompiled(repl_request)
-            decision = self._decide(
-                rep, lambda rep=rep, req=repl_request, pre=pre:
-                rep.policy.provision(req, self._snapshot, self.time,
-                                     precompiled=pre))
-            staged.append((rep, decision))
+        with events_log.span("kubepacs.fleet.collect"):
+            for rep in self.replicas:
+                self._accrue_cost(rep, self.time)
+            self.request = dataclasses.replace(self.request, pods=pods)
+            staged = []
+            for rep in self.replicas:
+                rpods = self.scenario.effective_pods(rep.seed, self.time,
+                                                     pods)
+                rep.request = dataclasses.replace(rep.request, pods=rpods)
+                if rep.recorder is not None:
+                    rep.recorder.write(demand_record(self.time, rpods))
+                shortfall = rpods - rep.pool.total_pods
+                if shortfall <= 0 and rep.pool.total_nodes:
+                    continue
+                repl_request = (dataclasses.replace(rep.request,
+                                                    pods=shortfall)
+                                if rep.pool.total_nodes else rep.request)
+                if solver_down(self.chaos, rep.policy, self.time):
+                    staged.append((rep, failed_decision(repl_request)))
+                    continue
+                pre = self._precompiled(repl_request)
+                decision = self._decide(
+                    rep, lambda rep=rep, req=repl_request, pre=pre:
+                    rep.policy.provision(req, self._snapshot, self.time,
+                                         precompiled=pre))
+                staged.append((rep, decision))
         self._execute_batch()
-        for rep, decision in staged:
-            self._launch(rep, self._resolved(decision), "demand",
-                         base_pool=rep.pool if rep.pool.total_nodes else None)
+        with events_log.span("kubepacs.fleet.launch"):
+            for rep, decision in staged:
+                self._launch(rep, self._resolved(decision), "demand",
+                             base_pool=(rep.pool if rep.pool.total_nodes
+                                        else None))
 
     def _on_tick(self, t: float, dt: float) -> None:
         self.ticks += 1
@@ -435,59 +445,66 @@ class FleetSim:
         self._record_all(tick_record(t, dt))
         self._refresh()
         pool_dicts = [rep.pool.as_dict() for rep in self.replicas]
-        sampled_fleet = self._sample_fleet(dt, t, pool_dicts)
-        staged = []
-        for rep, scale, sampled, pool_dict in zip(self.replicas, scales,
-                                                  sampled_fleet, pool_dicts):
-            matured = any(n.effective_time <= t + _EPS for n in rep.pending)
-            if (self.scenario.inject_if_idle and not sampled and not matured
-                    and any(c > 0 for c in pool_dict.values())):
-                oid, c = max(pool_dict.items(), key=lambda kv: kv[1])
-                sampled = [InterruptNotice(time=t, offering_id=oid, count=c,
-                                           reason="fault-injection")]
-            if rep.recorder is not None:
-                rep.recorder.write(interrupts_record(t, sampled))
-            for obs in rep.observers:
-                obs.observe_interrupts(t, dt, pool_dict, sampled)
-            effective, rep.pending = _split_pending(rep.pending, sampled, t)
+        with events_log.span("kubepacs.fleet.sample"):
+            sampled_fleet = self._sample_fleet(dt, t, pool_dicts)
+        with events_log.span("kubepacs.fleet.collect"):
+            staged = []
+            for rep, scale, sampled, pool_dict in zip(
+                    self.replicas, scales, sampled_fleet, pool_dicts):
+                matured = any(n.effective_time <= t + _EPS
+                              for n in rep.pending)
+                if (self.scenario.inject_if_idle and not sampled
+                        and not matured
+                        and any(c > 0 for c in pool_dict.values())):
+                    oid, c = max(pool_dict.items(), key=lambda kv: kv[1])
+                    sampled = [InterruptNotice(time=t, offering_id=oid,
+                                               count=c,
+                                               reason="fault-injection")]
+                if rep.recorder is not None:
+                    rep.recorder.write(interrupts_record(t, sampled))
+                for obs in rep.observers:
+                    obs.observe_interrupts(t, dt, pool_dict, sampled)
+                effective, rep.pending = _split_pending(rep.pending, sampled,
+                                                        t)
 
-            survivors, lost_nodes, lost_pods, lost_perf = _apply_losses(
-                rep.pool, effective)
-            rep.total_perf_hours -= 0.5 * dt * lost_perf * scale
-            rep.interrupted_nodes += lost_nodes
-            decision, shortfall = None, 0
-            if effective:
-                shortfall = max(0, rep.request.pods - survivors.total_pods)
-                if solver_down(self.chaos, rep.policy, t):
-                    decision = (failed_decision(dataclasses.replace(
-                        rep.request, pods=shortfall)) if shortfall > 0
-                        else None)
-                else:
-                    pre = self._precompiled(rep.request)
-                    decision = self._decide(
-                        rep, lambda rep=rep, eff=effective, surv=survivors,
-                        pre=pre: rep.policy.on_interrupts(
-                            eff, rep.request, self._snapshot,
-                            surv.total_pods, t, precompiled=pre))
-            staged.append((rep, sampled, effective, survivors, lost_nodes,
-                           lost_pods, lost_perf, shortfall, decision))
+                survivors, lost_nodes, lost_pods, lost_perf = _apply_losses(
+                    rep.pool, effective)
+                rep.total_perf_hours -= 0.5 * dt * lost_perf * scale
+                rep.interrupted_nodes += lost_nodes
+                decision, shortfall = None, 0
+                if effective:
+                    shortfall = max(0, rep.request.pods - survivors.total_pods)
+                    if solver_down(self.chaos, rep.policy, t):
+                        decision = (failed_decision(dataclasses.replace(
+                            rep.request, pods=shortfall)) if shortfall > 0
+                            else None)
+                    else:
+                        pre = self._precompiled(rep.request)
+                        decision = self._decide(
+                            rep, lambda rep=rep, eff=effective, surv=survivors,
+                            pre=pre: rep.policy.on_interrupts(
+                                eff, rep.request, self._snapshot,
+                                surv.total_pods, t, precompiled=pre))
+                staged.append((rep, sampled, effective, survivors, lost_nodes,
+                               lost_pods, lost_perf, shortfall, decision))
         self._execute_batch()
-        for (rep, sampled, effective, survivors, lost_nodes, lost_pods,
-             lost_perf, shortfall, decision) in staged:
-            decision = self._resolved(decision)
-            if effective:
-                self._set_pool(rep, survivors)
-                if decision is not None:
-                    self._launch(rep, decision, "interrupt",
-                                 base_pool=survivors)
-                else:
-                    self._notify_pool(rep, "losses")
-            rep.rounds.append(SimRound(
-                time=t, notices=list(sampled), effective=effective,
-                lost_nodes=lost_nodes, lost_pods=lost_pods,
-                shortfall=shortfall, decision=decision, pool=rep.pool,
-                snapshot=self._snapshot if self.keep_snapshots else None,
-                lost_perf=lost_perf))
+        with events_log.span("kubepacs.fleet.launch"):
+            for (rep, sampled, effective, survivors, lost_nodes, lost_pods,
+                 lost_perf, shortfall, decision) in staged:
+                decision = self._resolved(decision)
+                if effective:
+                    self._set_pool(rep, survivors)
+                    if decision is not None:
+                        self._launch(rep, decision, "interrupt",
+                                     base_pool=survivors)
+                    else:
+                        self._notify_pool(rep, "losses")
+                rep.rounds.append(SimRound(
+                    time=t, notices=list(sampled), effective=effective,
+                    lost_nodes=lost_nodes, lost_pods=lost_pods,
+                    shortfall=shortfall, decision=decision, pool=rep.pool,
+                    snapshot=self._snapshot if self.keep_snapshots else None,
+                    lost_perf=lost_perf))
 
     # -- batched interrupt sampling -----------------------------------------
     def _sample_fleet(self, dt: float, now: float,
@@ -644,12 +661,16 @@ def run_fleet(scenario: Scenario, interrupt_seeds: Sequence[int], *,
     big constant factor of a sweep), so ``result.records`` /
     ``decision_records()`` are empty — pass ``record_traces=True`` when a
     consumer (e.g. ``calibration_report``) reads the trace."""
-    return FleetSim(scenario, interrupt_seeds, catalog=catalog,
-                    record_traces=record_traces,
-                    keep_snapshots=keep_snapshots,
-                    observer_factory=observer_factory, clock=clock,
-                    memoize=memoize, batch_decisions=batch_decisions,
-                    backend=backend).run()
+    with events_log.span("kubepacs.fleet.run", root=True):
+        with events_log.span("kubepacs.fleet.setup"):
+            fleet = FleetSim(scenario, interrupt_seeds, catalog=catalog,
+                             record_traces=record_traces,
+                             keep_snapshots=keep_snapshots,
+                             observer_factory=observer_factory, clock=clock,
+                             memoize=memoize,
+                             batch_decisions=batch_decisions,
+                             backend=backend)
+        return fleet.run()
 
 
 def run_fleet_paths(scenario: Scenario, path_seeds: Sequence[int],

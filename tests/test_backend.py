@@ -518,3 +518,48 @@ def test_compile_cache_placement(from_env, tmp_path):
     # a fresh directory gains entries; the shared checkout cache may
     # already hold these programs from an earlier run
     assert (after - before) if from_env else after
+
+
+#: the named scopes of the device programs' stages (DESIGN.md §13), which
+#: the benchmark's trace reduction reads (bench/trace.py STAGES)
+_ROW_STAGES = ("saturate", "sort", "lp_prune", "core_dp", "compact",
+               "cover_dp", "backtrack", "rows")
+
+
+@requires_jax
+@pytest.mark.parametrize("program,stages", [
+    ("prescan", _ROW_STAGES), ("golden", _ROW_STAGES + ("score", "control"))])
+def test_device_programs_carry_names_and_stage_scopes(program, stages):
+    """Both jits carry a stable name of their own, and every stage of the
+    row solver (and the golden loop's scoring and control) its named
+    scope, so a profiler trace can name each operation's stage."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    be = make_backend("jax:fused")
+    N, B, RC, D = 16, 32, 129, 2
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    market = (s((N,), jnp.int32), s((N,), jnp.int32), s((B,), jnp.int32),
+              s((B,), jnp.int32), s((B,), jnp.int32), s((B,), bool),
+              s((N,), jnp.float32), s((N,), jnp.float32))
+    decisions = (s((D, N), jnp.int64), s((D, N), jnp.int64),
+                 s((D, N), bool), s((D,), jnp.int64))
+    if program == "prescan":
+        fn = be._prescan_program(N, B, RC, D, 9)
+        tail = (s((9,), jnp.int64),)
+    else:
+        fn = be._golden_program(N, B, RC, D, 12)
+        tail = (s((D,), jnp.int64), s((D,), jnp.int64), s((), jnp.int64))
+    lowered = fn.lower(market, *decisions, *tail, s((3,), jnp.int64))
+    assert f"@jit_kubepacs_{program}" in lowered.as_text()
+    scopes = set()
+    for loc in re.findall(r'loc\("([^"]*)"', lowered.as_text(
+            debug_info=True)):
+        scopes.update(loc.split("/"))
+    assert set(stages) <= scopes
+    if program == "prescan":
+        assert not {"score", "control"} & scopes
