@@ -112,6 +112,14 @@ _CORE_PAD = 33
 _CORE_MIN = 96
 _CORE_TRIGGER = 160
 
+#: width of the device LP prune's distinct-bundle-size table (one TPU lane
+#: row; DESIGN.md §13): a market with at most this many distinct bundle
+#: pod sizes computes the LP bound once per size, a wider one per bundle
+_SIZE_TABLE = 128
+#: the table's pad entry, and the size a pad bundle takes in it: above
+#: every real bundle's pod count
+_SIZE_PAD = np.iinfo(np.int32).max
+
 
 class SolverBackend:
     """Interface: batched cover-DP value passes with improvement bits."""
@@ -271,6 +279,15 @@ def _configure_compile_cache(jax) -> None:
 _MISS = object()      # lookup sentinel (stored values include None)
 
 
+def _program_key(kind: str, shape: Tuple[int, ...], table: bool) -> tuple:
+    """A fused program's cache key: its kind and static shapes, and a
+    ``"search"`` mark on a program that prunes by the per-bundle search
+    (a market with more distinct bundle sizes than ``_SIZE_TABLE``).  A
+    table program's key is ``(kind, *shape)`` alone, the form
+    ``bench/layers.py`` rebuilds a program's arguments from."""
+    return (kind, *shape) if table else (kind, *shape, "search")
+
+
 def _rc_tiers(RC: int) -> List[int]:
     """Geometric DP-width ladder ``129, 513, 2049, …, RC``.
 
@@ -371,6 +388,7 @@ class FusedJaxBackend(NumpyBackend):
         self.declined_batches = 0
         self.host_dp_groups = 0
         self.program_builds = 0
+        self.table_prune_programs = 0
         self.verify_solves = 0
 
     def cover_bits(self, groups):
@@ -383,8 +401,10 @@ class FusedJaxBackend(NumpyBackend):
 
     # -- device market cache -------------------------------------------------
     def _device_market(self, market, N: int, B: int):
-        """Upload-once market arrays, keyed on (content digest, pad shape).
-        Pad items have no pods and no bound; pad bundles are not real."""
+        """Upload-once market arrays, keyed on (content digest, pad shape),
+        and whether the market's distinct bundle sizes fit the LP prune's
+        size table (the programs' ``table`` branch).  Pad items have no
+        pods and no bound; pad bundles are not real."""
         key = (market.digest, N, B)
         ent = self._market_cache.get(key)
         if ent is not None:
@@ -400,7 +420,7 @@ class FusedJaxBackend(NumpyBackend):
             return out
 
         with events_log.span("kubepacs.device.upload"):
-            ent = tuple(self._jnp.asarray(a) for a in (
+            md = tuple(self._jnp.asarray(a) for a in (
                 pad(market.pods, N, np.int32),
                 pad(market.bound, N, np.int32),
                 pad(market.b_item, B, np.int32),
@@ -409,6 +429,7 @@ class FusedJaxBackend(NumpyBackend):
                 pad(np.ones(nb, bool), B, bool),
                 pad(market.perf, N, np.float32),
                 pad(market.price, N, np.float32, fill=1.0)))
+            ent = md, len(np.unique(market.b_pods)) <= _SIZE_TABLE
         self._market_cache[key] = ent
         while len(self._market_cache) > self._MAX_MARKETS:
             self._market_cache.popitem(last=False)
@@ -423,10 +444,12 @@ class FusedJaxBackend(NumpyBackend):
                 "host_dp_groups": self.host_dp_groups,
                 "fallback_solves": self.fallback_solves,
                 "verify_solves": self.verify_solves,
-                "program_builds": self.program_builds}
+                "program_builds": self.program_builds,
+                "table_prune_programs": self.table_prune_programs}
 
     # -- the device row solver (traced context) ------------------------------
-    def _solver_core(self, md, N: int, B: int, RC: int, coarse):
+    def _solver_core(self, md, N: int, B: int, RC: int, coarse,
+                     table: bool):
         """Build the traced closures shared by both fused programs.
 
         Returns ``(solve_rows, score)``.  ``solve_rows(coefs, actives,
@@ -442,6 +465,11 @@ class FusedJaxBackend(NumpyBackend):
         identical keep sets; only the core-bound DP, decode DP, and
         backtrack use scaled pods/targets).  Traced scalars, not static:
         changing the config or the market gcd never recompiles.
+
+        ``table`` (static: the market's distinct bundle sizes fit
+        ``_SIZE_TABLE``) computes the LP prune's bound once per distinct
+        bundle size instead of by a binary search per bundle; both give
+        the same ``lp`` bit for bit (DESIGN.md §13).
         """
         jax, jnp = self._jax, self._jnp
         lax = jax.lax
@@ -506,6 +534,24 @@ class FusedJaxBackend(NumpyBackend):
             # overflows its scoped vector memory at B = 512
             return lax.associative_scan(jnp.add, v)
 
+        # -- the LP prune's distinct-size table ------------------------------
+        # once per program call, outside the row loop: the market's
+        # distinct bundle pod sizes ascending, padded with _SIZE_PAD to
+        # _SIZE_TABLE entries, and which entry each bundle's size is (a
+        # pad bundle's hits read pad entries; bmask drops it in any case)
+        if table:
+            with scope("lp_prune"):
+                sizes = jnp.where(b_real, b_pods, _SIZE_PAD)
+                srt = jnp.sort(sizes)
+                first = (srt != _SIZE_PAD) & jnp.concatenate(
+                    [jnp.ones(1, bool), srt[1:] != srt[:-1]])
+                slot = jnp.cumsum(first.astype(i32)) - 1
+                size_tab = jnp.min(jnp.where(
+                    first & (slot == jnp.arange(_SIZE_TABLE,
+                                                dtype=i32)[:, None]),
+                    srt, _SIZE_PAD), axis=1)
+                size_hit = sizes[:, None] == size_tab
+
         # -- one engine row on device ----------------------------------------
         # each stage runs under a jax.named_scope (DESIGN.md §13: spans and
         # stage names), which the profiler's trace carries per operation
@@ -546,8 +592,9 @@ class FusedJaxBackend(NumpyBackend):
                     cum_c = cumsum(c_sorted)
                     cum_r = cumsum(r_sorted * p_sorted)
 
-                def lp_lower(need):
-                    k = jnp.searchsorted(cum_p, need)
+                def lp_lower(k, need):
+                    # fractional greedy bound on covering need pods, with
+                    # k = searchsorted(cum_p, need): #{i : cum_p[i] < need}
                     km = jnp.maximum(k - 1, 0)
                     prev_p = jnp.where(k > 0, cum_p[km], 0)
                     prev_r = jnp.where(k > 0, cum_r[km], 0)
@@ -556,7 +603,17 @@ class FusedJaxBackend(NumpyBackend):
                 with scope("lp_prune"):
                     k_ub = jnp.searchsorted(cum_p, residual)
                     ub = cum_c[k_ub]
-                    lp = lp_lower(jnp.maximum(residual - b_pods, 0))
+                    if table:
+                        # the bound once per distinct size, each k by a
+                        # (_SIZE_TABLE, B) compare-and-count; a bundle
+                        # reads its size's bound from the one-hot size_hit
+                        need = jnp.maximum(residual - size_tab, 0)
+                        lp_tab = lp_lower(jnp.sum(cum_p < need[:, None],
+                                                  axis=1, dtype=i32), need)
+                        lp = jnp.sum(jnp.where(size_hit, lp_tab, 0), axis=1)
+                    else:
+                        need = jnp.maximum(residual - b_pods, 0)
+                        lp = lp_lower(jnp.searchsorted(cum_p, need), need)
                     keep0 = bmask & (bcost + lp <= ub)
                     # DP stages run at granularity eff_g (1 = exact); the
                     # prune math above stays unscaled so the keep set is the
@@ -663,8 +720,8 @@ class FusedJaxBackend(NumpyBackend):
         return solve_rows, score
 
     # -- fused programs ------------------------------------------------------
-    def _prescan_program(self, N, B, RC, D, G):
-        key = ("prescan", N, B, RC, D, G)
+    def _prescan_program(self, N, B, RC, D, G, table=True):
+        key = _program_key("prescan", (N, B, RC, D, G), table)
         fn = self._fused_cache.get(key)
         if fn is None:
             jnp = self._jnp
@@ -672,7 +729,8 @@ class FusedJaxBackend(NumpyBackend):
             # the function's name is the program's on the trace's
             # "XLA Modules" line (jit_kubepacs_prescan)
             def kubepacs_prescan(md, w, q, active, reqs, ks, coarse):
-                solve_rows, _score = self._solver_core(md, N, B, RC, coarse)
+                solve_rows, _score = self._solver_core(md, N, B, RC, coarse,
+                                                       table)
                 di = jnp.arange(D * G) // G
                 k = ks[jnp.arange(D * G) % G][:, None]
                 coefs = exact.coefficients(k, w[di], q[di])
@@ -682,10 +740,11 @@ class FusedJaxBackend(NumpyBackend):
             fn = self._jax.jit(kubepacs_prescan)
             self._fused_cache[key] = fn
             self.program_builds += 1
+            self.table_prune_programs += table
         return fn
 
-    def _golden_program(self, N, B, RC, D, MAXR):
-        key = ("golden", N, B, RC, D, MAXR)
+    def _golden_program(self, N, B, RC, D, MAXR, table=True):
+        key = _program_key("golden", (N, B, RC, D, MAXR), table)
         fn = self._fused_cache.get(key)
         if fn is None:
             jax, jnp = self._jax, self._jnp
@@ -698,7 +757,8 @@ class FusedJaxBackend(NumpyBackend):
             def kubepacs_golden(md, w, q, active, reqs, a0, b0, tolk,
                                 coarse):
                 with jax.named_scope("control"):
-                    solve_rows, score = self._solver_core(md, N, B, RC, coarse)
+                    solve_rows, score = self._solver_core(md, N, B, RC,
+                                                          coarse, table)
                     reqf = reqs.astype(jnp.float32)
                     dn = jnp.arange(D)
                     g0 = exact.golden_width(b0 - a0)
@@ -758,6 +818,7 @@ class FusedJaxBackend(NumpyBackend):
             fn = jax.jit(kubepacs_golden)
             self._fused_cache[key] = fn
             self.program_builds += 1
+            self.table_prune_programs += table
         return fn
 
     # -- host-side drivers ---------------------------------------------------
@@ -810,10 +871,10 @@ class FusedJaxBackend(NumpyBackend):
         Dr, G = len(reqs), len(kgrid)
         with events_log.span("kubepacs.device.inputs"):
             N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
-            md = self._device_market(market, N, B)
+            md, table = self._device_market(market, N, B)
             w, q, active, rq = self._decision_arrays(market, reqs, excludes,
                                                      N, D)
-            fn = self._prescan_program(N, B, RC, D, G)
+            fn = self._prescan_program(N, B, RC, D, G, table)
             args = (md, w, q, active, rq, np.asarray(kgrid, np.int64),
                     self._coarse_scalars(market, coarsening))
         with events_log.span("kubepacs.device.prescan"):
@@ -828,7 +889,7 @@ class FusedJaxBackend(NumpyBackend):
         Dr = len(reqs)
         with events_log.span("kubepacs.device.inputs"):
             N, B, RC, D = self._shape_key(market, reqs, Dr, coarsening)
-            md = self._device_market(market, N, B)
+            md, table = self._device_market(market, N, B)
             w, q, active, rq = self._decision_arrays(market, reqs, excludes,
                                                      N, D)
             # round budget: any bracket is <= 1 wide and shrinks by at most
@@ -840,7 +901,7 @@ class FusedJaxBackend(NumpyBackend):
             a0[:Dr] = a_list
             b0 = np.zeros(D, np.int64)
             b0[:Dr] = b_list
-            fn = self._golden_program(N, B, RC, D, MAXR)
+            fn = self._golden_program(N, B, RC, D, MAXR, table)
             args = (md, w, q, active, rq, a0, b0,
                     np.int64(exact.tolerance_k(tolerance)),
                     self._coarse_scalars(market, coarsening))

@@ -448,6 +448,83 @@ def test_fused_prescan_rows_equal_numpy_110_markets():
     assert n_infeasible > 0
 
 
+def _sizes_market(rng, n_sizes):
+    """A gcd-2 market with exactly ``n_sizes`` distinct bundle pod sizes:
+    single-node items of 2, 4, …, 2·n_sizes pods, and three-node items
+    whose two bundles (one and two nodes) repeat sizes already there."""
+    pods = [2 * i for i in range(1, n_sizes + 1)]
+    pods += [2 * int(i) for i in rng.integers(1, n_sizes // 2 + 1, 20)]
+    t3 = [1] * n_sizes + [3] * 20
+    return [_mk_item(i, p, float(rng.uniform(1e3, 1e5)),
+                     float(rng.uniform(0.01, 3.0)), t)
+            for i, (p, t) in enumerate(zip(pods, t3))]
+
+
+@requires_jax
+@pytest.mark.parametrize("n_sizes,table", [(128, True), (136, False)],
+                         ids=["full_table", "search_fallback"])
+def test_fused_lp_prune_equals_numpy_at_the_table_width(n_sizes, table):
+    """The LP prune by distinct-size table (a market whose bundle sizes
+    fill the table exactly) and by per-bundle search (one size more than
+    it holds) select the NumPy engine's pools, α and every probe: rows
+    whose need is 0 for every bundle but the smallest, rows whose
+    residual equals their capacity (below the coarsening threshold and
+    gcd-coarsened above it), a masked row and a gcd-coarsened row."""
+    from repro.core import CoarseningConfig
+    rng = np.random.default_rng(n_sizes)
+    items = _sizes_market(rng, n_sizes)
+    market = compile_market(items)
+    assert len(np.unique(market.b_pods)) == n_sizes
+    assert market.pods_gcd == 2
+    capacity = sum(it.pods * it.t3 for it in items)
+    # the first 29 single-node items hold 2 + 4 + … + 58 = 870 pods
+    small = np.arange(len(items)) >= 29
+    reqs = [3, 700, 870, capacity, 5000]
+    excludes = [None, _random_exclude(rng, len(items)), small, None, None]
+    cfg = CoarseningConfig(threshold=1000, max_rows=100_000)
+    be = make_backend("jax:fused")
+    fake = lambda: 0.0                                     # noqa: E731
+    got_n = bracketed_gss_many(items, reqs, market=market, excludes=excludes,
+                               timer=fake, backend=NUMPY, coarsening=cfg)
+    got_f = bracketed_gss_many(items, reqs, market=market, excludes=excludes,
+                               timer=fake, backend=be, coarsening=cfg)
+    assert _gss_summary(got_n) == _gss_summary(got_f)
+    assert all(p is not None for p, _t in got_f)
+    info = be.device_cache_info()
+    assert info["fallback_solves"] == 0 and info["program_builds"] == 2
+    assert info["table_prune_programs"] == (2 if table else 0)
+
+
+@requires_jax
+@pytest.mark.parametrize("config,n_sizes", [("karpenter_zone_m", 41),
+                                            ("karpenter_region_cmr", 47)])
+def test_benchmark_catalogs_take_the_table_prune(config, n_sizes):
+    """Both benchmark deployments (catalog seed 11) have few distinct
+    bundle sizes, so every program their ticks build prunes by the
+    table; counted by ``device_cache_info()["table_prune_programs"]``."""
+    import json
+    import os
+
+    from bench import catalog
+    from repro.core import Offering
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    offerings = [Offering(**o)
+                 for o in catalog.deployment_offerings(cfg, 11)]
+    market = compile_market(preprocess(
+        offerings, Request(pods=1, cpu_per_pod=cfg["pod_cpu"],
+                           mem_per_pod=cfg["pod_mem_gib"])))
+    assert len(np.unique(market.b_pods)) == n_sizes
+    be = make_backend("jax:fused")
+    N, B, RC, D = be._shape_key(market, [1000] * 8, 8)
+    _md, table = be._device_market(market, N, B)
+    be._prescan_program(N, B, RC, D, 9, table)
+    be._golden_program(N, B, RC, D, 12, table)
+    info = be.device_cache_info()
+    assert info["table_prune_programs"] == info["program_builds"] == 2
+
+
 @requires_jax
 def test_fleet_fused_traces_byte_identical():
     """FleetSim with ``backend="jax:fused"`` (string spec resolved via
@@ -526,20 +603,10 @@ _ROW_STAGES = ("saturate", "sort", "lp_prune", "core_dp", "compact",
                "cover_dp", "backtrack", "rows")
 
 
-@requires_jax
-@pytest.mark.parametrize("program,stages", [
-    ("prescan", _ROW_STAGES), ("golden", _ROW_STAGES + ("score", "control"))])
-def test_device_programs_carry_names_and_stage_scopes(program, stages):
-    """Both jits carry a stable name of their own, and every stage of the
-    row solver (and the golden loop's scoring and control) its named
-    scope, so a profiler trace can name each operation's stage."""
-    import re
-
+def _lower_program(be, program, N, B, RC, D, table=True):
+    """One fused program lowered at the given shapes (G = 9, MAXR = 12)."""
     import jax
     import jax.numpy as jnp
-
-    be = make_backend("jax:fused")
-    N, B, RC, D = 16, 32, 129, 2
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
@@ -549,12 +616,25 @@ def test_device_programs_carry_names_and_stage_scopes(program, stages):
     decisions = (s((D, N), jnp.int64), s((D, N), jnp.int64),
                  s((D, N), bool), s((D,), jnp.int64))
     if program == "prescan":
-        fn = be._prescan_program(N, B, RC, D, 9)
+        fn = be._prescan_program(N, B, RC, D, 9, table)
         tail = (s((9,), jnp.int64),)
     else:
-        fn = be._golden_program(N, B, RC, D, 12)
+        fn = be._golden_program(N, B, RC, D, 12, table)
         tail = (s((D,), jnp.int64), s((D,), jnp.int64), s((), jnp.int64))
-    lowered = fn.lower(market, *decisions, *tail, s((3,), jnp.int64))
+    return fn.lower(market, *decisions, *tail, s((3,), jnp.int64))
+
+
+@requires_jax
+@pytest.mark.parametrize("program,stages", [
+    ("prescan", _ROW_STAGES), ("golden", _ROW_STAGES + ("score", "control"))])
+def test_device_programs_carry_names_and_stage_scopes(program, stages):
+    """Both jits carry a stable name of their own, and every stage of the
+    row solver (and the golden loop's scoring and control) its named
+    scope, so a profiler trace can name each operation's stage."""
+    import re
+
+    lowered = _lower_program(make_backend("jax:fused"), program,
+                             16, 32, 129, 2)
     assert f"@jit_kubepacs_{program}" in lowered.as_text()
     scopes = set()
     for loc in re.findall(r'loc\("([^"]*)"', lowered.as_text(
@@ -563,3 +643,27 @@ def test_device_programs_carry_names_and_stage_scopes(program, stages):
     assert set(stages) <= scopes
     if program == "prescan":
         assert not {"score", "control"} & scopes
+
+
+@requires_jax
+@pytest.mark.parametrize("program", ["prescan", "golden"])
+def test_table_prune_holds_no_per_bundle_search(program):
+    """Compiled at zone_m's shapes, the table programs' LP prune holds no
+    per-bundle binary search (no ``while`` under
+    ``lp_prune/jit(searchsorted)/vmap()``, which the search fallback
+    has), and every operation the table adds is under the ``lp_prune``
+    scope, so a trace attributes it to that stage."""
+    import re
+
+    be = make_backend("jax:fused")
+    names = {}
+    for table in (True, False):
+        text = _lower_program(be, program, 512, 1152, 1537, 32,
+                              table).compile().as_text()
+        names[table] = set(re.findall(r'op_name="([^"]*)"', text))
+    search = "/lp_prune/jit(searchsorted)/vmap()/while"
+    assert not [n for n in names[True] if search in n]
+    assert [n for n in names[False] if search in n]
+    # a bare name is a reducer's body, not an operation of the program
+    added = [n for n in names[True] - names[False] if "/" in n]
+    assert added and all("/lp_prune/" in n for n in added)
