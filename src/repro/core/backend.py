@@ -355,10 +355,23 @@ class FusedJaxBackend(NumpyBackend):
     ``device_cache_info()`` exposes hit/miss counters), so FleetSim ticks
     re-dispatch onto resident arrays; per-decision coefficient vectors,
     masks, demands and brackets are the only per-tick upload.
+
+    **Row counters.**  Each program call also returns the row solver's
+    ``ROW_COUNTERS``, counted in the program where it decides a row's rung
+    and tier; ``device_cache_info()`` exposes their totals, so a reader
+    sees which rung the device took without re-deriving it on the host.
     """
 
     name = "jax:fused"
     supports_fused_gss = True
+
+    #: the row solver's own counters, in the order both programs return
+    #: them: rows that reached the DP stages, those of them solved at a
+    #: granularity g > 1 (the gcd rung), the DP columns those rows needed
+    #: (``ceil(residual / g) + 1``) and the columns their DPs computed (the
+    #: static width of the residual tier each ran at)
+    ROW_COUNTERS = ("dp_rows", "gcd_rows", "dp_cols_needed",
+                    "dp_cols_computed")
 
     #: fused-program bucket ladders.  R is deliberately fine (512-multiples
     #: beyond 512): every vector op in the fused row solver is O(R_pad), so
@@ -390,6 +403,8 @@ class FusedJaxBackend(NumpyBackend):
         self.program_builds = 0
         self.table_prune_programs = 0
         self.verify_solves = 0
+        self.row_counters = dict.fromkeys(self.ROW_COUNTERS, 0)
+        self._rows_in_flight: List = []
 
     def cover_bits(self, groups):
         self.host_dp_groups += len(groups)
@@ -436,6 +451,7 @@ class FusedJaxBackend(NumpyBackend):
         return ent
 
     def device_cache_info(self) -> Dict[str, int]:
+        self._drain_rows()
         return {"hits": self.device_cache_hits,
                 "misses": self.device_cache_misses,
                 "entries": len(self._market_cache),
@@ -445,7 +461,16 @@ class FusedJaxBackend(NumpyBackend):
                 "fallback_solves": self.fallback_solves,
                 "verify_solves": self.verify_solves,
                 "program_builds": self.program_builds,
-                "table_prune_programs": self.table_prune_programs}
+                "table_prune_programs": self.table_prune_programs,
+                **self.row_counters}
+
+    def _drain_rows(self) -> None:
+        """Add the row counters of the calls :meth:`_dispatch` kept to the
+        totals."""
+        for rows in self._rows_in_flight:
+            for name, n in zip(self.ROW_COUNTERS, np.asarray(rows).tolist()):
+                self.row_counters[name] += n
+        self._rows_in_flight.clear()
 
     # -- the device row solver (traced context) ------------------------------
     def _solver_core(self, md, N: int, B: int, RC: int, coarse,
@@ -455,7 +480,10 @@ class FusedJaxBackend(NumpyBackend):
         Returns ``(solve_rows, score)``.  ``solve_rows(coefs, actives,
         reqs)`` solves a stack of engine rows — each one
         ``repro.core.ilp._solve_rows`` row end to end on its exact int64
-        coefficient row — returning ``(counts int32 (D, N), feasible)``.
+        coefficient row — returning ``(counts int32 (D, N), feasible,
+        rows)``, where ``rows`` holds the stack's ``ROW_COUNTERS`` as int32
+        scalars: which rung each row took, counted where the program
+        decides it.
 
         ``coarse`` is the traced ``(threshold, max_rows, gcd)`` int64
         triple of the active :class:`CoarseningConfig`.  Rows whose
@@ -527,6 +555,7 @@ class FusedJaxBackend(NumpyBackend):
 
         tiers = _rc_tiers(RC)
         tier_tools = [dp_tools(W) for W in tiers]
+        tier_w = jnp.asarray(tiers, i32)
 
         def cumsum(v):
             # integer prefix sums are exact in any association; the TPU
@@ -573,6 +602,16 @@ class FusedJaxBackend(NumpyBackend):
                 use_g = (residual > c_thr) & (c_gcd > 1) & (rs_g <= c_maxr)
                 eff_g = jnp.where(use_g, c_gcd, 1).astype(i64)
                 eff_res = ((residual + eff_g - 1) // eff_g).astype(i32)
+                # the narrowest tier wider than the effective (coarsened)
+                # residual: the static width the row's DPs run at
+                t_idx = jnp.minimum(
+                    jnp.searchsorted(tier_w, eff_res, side="right"),
+                    len(tiers) - 1)
+                # the row's ROW_COUNTERS, as scalars: a vector here would
+                # cost the row loop a kernel of its own per row
+                dp_row = (residual > 0) & feasible
+                row_counts = tuple(jnp.where(dp_row, v, 0) for v in (
+                    i32(1), use_g.astype(i32), eff_res + 1, tier_w[t_idx]))
 
             def dp_part(_):
                 # masked-not-compacted: non-DP bundles sort last (key INF)
@@ -670,17 +709,13 @@ class FusedJaxBackend(NumpyBackend):
                                 jnp.where(take, b_copies[perm], 0))
                     return run
 
-                # route the row to the narrowest tier wider than its
-                # effective (coarsened) residual; lax.switch preserves real
-                # branching, so a row pays only its own tier's vector width
-                t_idx = jnp.searchsorted(jnp.asarray(tiers, i32), eff_res,
-                                         side="right")
-                return lax.switch(jnp.minimum(t_idx, len(tiers) - 1),
-                                  [tier_case(t) for t in tier_tools], None)
+                # route the row to its tier (t_idx); lax.switch preserves
+                # real branching, so a row pays only its own tier's width
+                return lax.switch(t_idx, [tier_case(t) for t in tier_tools],
+                                  None)
 
-            counts = lax.cond((residual > 0) & feasible, dp_part,
-                              lambda _o: sat, None)
-            return counts, feasible
+            counts = lax.cond(dp_row, dp_part, lambda _o: sat, None)
+            return counts, feasible, row_counts
 
         # -- row batching ----------------------------------------------------
         def solve_rows(coefs, actives, reqs):
@@ -693,15 +728,17 @@ class FusedJaxBackend(NumpyBackend):
             D = coefs.shape[0]
 
             def body(i, out):
-                cnts, feas = out
-                c, f = solve_row(coefs[i], actives[i], reqs[i])
-                return cnts.at[i].set(c), feas.at[i].set(f)
+                cnts, feas, rows = out
+                c, f, r = solve_row(coefs[i], actives[i], reqs[i])
+                return (cnts.at[i].set(c), feas.at[i].set(f),
+                        tuple(map(jnp.add, rows, r)))
             # "rows": the row loop and each row's branch routing; the
             # stages nested in it carry their own scopes
             with scope("rows"):
                 return lax.fori_loop(
                     0, D, body,
-                    (jnp.zeros((D, N), i32), jnp.zeros(D, bool)))
+                    (jnp.zeros((D, N), i32), jnp.zeros(D, bool),
+                     (i32(0),) * len(self.ROW_COUNTERS)))
 
         # -- pool scoring ----------------------------------------------------
         def score(cnts, feas, reqf):
@@ -734,8 +771,10 @@ class FusedJaxBackend(NumpyBackend):
                 di = jnp.arange(D * G) // G
                 k = ks[jnp.arange(D * G) % G][:, None]
                 coefs = exact.coefficients(k, w[di], q[di])
-                counts, feas = solve_rows(coefs, active[di], reqs[di])
-                return counts.reshape(D, G, N), feas.reshape(D, G)
+                counts, feas, rows = solve_rows(coefs, active[di],
+                                                reqs[di])
+                return (counts.reshape(D, G, N), feas.reshape(D, G),
+                        jnp.stack(rows))
 
             fn = self._jax.jit(kubepacs_prescan)
             self._fused_cache[key] = fn
@@ -775,7 +814,8 @@ class FusedJaxBackend(NumpyBackend):
                                           & jnp.any((b - a) > tolk))
 
                     def body(st):
-                        (r, a, b, x1, x2, f1, f2, ev_k, ev_c, ev_f, evn) = st
+                        (r, a, b, x1, x2, f1, f2, ev_k, ev_c, ev_f, evn,
+                         rows) = st
                         init = r < 2
                         act = init | ((b - a) > tolk)
                         right = ~init & act & (f1 >= f2)  # shrink from right
@@ -793,7 +833,7 @@ class FusedJaxBackend(NumpyBackend):
                         # inactive decisions re-solve req=0 (the cheap
                         # saturation fast path) instead of a full row
                         reqv = jnp.where(act, reqs, 0)
-                        cp, fep = solve_rows(
+                        cp, fep, rp = solve_rows(
                             exact.coefficients(probe[:, None], w, q), active,
                             reqv)
                         fp = score(cp, fep, reqf)
@@ -807,13 +847,15 @@ class FusedJaxBackend(NumpyBackend):
                             jnp.where(act, fep, ev_f[dn, evn]))
                         evn = evn + act.astype(i32)
                         return (r + 1, na, nb, nx1, nx2, nf1, nf2,
-                                ev_k, ev_c, ev_f, evn)
+                                ev_k, ev_c, ev_f, evn,
+                                tuple(map(jnp.add, rows, rp)))
 
                     st = lax.while_loop(cond, body, (
                         i32(0), a0, b0, x1, x2, neg_inf, neg_inf,
                         jnp.zeros((D, ME), i64), jnp.zeros((D, ME, N), i32),
-                        jnp.zeros((D, ME), bool), jnp.zeros(D, i32)))
-                    return st[7], st[8], st[9], st[10]
+                        jnp.zeros((D, ME), bool), jnp.zeros(D, i32),
+                        (i32(0),) * len(self.ROW_COUNTERS)))
+                    return st[7], st[8], st[9], st[10], jnp.stack(st[11])
 
             fn = jax.jit(kubepacs_golden)
             self._fused_cache[key] = fn
@@ -823,16 +865,18 @@ class FusedJaxBackend(NumpyBackend):
 
     # -- host-side drivers ---------------------------------------------------
     def _shape_key(self, market, reqs, n_dec, coarsening=None):
+        """A batch's static shapes ``(N, B, RC, D)``; ``coarsening=None``
+        is :data:`DEFAULT_COARSENING`, as in :meth:`fused_gss_record`, so
+        every caller sizes the program the served path compiles."""
+        cfg = DEFAULT_COARSENING if coarsening is None else coarsening
         N = _bucket(max(market.n, 1), self._N_STEPS)
         B = _bucket(max(market.n_bundles, 1), self._BF_STEPS)
         width = max(max(reqs, default=1), 1)
-        if (coarsening is not None and coarsening.enabled
-                and width > coarsening.threshold
+        if (cfg.enabled and width > cfg.threshold
                 and market.pods_gcd > 1):
             # gcd-coarsened rows need ceil(req/g) DP rows; rows whose
             # residual stays below the threshold need the threshold width
-            width = max(coarsening.threshold,
-                        -(-width // market.pods_gcd))
+            width = max(cfg.threshold, -(-width // market.pods_gcd))
         RC = _bucket(width, self._RF_STEPS) + 1
         D = _bucket(max(n_dec, 1), self._D_STEPS)
         return N, B, RC, D
@@ -841,11 +885,13 @@ class FusedJaxBackend(NumpyBackend):
     def _coarse_scalars(market, coarsening):
         """The ``(threshold, max_rows, gcd)`` triple handed to the compiled
         programs as *traced* scalars (config or market changes never force
-        a recompile).  Coarsening off → an unreachable threshold, so every
-        row takes the exact path."""
-        if coarsening is None or not coarsening.enabled:
+        a recompile); ``None`` is :data:`DEFAULT_COARSENING`.  Coarsening
+        off → an unreachable threshold, so every row takes the exact
+        path."""
+        cfg = DEFAULT_COARSENING if coarsening is None else coarsening
+        if not cfg.enabled:
             return np.asarray([2 ** 62, 1, 1], np.int64)
-        return np.asarray([coarsening.threshold, coarsening.max_rows,
+        return np.asarray([cfg.threshold, cfg.max_rows,
                            max(market.pods_gcd, 1)], np.int64)
 
     def _decision_arrays(self, market, reqs, excludes, N, D):
@@ -867,6 +913,17 @@ class FusedJaxBackend(NumpyBackend):
             w[d, :n], q[d, :n], active[d, :n] = seen[mkey]
         return w, q, active, rq
 
+    def _dispatch(self, fn, args):
+        """Run a program to completion.  Its last output, the row counters,
+        starts for the host at once and joins the totals at the next call
+        (or ``device_cache_info()``), by when it has landed: reading it at
+        once would make every call wait on one more transfer."""
+        self._drain_rows()
+        out = fn(*args)
+        out[-1].copy_to_host_async()
+        self._rows_in_flight.append(out[-1])
+        return self._jax.block_until_ready(out)
+
     def _run_prescan(self, market, reqs, excludes, kgrid, coarsening=None):
         Dr, G = len(reqs), len(kgrid)
         with events_log.span("kubepacs.device.inputs"):
@@ -878,9 +935,9 @@ class FusedJaxBackend(NumpyBackend):
             args = (md, w, q, active, rq, np.asarray(kgrid, np.int64),
                     self._coarse_scalars(market, coarsening))
         with events_log.span("kubepacs.device.prescan"):
-            out = self._jax.block_until_ready(fn(*args))
+            out = self._dispatch(fn, args)
         with events_log.span("kubepacs.device.readback"):
-            counts, feas = out
+            counts, feas, _rows = out
             return (np.asarray(counts)[:Dr, :, :market.n],
                     np.asarray(feas)[:Dr])
 
@@ -906,9 +963,9 @@ class FusedJaxBackend(NumpyBackend):
                     np.int64(exact.tolerance_k(tolerance)),
                     self._coarse_scalars(market, coarsening))
         with events_log.span("kubepacs.device.golden"):
-            out = self._jax.block_until_ready(fn(*args))
+            out = self._dispatch(fn, args)
         with events_log.span("kubepacs.device.readback"):
-            ev_k, ev_c, ev_f, evn = out
+            ev_k, ev_c, ev_f, evn, _rows = out
             return (np.asarray(ev_k)[:Dr],
                     np.asarray(ev_c)[:Dr, :, :market.n],
                     np.asarray(ev_f)[:Dr], np.asarray(evn)[:Dr])

@@ -497,7 +497,8 @@ def test_fused_lp_prune_equals_numpy_at_the_table_width(n_sizes, table):
 
 @requires_jax
 @pytest.mark.parametrize("config,n_sizes", [("karpenter_zone_m", 41),
-                                            ("karpenter_region_cmr", 47)])
+                                            ("karpenter_region_cmr", 47),
+                                            ("karpenter_zone_m_250m", 41)])
 def test_benchmark_catalogs_take_the_table_prune(config, n_sizes):
     """Both benchmark deployments (catalog seed 11) have few distinct
     bundle sizes, so every program their ticks build prunes by the

@@ -223,3 +223,122 @@ def test_high_demand_scenario_engages_coarse_tier():
     assert sc == type(sc).from_dict(sc.to_dict())
     small = high_demand_scenario(pods=40_000)
     assert small.pods == 40_000 and small.name == "high_demand_40000"
+
+
+# ------------------------------------- the gcd rung against the reference ----
+
+#: a lowered ladder: exact up to 256 pods, the gcd rung up to 128 rows
+SMALL_LADDER = CoarseningConfig(threshold=256, max_rows=128)
+
+
+def _quarter_vcpu_market():
+    """One zone's generation-5 m offerings (bench/catalog.py, seed 11: 56
+    offerings) under 250m CPU / 256Mi pods: 8 to 384 pods per node, gcd 8."""
+    from bench import catalog
+    from repro.core import Offering, Request
+    from repro.core.provisioner import preprocess
+
+    offs = catalog.offerings(11, ["us-east-1"], ["m"], [5], ["us-east-1a"])
+    items = preprocess([Offering(**o) for o in offs], Request(1, 0.25, 0.25))
+    return offs, items, compile_market(items)
+
+
+def _expected_row_counters(market, rows, cfg, tiers):
+    """The row solver's counters for ``(req, k, mask)`` rows, worked out
+    from each row's residual: the demand less the nodes of the items its
+    objective saturates; a row reaches the DP stages when that is positive
+    and the rest of the market covers it.  Also the number of rows that
+    saturate to residual 0."""
+    nodes = market.pods.astype(np.int64) * market.bound.astype(np.int64)
+    g = market.pods_gcd
+    out = {"dp_rows": 0, "gcd_rows": 0, "dp_cols_needed": 0,
+           "dp_cols_computed": 0}
+    zero = 0
+    for req, k, mask in rows:
+        w, q, active = market.solve_inputs(mask)
+        neg = (exact.coefficients(np.int64(k), w, q) < 0) & active
+        residual = max(req - int(nodes[neg].sum()), 0)
+        zero += residual == 0
+        if residual == 0 or int(nodes[active & ~neg].sum()) < residual:
+            continue
+        gcd = residual > cfg.threshold and -(-residual // g) <= cfg.max_rows
+        cols = -(-residual // (g if gcd else 1))
+        out["dp_rows"] += 1
+        out["gcd_rows"] += gcd
+        out["dp_cols_needed"] += cols + 1
+        out["dp_cols_computed"] += min(t for t in tiers if t > cols)
+    return out, zero
+
+
+@requires_jax
+def test_fused_gcd_rung_equals_the_reference_and_counts_its_rows():
+    """bracketed_gss_many on ``jax:fused`` over a quarter-vCPU catalog
+    (gcd 8) under a lowered ladder, so one batch holds rows at residual 0,
+    rows on the exact rung and rows on the gcd rung: pools, α and every
+    probe equal the plain reference (bench/reference.py), nothing runs on
+    the host, and the programs' own row counters equal the counts worked
+    out from each row's residual."""
+    from bench import reference
+    from repro.core.backend import FusedJaxBackend, _rc_tiers
+    from repro.core.provisioner import exclusion_mask
+
+    offs, items, market = _quarter_vcpu_market()
+    assert len(offs) == 56 and market.pods_gcd == 8
+    reqs = [180, 640, 900, 1010]
+    excluded = [set(), {offs[3]["offering_id"], offs[20]["offering_id"]},
+                set(), set()]
+    masks = [exclusion_mask(items, e) for e in excluded]
+    be = make_backend("jax:fused")
+    RC = be._shape_key(market, reqs, len(reqs), SMALL_LADDER)[2]
+    assert RC == 257            # max(threshold, ceil(1010 / 8)) bucketed
+    got = bracketed_gss_many(items, reqs, market=market, excludes=masks,
+                             timer=lambda: 0.0, backend=be,
+                             coarsening=SMALL_LADDER)
+    ref = reference.decide_many(reference.Market(offs, 0.25, 0.25),
+                                list(zip(reqs, excluded)))
+    for (pool, trace), (r_pool, r_alpha, r_probes) in zip(got, ref):
+        assert pool.as_dict() == r_pool and pool.alpha == r_alpha
+        assert list(zip(trace.alphas, trace.e_totals)) == r_probes
+    info = be.device_cache_info()
+    assert info["fused_records"] == 1
+    assert info["declined_batches"] == info["host_dp_groups"] == \
+        info["fallback_solves"] == 0
+    # the prescan solves each decision's grid and the golden program each
+    # later probe of its search: the reference's probes, in order
+    rows = [(req, int(round(alpha * exact.ALPHA_ONE)), mask)
+            for req, mask, (_p, _a, probes) in zip(reqs, masks, ref)
+            for alpha, _e in probes]
+    want, zero = _expected_row_counters(market, rows, SMALL_LADDER,
+                                        _rc_tiers(RC))
+    assert {k: info[k] for k in FusedJaxBackend.ROW_COUNTERS} == want
+    # the batch mixes every rung
+    assert zero > 0 and want["gcd_rows"] > 0
+    assert want["dp_rows"] > want["gcd_rows"]
+    # the host engine takes the same rung on every gcd row
+    stats = solve_ilp_many(items, [r for r, _k, _m in rows],
+                           [[exact.k_alpha(k)] for _r, k, _m in rows],
+                           market=market, excludes=[m for _r, _k, m in rows],
+                           return_stats=True, coarsening=SMALL_LADDER)[1]
+    assert sum(s[0] is not None and s[0].coarse == "gcd"
+               for s in stats) == want["gcd_rows"]
+
+
+def test_unset_coarsening_sizes_programs_as_the_served_path():
+    """``coarsening=None`` is DEFAULT_COARSENING in ``_shape_key`` and
+    ``_coarse_scalars``, as in ``fused_gss_record``: above the threshold a
+    gcd market sizes the program by its coarsened width, and at or below
+    it every shape is the exact ladder's."""
+    from repro.core.backend import FusedJaxBackend as F
+
+    _offs, _items, market = _quarter_vcpu_market()
+    for reqs in ([27_600, 20_400], [8_193], [9_000, 100]):
+        key = F._shape_key(F, market, reqs, len(reqs))
+        assert key == F._shape_key(F, market, reqs, len(reqs),
+                                   DEFAULT_COARSENING)
+        assert key[2] == 8193
+    for reqs in ([8_192], [1_000, 5], [4_000, 8_000, 2]):
+        assert F._shape_key(F, market, reqs, len(reqs)) == \
+            F._shape_key(F, market, reqs, len(reqs), EXACT)
+    assert (F._coarse_scalars(market, None)
+            == F._coarse_scalars(market, DEFAULT_COARSENING)).all()
+    assert list(F._coarse_scalars(market, None)) == [8192, 4096, 8]
