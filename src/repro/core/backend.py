@@ -1004,7 +1004,11 @@ class _FusedGssRecord:
 
     Construction runs the fused prescan; :meth:`run_golden` runs the fused
     golden program once the host has chosen brackets.  Both fill a
-    grid-index → counts lookup per decision.  The host replay
+    grid-index → counts lookup per decision.  Counts stay the readback's
+    arrays (a row is a view of the program's output, None where
+    infeasible): no per-item Python list is built, and the replay turns
+    only the rows that become pools into their few non-zero entries
+    (:func:`~repro.core.efficiency.nonzero_pool`).  The host replay
     (``bracketed_gss_many``) then re-executes the sequential control flow
     with exact host scores and resolves every probe through
     :meth:`solve_many`: device-recorded counts on a hit, a counted NumPy
@@ -1026,10 +1030,10 @@ class _FusedGssRecord:
                                             self._excludes, kgrid,
                                             coarsening=coarsening)
         with events_log.span("kubepacs.device.readback"):
-            self.prescan = [
-                [list(map(int, counts[d, g])) if feas[d, g] else None
-                 for g in range(len(kgrid))]
-                for d in range(len(self._reqs))]
+            self.prescan = [[row if ok else None
+                             for row, ok in zip(counts_d, feas_d)]
+                            for counts_d, feas_d in zip(counts,
+                                                        feas.tolist())]
             self._lookup: List[dict] = [dict(zip(kgrid, row))
                                         for row in self.prescan]
         with events_log.span("kubepacs.fused.verify"):
@@ -1058,7 +1062,9 @@ class _FusedGssRecord:
         be.verify_solves += 1
         ref = self._host_solve([self._reqs[d]], [[kgrid[g]]],
                                [self._excludes[d]])[0][0]
-        if ref != self.prescan[d][g]:
+        row = self.prescan[d][g]
+        if ((ref is None) != (row is None)
+                or (ref is not None and not np.array_equal(ref, row))):
             raise PrescanMismatch(
                 f"device prescan counts diverged from the host engine at "
                 f"decision {d}, alpha {exact.k_alpha(kgrid[g])!r}")
@@ -1068,16 +1074,16 @@ class _FusedGssRecord:
             self._market, self._reqs, self._excludes, a_list, b_list,
             self._tolerance, coarsening=self._coarsening)
         with events_log.span("kubepacs.device.readback"):
-            for d in range(len(self._reqs)):
-                lut = self._lookup[d]
-                for s in range(int(evn[d])):
-                    cnt = (list(map(int, ev_c[d, s])) if ev_f[d, s]
-                           else None)
-                    lut.setdefault(int(ev_k[d, s]), cnt)
+            for lut, ks, rows, oks, n in zip(self._lookup, ev_k.tolist(),
+                                             ev_c, ev_f.tolist(),
+                                             evn.tolist()):
+                for k, row, ok in zip(ks[:n], rows, oks):
+                    lut.setdefault(k, row if ok else None)
 
     def solve_many(self, idxs, k_lists):
         """``solve_ilp_many``-shaped resolution of a golden round's probes:
-        one counts-or-None list per (decision index, grid-index list)."""
+        one list of dense count arrays (None where infeasible) per
+        (decision index, grid-index list)."""
         out = [[None] * len(ks) for ks in k_lists]
         miss_pos: List[Tuple[int, List[int]]] = []
         for i, (d, ks) in enumerate(zip(idxs, k_lists)):
@@ -1100,8 +1106,9 @@ class _FusedGssRecord:
                     [self._excludes[idxs[i]] for i, _js in miss_pos])
             for (i, js), counts_d in zip(miss_pos, solved):
                 for j, c in zip(js, counts_d):
-                    out[i][j] = c
-                    self._lookup[idxs[i]].setdefault(k_lists[i][j], c)
+                    out[i][j] = None if c is None else np.asarray(c)
+                    self._lookup[idxs[i]].setdefault(k_lists[i][j],
+                                                     out[i][j])
         return out
 
 

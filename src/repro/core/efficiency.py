@@ -108,6 +108,19 @@ class NodePool:
                         alpha=self.alpha, request=self.request)
 
 
+def nonzero_pool(items: Sequence[CandidateItem], counts,
+                 alpha: Optional[float] = None) -> NodePool:
+    """The pool of a dense count vector's non-zero entries, in item order,
+    with Python ``int`` counts.  It scores bit for bit as the whole-market
+    pool: :func:`e_perf_cost` sums only the ``c > 0`` terms, in item
+    order, and ``total_pods`` is an exact integer sum; so its cost scales
+    with the pool's few items, not with the market's n."""
+    row = np.asarray(counts)
+    idx = np.flatnonzero(row)
+    return NodePool(items=[items[i] for i in idx.tolist()],
+                    counts=row[idx].tolist(), alpha=alpha)
+
+
 def pods_per_instance(offering: Offering, req: Request) -> int:
     """Eq. 1: Pod_i = min(floor(CPU_i/Req_cpu), floor(Mem_i/Req_mem))."""
     if req.cpu_per_pod <= 0 or req.mem_per_pod <= 0:

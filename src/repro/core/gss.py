@@ -35,7 +35,7 @@ import numpy as np
 
 from . import events_log, exact
 from .backend import CoarseningConfig, SolverBackend
-from .efficiency import (CandidateItem, NodePool, e_total,
+from .efficiency import (CandidateItem, NodePool, e_total, nonzero_pool,
                          score_counts_batch, score_counts_many)
 from .ilp import (CompiledMarket, compile_market, solve_ilp, solve_ilp_many)
 
@@ -284,7 +284,11 @@ def bracketed_gss_many(
     changes.  Scoring deliberately runs per decision with the same array
     shapes as the sequential path (``score_counts_batch`` over that
     decision's grid, scalar ``e_total`` per golden probe) so every float
-    matches bit-for-bit.
+    matches bit-for-bit.  The fused record's counts are arrays, and its
+    pools hold only their non-zero entries (:func:`nonzero_pool`, which
+    scores as the whole-market pool); the host engine's lists make
+    whole-market pools.  Of the prescan grid only the best point's pool
+    is built.
     """
     with events_log.span("kubepacs.gss"):
         n_dec = len(req_pods_list)
@@ -318,30 +322,34 @@ def bracketed_gss_many(
                                               tolerance,
                                               coarsening=coarsening)
 
-        # -- prescan: one stacked engine invocation over every (decision, α)
+        # -- prescan: one stacked engine invocation over every (decision, α).
+        # The record's counts are the readback's arrays: its pools keep only
+        # the few non-zero entries (DESIGN.md §13)
         if record is not None:
             all_counts = record.prescan
+            make_pool = functools.partial(nonzero_pool, items)
         else:
             all_counts = solve_ilp_many(items, list(req_pods_list), grid,
                                         market=market, excludes=list(excludes),
                                         backend=backend, coarsening=coarsening)
+
+            def make_pool(counts, alpha=None):
+                return NodePool(items=list(items), counts=counts,
+                                alpha=alpha)
         all_scores = score_counts_many(items, all_counts, list(req_pods_list),
                                        none_score=float("-inf"),
                                        arrays=market.metric_arrays)
         for st, counts_d, scores in zip(states, all_counts, all_scores):
             st.scan_trace.ilp_solves += len(grid)
-            pools = [None if counts is None
-                     else NodePool(items=list(items), counts=counts)
-                     for counts in counts_d]
             best_idx = 0
-            for gi, (alpha, score, pool) in enumerate(zip(grid, scores,
-                                                          pools)):
-                if pool is not None:
-                    pool.alpha = alpha
+            for gi, (alpha, score) in enumerate(zip(grid, scores)):
                 st.scan_trace.alphas.append(alpha)
                 st.scan_trace.e_totals.append(max(score, 0.0))
                 if score > st.scan_f:
-                    st.scan_pool, st.scan_f, best_idx = pool, score, gi
+                    st.scan_f, best_idx = score, gi
+            if st.scan_f > float("-inf"):      # infeasible rows score -inf
+                st.scan_pool = make_pool(counts_d[best_idx],
+                                         alpha=grid[best_idx])
             st.a = kgrid[max(0, best_idx - 1)]
             st.b = kgrid[min(len(kgrid) - 1, best_idx + 1)]
             w = exact.golden_width(st.b - st.a)
@@ -392,8 +400,7 @@ def bracketed_gss_many(
                     if counts is None:
                         pool, score = None, float("-inf")
                     else:
-                        pool = NodePool(items=list(items), counts=counts,
-                                        alpha=alpha)
+                        pool = make_pool(counts, alpha=alpha)
                         score = e_total(pool, st.req)
                     st.trace.alphas.append(alpha)
                     st.trace.e_totals.append(

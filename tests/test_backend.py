@@ -495,14 +495,8 @@ def test_fused_lp_prune_equals_numpy_at_the_table_width(n_sizes, table):
     assert info["table_prune_programs"] == (2 if table else 0)
 
 
-@requires_jax
-@pytest.mark.parametrize("config,n_sizes", [("karpenter_zone_m", 41),
-                                            ("karpenter_region_cmr", 47),
-                                            ("karpenter_zone_m_250m", 41)])
-def test_benchmark_catalogs_take_the_table_prune(config, n_sizes):
-    """Both benchmark deployments (catalog seed 11) have few distinct
-    bundle sizes, so every program their ticks build prunes by the
-    table; counted by ``device_cache_info()["table_prune_programs"]``."""
+def _bench_market(config):
+    """The compiled market of a benchmark deployment at catalog seed 11."""
     import json
     import os
 
@@ -513,9 +507,20 @@ def test_benchmark_catalogs_take_the_table_prune(config, n_sizes):
         cfg = json.load(f)
     offerings = [Offering(**o)
                  for o in catalog.deployment_offerings(cfg, 11)]
-    market = compile_market(preprocess(
+    return compile_market(preprocess(
         offerings, Request(pods=1, cpu_per_pod=cfg["pod_cpu"],
                            mem_per_pod=cfg["pod_mem_gib"])))
+
+
+@requires_jax
+@pytest.mark.parametrize("config,n_sizes", [("karpenter_zone_m", 41),
+                                            ("karpenter_region_cmr", 47),
+                                            ("karpenter_zone_m_250m", 41)])
+def test_benchmark_catalogs_take_the_table_prune(config, n_sizes):
+    """Both benchmark deployments (catalog seed 11) have few distinct
+    bundle sizes, so every program their ticks build prunes by the
+    table; counted by ``device_cache_info()["table_prune_programs"]``."""
+    market = _bench_market(config)
     assert len(np.unique(market.b_pods)) == n_sizes
     be = make_backend("jax:fused")
     N, B, RC, D = be._shape_key(market, [1000] * 8, 8)
@@ -524,6 +529,58 @@ def test_benchmark_catalogs_take_the_table_prune(config, n_sizes):
     be._golden_program(N, B, RC, D, 12, table)
     info = be.device_cache_info()
     assert info["table_prune_programs"] == info["program_builds"] == 2
+
+
+@requires_jax
+@pytest.mark.parametrize("miss", [False, True])
+def test_fused_sparse_wide_market_equals_numpy(miss, monkeypatch):
+    """On the region deployment's 2,444 items, where a pool holds a
+    handful, the fused record's count arrays and the pools built from
+    their non-zero entries (DESIGN.md §13) select bit for bit what the
+    NumPy path selects: pools with ``int`` counts,
+    α, and traces that serialise byte-identically, with a mask and an
+    infeasible decision (every item excluded).  With ``miss`` the first
+    golden probe's lookup entry is dropped, so one probe takes the host
+    fallback through the same representation."""
+    import json
+
+    market = _bench_market("karpenter_region_cmr")
+    items = market.items
+    assert market.n >= 2000
+    rng = np.random.default_rng(61)
+    mask = np.zeros(market.n, bool)
+    mask[rng.choice(market.n, 60, replace=False)] = True
+    reqs = [900, 1100, 1000]
+    excludes = [None, mask, np.ones(market.n, bool)]
+    if miss:
+        run_golden = backend_mod._FusedGssRecord.run_golden
+
+        def drop_first_probe(rec, a_list, b_list):
+            before = set(rec._lookup[0])
+            run_golden(rec, a_list, b_list)
+            # the lookup keeps insertion order: the grid, then the probes
+            del rec._lookup[0][next(k for k in rec._lookup[0]
+                                    if k not in before)]
+
+        monkeypatch.setattr(backend_mod._FusedGssRecord, "run_golden",
+                            drop_first_probe)
+    be = make_backend("jax:fused")
+    fake = lambda: 0.0                                     # noqa: E731
+    got_n = bracketed_gss_many(items, reqs, market=market,
+                               excludes=excludes, timer=fake, backend=NUMPY)
+    got_f = bracketed_gss_many(items, reqs, market=market,
+                               excludes=excludes, timer=fake, backend=be)
+    assert _gss_summary(got_n) == _gss_summary(got_f)
+    assert got_f[2][0] is None and all(p is not None for p, _ in got_f[:2])
+    for (_pn, tn), (pf, tf) in zip(got_n, got_f):
+        assert (json.dumps(dataclasses.asdict(tn))
+                == json.dumps(dataclasses.asdict(tf)))
+        if pf is not None:
+            assert 0 < len(pf.counts) <= 12
+            assert all(type(c) is int and c > 0 for c in pf.counts)
+    info = be.device_cache_info()
+    assert info["fused_records"] == 1
+    assert info["fallback_solves"] == (1 if miss else 0)
 
 
 @requires_jax

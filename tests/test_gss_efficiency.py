@@ -12,6 +12,8 @@ from repro.core import (CandidateItem, NodePool, Offering, Request,
                         e_total, expected_iterations, generate_catalog,
                         golden_section_search, pods_per_instance,
                         scaled_benchmark_score, preprocess)
+from repro.core.efficiency import (nonzero_pool, pool_metric_arrays,
+                                   score_counts_batch)
 from repro.core.gss import PHI, bracketed_gss
 from tests.strategies import mk_item as _mk_item
 
@@ -117,6 +119,43 @@ def test_e_metrics_invariants_deterministic():
             n_short += 1
             assert e_total(pool, req) == 0.0
     assert n_covered >= 10 and n_short >= 10
+
+
+def test_scores_of_sparse_pools_equal_dense_scores():
+    """The fused record's representation scores bit for bit as the dense
+    one (DESIGN.md §13): ``e_total`` of ``nonzero_pool`` (only the
+    non-zero entries) equals ``e_total`` of the whole-market pool, and
+    ``score_counts_batch`` over an int32 array equals it over the same
+    rows as int lists."""
+    rng = np.random.default_rng(83)
+    n = 300
+    items = [_mk_item(i, int(rng.integers(1, 9)),
+                      float(rng.uniform(1e3, 1e5)),
+                      float(rng.uniform(0.01, 3.0)), 40) for i in range(n)]
+    arrays = pool_metric_arrays(items)
+    n_covered = 0
+    for _ in range(40):
+        rows = np.zeros((9, n), np.int32)
+        for r in range(9):
+            nz = rng.choice(n, int(rng.integers(0, 6)), replace=False)
+            rows[r, nz] = rng.integers(1, 40, len(nz))
+        req = int(rng.integers(1, 200))
+        for row in rows:
+            dense = NodePool(items=list(items), counts=row.tolist())
+            sparse = nonzero_pool(items, row)
+            assert sparse.counts == [c for c in row.tolist() if c]
+            assert all(type(c) is int for c in sparse.counts)
+            assert e_total(sparse, req) == e_total(dense, req)
+            assert sparse.total_pods == dense.total_pods
+            n_covered += dense.total_pods >= req > 0
+        feasible = [None if r % 4 == 3 else row for r, row in enumerate(rows)]
+        as_lists = [None if c is None else c.tolist() for c in feasible]
+        assert (score_counts_batch(items, feasible, req, arrays=arrays)
+                == score_counts_batch(items, as_lists, req, arrays=arrays))
+        assert (score_counts_batch(items, rows, req, arrays=arrays)
+                == score_counts_batch(items, rows.tolist(), req,
+                                      arrays=arrays))
+    assert n_covered >= 50
 
 
 def test_e_total_scale_free_for_single_type():
