@@ -3,17 +3,15 @@
 
 PY := PYTHONPATH=src python
 
-.PHONY: verify test bench bench-solver bench-backend bench-risk bench-fleet \
-        bench-scale bench-serve bench-chaos bench-region perf-gate docs-check \
-        check-skips
+.PHONY: verify test bench bench-risk bench-serve bench-chaos bench-region \
+        docs-check check-skips
 
 ## tier-1 gate: full test suite (junitxml-audited: every skip must be in
-## tests/skip_registry.py) + a smoke pass of the solver microbenchmark
-## + the docs gate (README quickstart runs, DESIGN.md refs resolve)
+## tests/skip_registry.py) + the docs gate (README quickstart runs,
+## DESIGN.md refs resolve)
 verify:
 	$(PY) -m pytest -x -q --junitxml=.pytest-report.xml
 	$(PY) tools/check_skips.py .pytest-report.xml
-	$(PY) -m benchmarks.bench_solver --smoke --json ""
 	$(PY) tools/docs_check.py
 
 ## audit the last test run's skips against the registered-skip table
@@ -31,37 +29,10 @@ test:
 bench:
 	$(PY) -m benchmarks.run
 
-## solver microbenchmark at all market sizes; refreshes BENCH_solver.json
-bench-solver:
-	$(PY) -m benchmarks.bench_solver --json BENCH_solver.json
-
-## decision-plane backend benchmark (PR 1 path vs batched numpy / fused
-## device-resident engine; compile vs steady-state split + catalog-size
-## scaling column); refreshes BENCH_backend.json
-bench-backend:
-	$(PY) -m benchmarks.bench_backend --json BENCH_backend.json
-
-## ReFrame-style perf regression gate: re-run the cheap fleet-tick config,
-## compare ratio metrics against PERF_REFERENCE.json within tolerance
-## bands, append to PERF_trajectory.jsonl; `--update` refreshes references
-perf-gate:
-	$(PY) -m benchmarks.perf_gate
-
 ## risk-subsystem backtest (kubepacs_risk vs kubepacs + forecast
 ## calibration); refreshes BENCH_risk.json
 bench-risk:
 	$(PY) -m benchmarks.bench_risk --json BENCH_risk.json
-
-## fleet-engine throughput (FleetSim vs per-seed run_replicas at R=256,
-## decision-memo effectiveness); refreshes BENCH_fleet.json
-bench-fleet:
-	$(PY) -m benchmarks.bench_fleet --json BENCH_fleet.json
-
-## demand-scale sweep 5k → 1M pods (coarsening ladder; in-bench
-## coarse≡exact verification at overlapping scales); refreshes
-## BENCH_scale.json
-bench-scale:
-	$(PY) -m benchmarks.bench_scale --json BENCH_scale.json
 
 ## serving co-simulation (serving_slo vs karpenter_like/kubepacs/… on
 ## diurnal/bursty/flash; in-bench determinism + zero-infeasibility

@@ -229,27 +229,6 @@ def run(smoke: bool = False, json_path: Optional[str] = None) -> dict:
     return out
 
 
-def gate_measurement(repeat: int = 1) -> dict:
-    """The ``make perf-gate`` metrics.  The sweep is numpy-engine
-    deterministic (FleetSim decisions are backend-bitwise by the DESIGN
-    §12 contract), so the ratio is identical on the jax and no-jax legs
-    and one run suffices; ``repeat`` is accepted for signature parity."""
-    checks = _contract_checks()
-    od_rate, od_perf = od_backfill_rate(chaos_scenario(None, "kubepacs"))
-    rows = _sweep("combined", [7], od_rate, od_perf)
-    hard, naive = rows["hardened"], rows["kubepacs"]
-    ratio = hard["slo_perf_per_dollar"] / max(naive["slo_perf_per_dollar"],
-                                              _DENOM_FLOOR)
-    return {
-        "chaos_hardened_vs_naive_ratio": round(ratio, 3),
-        "availability_ok": (hard["decision_availability"]
-                            >= TARGET_AVAILABILITY),
-        "determinism_ok": checks["determinism_ok"] and checks["replay_ok"],
-        "inert_ok": checks["inert_ok"],
-        "hardened_availability": hard["decision_availability"],
-    }
-
-
 def main(argv: Optional[List[str]] = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",

@@ -234,29 +234,6 @@ def run(smoke: bool = False, json_path: Optional[str] = None) -> dict:
     return out
 
 
-def gate_measurement(repeat: int = 1) -> dict:
-    """The ``make perf-gate`` metrics: the failover ratio plus the §17
-    hard contracts.  Numpy-engine deterministic (region policies solve
-    inline through the same backend-bitwise stack), so one run suffices;
-    ``repeat`` is accepted for signature parity."""
-    checks = _contract_checks()
-    od_rate, od_perf = od_backfill_rate(
-        region_scenario("kubepacs", storm=False))
-    rows = _sweep([7], [11], od_rate, od_perf)
-    hard = rows["hardened"]
-    pinned = rows[f"region_pinned:{HOME}"]
-    ratio = (hard["slo_perf_per_dollar"]
-             / max(pinned["slo_perf_per_dollar"], _DENOM_FLOOR))
-    return {
-        "region_failover_vs_pinned_ratio": round(ratio, 3),
-        "determinism_ok": (checks["determinism_ok"] and checks["replay_ok"]
-                           and checks["fleet_ok"]),
-        "single_region_inert": checks["single_region_inert"],
-        "identity_config_inert": checks["identity_config_inert"],
-        "hardened_demand_coverage": hard["demand_coverage"],
-    }
-
-
 def main(argv: Optional[List[str]] = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",

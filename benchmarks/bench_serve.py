@@ -17,12 +17,9 @@ serving scenario family:
     serving_slo on the pinned market — a comparison against an infeasible
     or non-reproducible run would be meaningless, so these raise.
 
-``gate_measurement()`` is the ``make perf-gate`` entry point: it pins the
-*analytic* perf-model mode (via the ``KUBEPACS_SERVE_PERF`` env override)
-so the gated ratio is identical on the jax and no-jax CI legs; the main
-comparison deliberately runs in the ambient mode instead, which is how
-the jax leg exercises the roofline table and the no-jax leg the analytic
-fallback end to end.
+The comparison runs in the ambient perf-model mode, which is how the jax
+leg exercises the roofline table and the no-jax leg the analytic fallback
+end to end.
 
 Usage:
   python -m benchmarks.bench_serve [--smoke] [--json PATH]
@@ -33,9 +30,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
-import os
 import platform
 import time
 from typing import List, Optional
@@ -43,8 +38,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.serve_sim import (WorkloadSpec, build_serve_scenario,
-                             clear_caches, run_serving, trace_digest)
-from repro.serve_sim.perf_model import ENV_MODE
+                             run_serving, trace_digest)
 
 #: acceptance bar (ISSUE 8): serving_slo ≥ 1.2× SLO-served QPS-hours per
 #: dollar over karpenter_like on the diurnal scenario, at equal-or-better
@@ -57,22 +51,6 @@ POLICIES = ("serving_slo", "karpenter_like", "kubepacs",
 #: ratio denominators are floored so one pathological karpenter run (zero
 #: SLO-served traffic) reports a huge finite ratio instead of inf/NaN
 _DENOM_FLOOR = 1e-9
-
-
-@contextlib.contextmanager
-def _pinned_mode(mode: str):
-    """Temporarily pin the perf-model mode (policy + staffing + report all
-    resolve ``default_profile`` → the env override)."""
-    old = os.environ.get(ENV_MODE)
-    os.environ[ENV_MODE] = mode
-    clear_caches()           # tables keyed by mode-inclusive digest anyway;
-    try:                     # cleared so counters reflect this block only
-        yield
-    finally:
-        if old is None:
-            del os.environ[ENV_MODE]
-        else:
-            os.environ[ENV_MODE] = old
 
 
 def _run_policy(kind: str, policy: str, duration_hours: float) -> tuple:
@@ -160,30 +138,6 @@ def run(smoke: bool = False, json_path: Optional[str] = None) -> dict:
         with open(json_path, "w") as f:
             json.dump(out, f, indent=2)
     return out
-
-
-def gate_measurement(repeat: int = 1) -> dict:
-    """The ``make perf-gate`` metrics, pinned to the analytic perf-model
-    mode so the ratio is identical on the jax and no-jax CI legs (mode
-    changes pod counts and absolute latencies; the gate must not see
-    that as a regression).  ``repeat`` is accepted for signature parity
-    with the other gate measurements — the serving ratio is exact
-    (integral of deterministic step functions), not a timing, so one run
-    suffices."""
-    with _pinned_mode("analytic"):
-        determinism_ok = _determinism_check(12.0)
-        rows = _compare("diurnal", ("serving_slo", "karpenter_like"), 12.0)
-    slo, karp = rows["serving_slo"], rows["karpenter_like"]
-    ratio = slo["slo_qps_hours_per_dollar"] / max(
-        karp["slo_qps_hours_per_dollar"], _DENOM_FLOOR)
-    return {
-        "serve_qps_per_dollar_ratio": round(ratio, 3),
-        "attainment_ok": (slo["slo_attainment"]
-                          >= karp["slo_attainment"] - 1e-9),
-        "infeasible_free": slo["infeasible_decisions"] == 0,
-        "determinism_ok": determinism_ok,
-        "serving_slo_attainment": round(slo["slo_attainment"], 4),
-    }
 
 
 def main(argv: Optional[List[str]] = None):

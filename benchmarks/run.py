@@ -3,26 +3,21 @@
 Usage:
   python -m benchmarks.run                    # full sweep
   python -m benchmarks.run --only fig7_tolerance
-  python -m benchmarks.run --only bench_solver --json out.json
+  python -m benchmarks.run --only bench_risk --json out.json
 
-``--json`` additionally folds every checked-in ``BENCH_*.json`` micro-
-benchmark record into a ``trajectory`` key, so the repo's whole perf
-history (solver, risk, fleet, …) is machine-readable from one file.
+Speed is measured on the chip by ``bench/run.py`` (``BENCHMARK.json``);
+these drivers report the paper's figures and subsystem quality.
 """
 import argparse
-import glob
 import json
-import os
 import sys
 import traceback
 
-from . import (bench_backend, bench_chaos, bench_fleet, bench_region,
-               bench_risk, bench_scale,
-               bench_serve, bench_solver, elastic_training, fig5_sota,
-               fig5c_spotkube,
-               fig6_alpha, fig6b_cross_provider, fig7_tolerance,
-               fig8_preferences, fig9_t3_fulfillment, fig12_interrupts,
-               roofline_report, table2_fixed_alpha, table3_perf_dollar)
+from . import (bench_chaos, bench_region, bench_risk, bench_serve,
+               elastic_training, fig5_sota, fig5c_spotkube, fig6_alpha,
+               fig6b_cross_provider, fig7_tolerance, fig8_preferences,
+               fig9_t3_fulfillment, fig12_interrupts, roofline_report,
+               table2_fixed_alpha, table3_perf_dollar)
 
 ALL = [
     ("fig5_sota", fig5_sota),
@@ -35,11 +30,7 @@ ALL = [
     ("fig9_t3_fulfillment", fig9_t3_fulfillment),
     ("fig12_interrupts", fig12_interrupts),
     ("table3_perf_dollar", table3_perf_dollar),
-    ("bench_solver", bench_solver),
-    ("bench_backend", bench_backend),
-    ("bench_scale", bench_scale),
     ("bench_risk", bench_risk),
-    ("bench_fleet", bench_fleet),
     ("bench_serve", bench_serve),
     ("bench_chaos", bench_chaos),
     ("bench_region", bench_region),
@@ -48,31 +39,10 @@ ALL = [
 ]
 
 
-def bench_trajectory(root: str = ".") -> dict:
-    """Consolidate every checked-in ``BENCH_*.json`` record: the benchmark
-    modules each refresh their own file (``make bench-solver`` /
-    ``bench-risk`` / ``bench-fleet``); this view stitches the perf history
-    together, keyed by file stem, with each record's ``headline`` (when the
-    writer provides one) surfaced next to the full record."""
-    trajectory = {}
-    for path in sorted(glob.glob(os.path.join(root, "BENCH_*.json"))):
-        name = os.path.splitext(os.path.basename(path))[0]
-        try:
-            with open(path) as f:
-                record = json.load(f)
-        except (OSError, json.JSONDecodeError) as exc:
-            trajectory[name] = {"status": "unreadable", "error": str(exc)}
-            continue
-        trajectory[name] = {"record": record}
-        if isinstance(record, dict) and "headline" in record:
-            trajectory[name]["headline"] = record["headline"]
-    return trajectory
-
-
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default=None, metavar="NAME",
-                    help="run a single figure/table/microbenchmark by name")
+                    help="run a single figure/table/driver by name")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write every driver's returned record to PATH")
     args = ap.parse_args(argv)
@@ -98,7 +68,6 @@ def main(argv=None) -> None:
             print(f"{name},0,FAILED")
             records[name] = {"status": "failed"}
     if args.json:
-        records["trajectory"] = bench_trajectory()
         with open(args.json, "w") as f:
             json.dump(records, f, indent=2, default=str)
     if failures:

@@ -165,7 +165,7 @@ def gss_phase(name, be, numpy_be, n_offerings, n_items, pods, n_dec):
 
 
 def fleet_phase(be, seeds=32):
-    """run_fleet of the bench_fleet interrupt-storm scenario, NumPy vs the
+    """run_fleet of risk.backtest's interrupt-storm scenario, NumPy vs the
     device plane: traces must be byte-identical."""
     from repro.risk import backtest
     from repro.sim import run_fleet

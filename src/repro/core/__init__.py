@@ -14,9 +14,9 @@ from .scaling import scaled_benchmark_score, build_base_price_index, matches_int
 from .backend import (DEFAULT_COARSENING, CoarseningConfig, FusedJaxBackend,
                       NumpyBackend, SolverBackend, get_backend,
                       jax_available, make_backend, set_backend)
-from .ilp import (solve_ilp, solve_ilp_batch, solve_ilp_many, solve_ilp_pulp,
-                  solve_ilp_reference, objective_coefficients,
-                  CompiledMarket, compile_market, reweight_market)
+from .ilp import (solve_ilp, solve_ilp_batch, solve_ilp_many,
+                  objective_coefficients, CompiledMarket, compile_market,
+                  reweight_market)
 from .gss import (golden_section_search, bracketed_gss, bracketed_gss_many,
                   expected_iterations, GssTrace, PHI)
 from .baselines import kubepacs_greedy, spotverse, spotkube, karpenter_like
@@ -30,8 +30,8 @@ __all__ = [
     "e_perf_cost", "e_over_pods", "e_total", "e_total_batch",
     "pool_metric_arrays", "score_counts_batch", "scaled_benchmark_score",
     "build_base_price_index", "matches_intent", "solve_ilp",
-    "solve_ilp_batch", "solve_ilp_pulp", "solve_ilp_reference",
-    "objective_coefficients", "CompiledMarket", "compile_market",
+    "solve_ilp_batch", "objective_coefficients", "CompiledMarket",
+    "compile_market",
     "golden_section_search", "bracketed_gss", "expected_iterations",
     "GssTrace", "PHI", "kubepacs_greedy", "spotverse", "spotkube",
     "karpenter_like", "KubePACSProvisioner", "ProvisioningDecision",

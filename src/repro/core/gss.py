@@ -10,8 +10,8 @@ reproduces every probe bit for bit.  The best pool over *all* evaluated α
 is returned (Alg. 1's S*), which also guards against mild
 non-unimodality of the empirical E_Total(α).
 
-Engine wiring (DESIGN.md §8 + §12): with the default solver,
-``bracketed_gss`` is the one-decision case of :func:`bracketed_gss_many`,
+Engine wiring (DESIGN.md §8 + §12): ``bracketed_gss`` is the one-decision
+case of :func:`bracketed_gss_many`,
 the *cross-decision batched* search: D decisions (each with its own
 demand and §4.1 exclusion mask) advance their prescans and golden-section
 brackets in lockstep, and every round's pending α probes across all
@@ -19,8 +19,8 @@ decisions go to :func:`~repro.core.ilp.solve_ilp_many` as one stacked
 engine invocation (one backend dispatch).  Each decision's (α, E_Total)
 evaluation sequence — and therefore its selected pool and trace — is
 exactly the sequential algorithm's; batching changes execution, never
-content.  A custom ``solver`` callable falls back to the seed per-α path
-unchanged.
+content.  :func:`golden_section_search` keeps a ``solver`` channel (float
+α, the seed calling convention) for the unguarded policy and for tests.
 """
 
 from __future__ import annotations
@@ -169,7 +169,6 @@ def bracketed_gss(
     req_pods: int,
     tolerance: float = 0.01,
     prescan: int = 9,
-    solver: Callable[[Sequence[CandidateItem], int, float], Optional[List[int]]] = solve_ilp,
     market: Optional[CompiledMarket] = None,
     exclude: Optional[np.ndarray] = None,
     timer: Callable[[], float] = time.perf_counter,
@@ -181,64 +180,18 @@ def bracketed_gss(
     The paper's Fig. 6 landscapes are empirically unimodal; a synthetic or
     adversarial market can produce secondary bumps that trap pure GSS in the
     wrong bracket.  We first scan ``prescan`` equispaced α (one batched
-    engine invocation with the default solver), then run Algorithm 1 inside
-    the grid cell bracketing the best scan point.  Degrades gracefully to
-    pure GSS quality on unimodal landscapes; strictly better on bumpy ones.
+    engine invocation), then run Algorithm 1 inside the grid cell
+    bracketing the best scan point.  Degrades gracefully to pure GSS
+    quality on unimodal landscapes; strictly better on bumpy ones.
 
-    With the default solver this *is* :func:`bracketed_gss_many` at
-    ``D = 1`` — one implementation, so the batched tick phase of the fleet
-    engine and the sequential path can never diverge (DESIGN.md §12).
+    This *is* :func:`bracketed_gss_many` at ``D = 1`` — one
+    implementation, so the batched tick phase of the fleet engine and the
+    sequential path can never diverge (DESIGN.md §12).
     """
-    if solver is solve_ilp:
-        return bracketed_gss_many(
-            items, [req_pods], tolerance=tolerance, prescan=prescan,
-            market=market, excludes=[exclude], timer=timer,
-            backend=backend, coarsening=coarsening)[0]
-
-    # custom-solver fallback: the seed per-α path, unchanged
-    if exclude is not None:
-        raise ValueError("exclude masks require the default solve_ilp "
-                         "solver (custom solvers have no exclusion "
-                         "channel)")
-    grid = [exact.k_alpha(k) for k in exact.alpha_grid(prescan)]
-    scan_trace = GssTrace()
-    t0 = timer()
-    scores, pools = [], []
-    for alpha in grid:
-        counts = solver(items, req_pods, alpha)
-        scan_trace.ilp_solves += 1
-        if counts is None:
-            scores.append(float("-inf"))
-            pools.append(None)
-        else:
-            pool = NodePool(items=list(items), counts=counts, alpha=alpha)
-            scores.append(e_total(pool, req_pods))
-            pools.append(pool)
-
-    best_pool, best_f, best_idx = None, float("-inf"), 0
-    for gi, (alpha, score, pool) in enumerate(zip(grid, scores, pools)):
-        if pool is not None:
-            pool.alpha = alpha
-        scan_trace.alphas.append(alpha)
-        scan_trace.e_totals.append(max(score, 0.0))
-        if score > best_f:
-            best_pool, best_f, best_idx = pool, score, gi
-
-    lo = grid[max(0, best_idx - 1)]
-    hi = grid[min(len(grid) - 1, best_idx + 1)]
-    pool, trace = golden_section_search(items, req_pods, tolerance=tolerance,
-                                        alpha_lo=lo, alpha_hi=hi,
-                                        solver=solver, market=market,
-                                        exclude=exclude, timer=timer)
-    # merge traces and keep the global argmax
-    trace.alphas = scan_trace.alphas + trace.alphas
-    trace.e_totals = scan_trace.e_totals + trace.e_totals
-    trace.ilp_solves += scan_trace.ilp_solves
-    trace.wall_seconds = timer() - t0
-    inner_f = e_total(pool, req_pods) if pool is not None else float("-inf")
-    if best_pool is not None and best_f > inner_f:
-        return best_pool.nonzero(), trace
-    return pool, trace
+    return bracketed_gss_many(
+        items, [req_pods], tolerance=tolerance, prescan=prescan,
+        market=market, excludes=[exclude], timer=timer,
+        backend=backend, coarsening=coarsening)[0]
 
 
 class _GssState:

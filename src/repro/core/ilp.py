@@ -28,11 +28,9 @@ All three produce *bit-identical selections* for a given row regardless of
 batching and backend: every value that decides a selection is an exact
 integer (:mod:`repro.core.exact`: α on a dyadic grid, quantized objective
 coefficients, int64 costs and DP values), and tie-breaking lives entirely
-in the shared improvement-bit backtracker.
-
-:func:`solve_ilp_reference` preserves the seed history-matrix solver
-verbatim for cross-validation tests and as the benchmark baseline;
-:func:`solve_ilp_pulp` wraps the paper's actual tool (PuLP/CBC).
+in the shared improvement-bit backtracker.  The seed history-matrix
+solver and a PuLP/CBC wrapper live with the tests (``tests/oracles.py``)
+as independent references.
 
 All count-returning entry points return per-item integers, or ``None`` when
 demand exceeds the total bounded capacity (the paper assumes the cloud
@@ -63,8 +61,7 @@ from .efficiency import CandidateItem
 __all__ = [
     "CoarseningConfig", "DEFAULT_COARSENING", "CompiledMarket", "IlpStats",
     "compile_market", "reweight_market", "objective_coefficients",
-    "solve_ilp", "solve_ilp_batch", "solve_ilp_many", "solve_ilp_reference",
-    "solve_ilp_pulp",
+    "solve_ilp", "solve_ilp_batch", "solve_ilp_many",
 ]
 
 _INF = float("inf")
@@ -973,98 +970,3 @@ def solve_ilp_many(items: Sequence[CandidateItem],
         st.append(flat_stats[pos:pos + k])
         pos += k
     return (out, st) if return_stats else out
-
-
-# ---------------------------------------------------------------------------
-# Reference backends
-# ---------------------------------------------------------------------------
-
-def solve_ilp_reference(items: Sequence[CandidateItem], req_pods: int,
-                        alpha: float, return_stats: bool = False,
-                        ) -> Optional[List[int]] | Tuple[Optional[List[int]],
-                                                         IlpStats]:
-    """The seed history-matrix solver, retained verbatim as the baseline for
-    cross-validation tests and ``benchmarks/bench_solver.py``.  Peak memory
-    is O(bundles × residual): the ``history`` matrix below is exactly what
-    the production engine eliminates."""
-    n = len(items)
-    counts = [0] * n
-    if n == 0:
-        result = None if req_pods > 0 else counts
-        return (result, IlpStats(0, 0, req_pods, _INF)) if return_stats else result
-
-    coef = objective_coefficients(items, alpha)
-    pods = np.array([it.pods for it in items], dtype=np.int64)
-    bound = np.array([it.t3 for it in items], dtype=np.int64)
-
-    neg = (coef < 0) & (bound > 0)
-    covered = 0
-    for i in np.nonzero(neg)[0]:
-        counts[i] = int(bound[i])
-        covered += int(pods[i] * bound[i])
-
-    residual = max(0, req_pods - covered)
-    objective = float(np.sum(coef[neg] * bound[neg]))
-
-    if residual == 0:
-        stats = IlpStats(n, 0, 0, objective)
-        return (counts, stats) if return_stats else counts
-
-    idx = [i for i in range(n)
-           if not neg[i] and bound[i] > 0 and pods[i] > 0]
-    if int(np.sum(pods[idx] * bound[idx])) < residual:
-        return (None, IlpStats(n, 0, residual, _INF)) if return_stats else None
-
-    bundles: List[Tuple[int, int, float, int]] = []   # (item, pods, cost, copies)
-    for i in idx:
-        for copies in _binary_bundles(int(bound[i])):
-            bundles.append((i, int(pods[i] * copies),
-                            float(coef[i] * copies), copies))
-
-    R = residual
-    dp = np.full(R + 1, _INF)
-    dp[0] = 0.0
-    history = np.empty((len(bundles) + 1, R + 1))
-    history[0] = dp
-    for b, (_, pb, cb, _) in enumerate(bundles):
-        shifted = np.empty(R + 1)
-        cut = min(pb, R + 1)
-        shifted[:cut] = dp[0]
-        if cut <= R:
-            shifted[cut:] = dp[: R + 1 - pb]
-        dp = np.minimum(dp, shifted + cb)
-        history[b + 1] = dp
-
-    if not np.isfinite(dp[R]):
-        return (None, IlpStats(n, len(bundles), residual, _INF)) if return_stats else None
-
-    j = R
-    for b in range(len(bundles) - 1, -1, -1):
-        if j == 0:
-            break
-        if history[b + 1][j] < history[b][j] - 1e-12:
-            i, pb, _, copies = bundles[b]
-            counts[i] += copies
-            j = max(0, j - pb)
-    objective += float(dp[R])
-
-    stats = IlpStats(n, len(bundles), residual, objective)
-    return (counts, stats) if return_stats else counts
-
-
-def solve_ilp_pulp(items: Sequence[CandidateItem], req_pods: int,
-                   alpha: float) -> Optional[List[int]]:
-    """Reference backend using PuLP/CBC (the paper's implementation, §4)."""
-    import pulp
-
-    coef = objective_coefficients(items, alpha)
-    prob = pulp.LpProblem("kubepacs_node_selection", pulp.LpMinimize)
-    xs = [pulp.LpVariable(f"x_{i}", lowBound=0, upBound=int(it.t3),
-                          cat="Integer") for i, it in enumerate(items)]
-    prob += pulp.lpSum(float(coef[i]) * xs[i] for i in range(len(items)))
-    prob += pulp.lpSum(int(it.pods) * xs[i]
-                       for i, it in enumerate(items)) >= int(req_pods)
-    status = prob.solve(pulp.PULP_CBC_CMD(msg=False))
-    if pulp.LpStatus[status] != "Optimal":
-        return None
-    return [int(round(x.value() or 0)) for x in xs]

@@ -63,7 +63,7 @@ def gcd_market(rng, n_items=80, pod_mult=8, t3_lo=20, t3_hi=120):
 
 def big_market(rng, n_items=600, t3_lo=200, t3_hi=3000):
     """A deep market (capacity in the millions of pods) for the approx
-    coarsening tier and the scale benchmarks; gcd is almost surely 1."""
+    coarsening tier tests; gcd is almost surely 1."""
     return [mk_item(i, int(rng.integers(1, 9)),
                     float(rng.uniform(0.5, 4.0)),
                     float(rng.uniform(0.05, 2.5)),

@@ -6,10 +6,10 @@ import numpy as np
 import pytest
 
 from tests._optional import given, settings, st
+from tests.oracles import solve_ilp_pulp
 from tests.strategies import item_strategy, mk_item
 
 from repro.core import objective_coefficients, solve_ilp
-from repro.core.ilp import solve_ilp_pulp
 
 
 def _brute_force(items, req, alpha):

@@ -430,6 +430,22 @@ def test_fig9_via_engine_matches_direct_simulator():
     assert out["rows"][0]["mean_fulfilled"] == expect
 
 
+def test_figure_sweep_registry_resolves():
+    """Every ``benchmarks.run.ALL`` entry names a driver module that exists
+    on disk under that name and has a callable ``main`` (no driver runs)."""
+    import os
+
+    from benchmarks import run
+
+    names = [name for name, _ in run.ALL]
+    assert len(set(names)) == len(names)
+    pkg_dir = os.path.dirname(run.__file__)
+    for name, mod in run.ALL:
+        assert mod.__name__ == f"benchmarks.{name}"
+        assert os.path.isfile(os.path.join(pkg_dir, f"{name}.py"))
+        assert callable(getattr(mod, "main", None))
+
+
 def test_fig12_via_engine(small_catalog):
     from benchmarks import fig12_interrupts
     out = fig12_interrupts.run(small_catalog, rounds=3)

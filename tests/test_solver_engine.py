@@ -17,10 +17,12 @@ from repro.core import (KubePACSProvisioner, Request,
                         compile_market, e_total, e_total_batch,
                         generate_catalog, objective_coefficients,
                         pool_metric_arrays, preprocess, solve_ilp,
-                        solve_ilp_batch, solve_ilp_reference)
+                        solve_ilp_batch)
 from repro.core.gss import bracketed_gss, golden_section_search
 from repro.core.ilp import _lp_prune
 
+from tests.oracles import (guarded_gss_reference, solve_ilp_pulp,
+                           solve_ilp_reference)
 from tests.strategies import mk_item as _mk_item
 from tests.strategies import random_market as _random_market
 
@@ -105,7 +107,6 @@ def test_engine_matches_brute_force_small():
 
 def test_engine_matches_pulp():
     pytest.importorskip("pulp")
-    from repro.core.ilp import solve_ilp_pulp
     rng = np.random.default_rng(11)
     for _ in range(10):
         items = _random_market(rng, max_items=8)
@@ -129,8 +130,9 @@ def test_bracketed_gss_identical_before_after_rewire(catalog):
         req = Request(pods=pods, cpu_per_pod=cpu, mem_per_pod=mem)
         items = preprocess(catalog, req)
         engine_pool, engine_trace = bracketed_gss(items, pods, tolerance=0.01)
-        legacy_pool, legacy_trace = bracketed_gss(items, pods, tolerance=0.01,
-                                                  solver=solve_ilp_reference)
+        legacy_pool, legacy_trace = guarded_gss_reference(
+            items, pods, tolerance=0.01, prescan=9,
+            solver=solve_ilp_reference)
         assert engine_trace.ilp_solves == legacy_trace.ilp_solves
         assert e_total(engine_pool, pods) == pytest.approx(
             e_total(legacy_pool, pods), rel=1e-9)
@@ -154,8 +156,9 @@ def test_provision_identical_before_after_rewire(catalog):
         req = Request(pods=pods, cpu_per_pod=cpu, mem_per_pod=mem)
         d = prov.provision(req, catalog)
         items = preprocess(catalog, req)
-        legacy_pool, _ = bracketed_gss(items, pods, tolerance=0.01,
-                                       solver=solve_ilp_reference)
+        legacy_pool, _ = guarded_gss_reference(
+            items, pods, tolerance=0.01, prescan=9,
+            solver=solve_ilp_reference)
         assert d.metrics["e_total"] == pytest.approx(
             e_total(legacy_pool, pods), rel=1e-9)
 
